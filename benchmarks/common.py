@@ -23,7 +23,7 @@ from pathlib import Path
 from typing import Callable, Dict, List
 
 from repro.configs.paper_machine import paper_machine
-from repro.core import Summary, default_jobs, get_pool, run_many
+from repro.core import Summary, default_jobs, get_pool, pool_allowed, run_many
 from repro.linalg.cholesky import cholesky_graph
 from repro.sched import resolve
 from repro.linalg.lu import lu_graph
@@ -226,7 +226,10 @@ def sweep(
     )
     n_jobs = default_jobs(len(configs))
     futs = None
-    if not batched and n_jobs > 1 and len(configs) > 1:
+    if (
+        not batched and n_jobs > 1 and len(configs) > 1
+        and pool_allowed([sfac for _, _, sfac in configs])
+    ):
         try:
             import pickle
 

@@ -314,12 +314,6 @@ def _validate_verified(checks: List[dict]) -> List[dict]:
         )
     )
 
-    try:
-        import jax  # noqa: F401
-    except Exception:
-        print("  (jax unavailable — skipping surrogate verifier claim)")
-        return checks
-
     import numpy as np
 
     from repro.core import episode as ep

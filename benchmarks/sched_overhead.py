@@ -36,7 +36,7 @@ Results go to stdout (``name,us_per_call,derived`` contract) and to
 
 Knobs: REPRO_BENCH_GPUS (first entry, default 8), REPRO_BENCH_RUNS
 (default 3), REPRO_BENCH_NT (comma list, default 16), REPRO_SCHED_BACKENDS
-(default ``numpy,jax`` when jax imports, else ``numpy``),
+(default ``numpy,jax``),
 REPRO_BENCH_LAMBDA (=0 skips the λ-probe section), REPRO_BENCH_LAMBDA_NT
 (default 64), REPRO_BENCH_LAMBDA_REPS (default 3).
 """
@@ -85,14 +85,9 @@ BACKEND_FREE_STRATEGIES = {
 
 
 def available_backends() -> list:
-    """Backends to measure: only ones that actually initialise.
-
-    ``get_backend("jax")`` can fall back to numpy (missing jax, init
-    failure); measuring that fallback under a ``jax`` label would record
-    duplicate-numpy rows into the perf trajectory, so such entries are
-    dropped with a notice.
-    """
-    from repro.core import get_backend
+    """Backends to measure, each built up front: an unknown name or a jax
+    backend that cannot be built fails the run instead of being skipped."""
+    from repro.core import backend_name, get_backend
     from repro.sched import current_config
 
     cfg = current_config()
@@ -101,18 +96,9 @@ def available_backends() -> list:
         if cfg.bench_backends is not None
         else ["numpy", "jax"]
     )
-    out = []
     for name in names:
-        try:
-            unavailable = name != "numpy" and get_backend(name) is None
-        except ValueError:
-            print(f"note: unknown backend {name!r} — skipped")
-            continue
-        if unavailable:
-            print(f"note: backend {name!r} unavailable here — skipped")
-            continue
-        out.append(name)
-    return out
+        get_backend(backend_name(name))
+    return names
 
 
 # ---------------------------------------------------------------------------
@@ -463,11 +449,6 @@ def batched_sweep_rows(nt: int, n_gpus: int, n_runs: int) -> list:
     for. Rows carry ``exact=False`` (the regression key separates the two
     engines) and the per-dispatch batch size.
     """
-    try:
-        import jax  # noqa: F401
-    except Exception:
-        print("note: jax unavailable — batched-sweep rows skipped")
-        return []
     from repro.core import cached_graph, run_batch, run_simulation
     from repro.sched import current_config
 

@@ -12,6 +12,7 @@ from .api import (
     default_jobs,
     get_pool,
     make_strategy,
+    pool_allowed,
     run_batch,
     run_many,
     run_simulation,
@@ -48,6 +49,6 @@ __all__ = [
     "Mode", "Residency", "Resource", "ResourceClass", "SimResult",
     "Simulator", "Strategy", "Summary", "Task", "TaskGraph", "TransferModel",
     "WorkSteal", "backend_name", "cached_graph", "default_jobs", "get_backend",
-    "get_pool", "make_machine", "make_strategy", "run_batch", "run_many",
-    "run_simulation",
+    "get_pool", "make_machine", "make_strategy", "pool_allowed", "run_batch",
+    "run_many", "run_simulation",
 ]

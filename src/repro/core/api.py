@@ -227,6 +227,25 @@ def _get_pool(n_jobs: int):
         return _POOL
 
 
+def pool_allowed(strategy_factories: Sequence) -> bool:
+    """Whether seeded runs of these strategies may fan out over the fork
+    pool. A worker never touches JAX: forking after this process took the
+    chip would hand the child a runtime it cannot use, and a jax-scored
+    strategy in a child would claim the chip itself (one process per
+    chip). Such runs stay in this process; results are identical."""
+    from .backend import ScoringBackendMixin, accelerator_initialised, backend_name
+
+    if accelerator_initialised():
+        return False
+    for factory in strategy_factories:
+        s = factory()
+        if isinstance(s, ScoringBackendMixin) and backend_name(
+            s.backend_name, s.config
+        ) == "jax":
+            return False
+    return True
+
+
 def run_many(
     graph_factory,
     machine: MachineModel,
@@ -246,14 +265,15 @@ def run_many(
     seeded, so the summary is bit-identical to the serial path regardless
     of worker count; results are gathered in seed order. Falls back to the
     serial loop when ``n_jobs == 1``, when the factories are not picklable
-    (e.g. test-local closures), or when the pool cannot be created.
+    (e.g. test-local closures), when the pool cannot be created, or when
+    :func:`pool_allowed` keeps JAX work in this process.
     """
     if n_jobs is None:
         n_jobs = default_jobs(n_runs)
     seeds = [base_seed + i for i in range(n_runs)]
 
     futs = None
-    if n_jobs > 1 and n_runs > 1:
+    if n_jobs > 1 and n_runs > 1 and pool_allowed([strategy_factory]):
         # contiguous seed chunks, one per worker; gathered in order, so the
         # summary is bit-identical to the serial path
         n_chunks = min(n_jobs, n_runs)
@@ -347,9 +367,7 @@ def run_batch(configs: Sequence[dict], config=None) -> List[BatchResult]:
     This is the approximate engine: placements relax the oracle's
     tie-breaking (see the module docstring of ``repro.core.episode``),
     so use it for sweeps and searches, and the exact engine
-    (:func:`run_simulation` / :func:`run_many`) for verification. It
-    requires the jax backend; a numpy-only environment raises instead
-    of silently falling back to the exact path.
+    (:func:`run_simulation` / :func:`run_many`) for verification.
     """
     from repro.core import episode as ep
 
@@ -357,14 +375,6 @@ def run_batch(configs: Sequence[dict], config=None) -> List[BatchResult]:
         from repro.sched.config import current_config
 
         config = current_config()
-    try:
-        import jax  # noqa: F401
-    except Exception as exc:  # pragma: no cover - jax baked into CI images
-        raise RuntimeError(
-            "run_batch needs the jax backend for the batched surrogate "
-            "engine; install jax or use run_many on the exact path"
-        ) from exc
-
     # resolve graphs and group by (graph, machine template)
     items = []
     for i, c in enumerate(configs):

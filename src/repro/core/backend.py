@@ -7,9 +7,10 @@ accelerates the two placement hot spots on wide activations:
   * **fused score matrices** — the (ready × resources) duration / transfer /
     affinity matrices come out of one jitted call over padded CSR slices
     (reads and writes are padded to static shapes so retraces stay bounded),
-    with the CSR-incidence → transfer-time reduction optionally running
-    through the Pallas kernel in ``repro.kernels.sched_score`` on
-    accelerator platforms;
+    with the CSR-incidence → transfer-time reduction through the shared
+    in-order hop fold of ``repro.kernels.sched_score`` (XLA: the
+    scores are f64, and the Pallas kernel, which has no f64 lowering on
+    TPU, serves the f32 surrogate episodes instead);
   * **batched λ-probe search** — DADA's binary search on the makespan guess
     λ runs as **one jitted dispatch** (a ``lax.while_loop``, no Python
     loop): each iteration computes the 2^d−1 midpoints reachable within
@@ -26,7 +27,12 @@ Bit-for-bit contract: the backend only ever computes *score values* (which
 are IEEE-f64 op-for-op identical to the numpy path) and *feasibility
 verdicts*; the placement for the accepted λ is always rebuilt by the
 strategy's own Python ``try_build``, so decisions — including tie-breaks —
-cannot drift. ``tests/test_backend.py`` enforces both levels.
+cannot drift. ``tests/test_backend.py`` enforces both levels. A TPU has no
+IEEE f64 (XLA emulates it with pairs of f32, which changes values in their
+last bits), so there the programs compute on the int64 bit patterns of
+their f64 values with integer-exact IEEE arithmetic (``repro.core.f64``);
+``tests/test_f64.py`` holds that arithmetic to numpy's bits and
+``chip_smoke.py`` holds the chip's placements to numpy's.
 
 The feasibility verdict reproduces ``try_build``'s boolean without its
 early exits (overflow flags are sticky, loads accumulate through the same
@@ -47,9 +53,8 @@ results:
 The backend is selected per strategy instance (``DADA(backend="jax")``),
 falling back to the scheduling configuration (``repro.sched.SchedConfig``,
 itself parsed once from ``REPRO_SCHED_BACKEND`` et al. with validation)
-and defaulting to numpy. JAX is imported lazily; when it is missing the
-jax backend degrades to numpy with a one-time warning so dependency-light
-environments keep working.
+and defaulting to numpy. JAX is imported lazily; a jax backend that
+cannot be built raises instead of degrading to numpy.
 
 Knobs (all parsed/validated by ``SchedConfig.from_env``; this module never
 reads ``os.environ`` directly):
@@ -58,16 +63,16 @@ reads ``os.environ`` directly):
                             (default 32; set 1 to force it everywhere)
   REPRO_SCHED_LAMBDA_DEPTH  speculative bisection depth d (default: 1 on
                             cpu, 5 on gpu/tpu; 1-8)
-  REPRO_SCHED_PALLAS        auto (default: Pallas on gpu/tpu, XLA fold on
-                            cpu) | 1 (force, interpret-mode on cpu) | 0
 """
 from __future__ import annotations
 
-import warnings
+import sys
+from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .f64 import for_platform as f64_for_platform
 from .machine import HOST_MEM
 
 DEFAULT_JAX_MIN = 32
@@ -83,10 +88,6 @@ def _resolve_config(config=None):
     return current_config()
 
 _TINY = 1e-12  # must match dada._TINY
-
-# scan unrolling amortizes the per-step XLA loop overhead that dominates the
-# sequential phases on CPU; it changes code size only, never op order/results
-_UNROLL = 16
 
 _BACKENDS = ("numpy", "jax")
 
@@ -109,53 +110,61 @@ def jax_min_wide(config=None) -> int:
 
 
 # built backends keyed by the config fields the backend actually consumes
-# (lambda_depth, pallas) — the typical process uses one config and hence
+# (lambda_depth, jax_min) — the typical process uses one config and hence
 # one instance (its jit caches are the expensive part), but an explicit
 # per-strategy SchedConfig must not silently inherit the first caller's
-# depth/pallas settings
+# settings
 _JAX_BACKENDS: Dict[tuple, "JaxScoringBackend"] = {}
-_JAX_FAILED = False
-_WARNED_FALLBACK = False
 
 
 def get_backend(explicit: Optional[str] = None, config=None):
     """Return the scoring backend: ``None`` for numpy, else the jax backend.
 
-    A missing/broken jax degrades to numpy with a single warning — tier-1
-    environments without jax keep working unchanged.
+    A jax backend that cannot be built raises: a run that asked for the
+    device path never degrades to numpy behind the caller's back.
     """
     config = _resolve_config(config)
     if backend_name(explicit, config) == "numpy":
         return None
-    global _JAX_FAILED, _WARNED_FALLBACK
-    if _JAX_FAILED:
-        return None
-    key = (config.lambda_depth, config.pallas, config.jax_min)
+    key = (config.lambda_depth, config.jax_min)
     be = _JAX_BACKENDS.get(key)
     if be is None:
-        try:
-            be = _JAX_BACKENDS[key] = JaxScoringBackend(config)
-        except Exception as exc:  # ImportError or accelerator init failure
-            _JAX_FAILED = True
-            if not _WARNED_FALLBACK:
-                _WARNED_FALLBACK = True
-                warnings.warn(
-                    "REPRO_SCHED_BACKEND=jax requested but the jax backend "
-                    f"could not be initialised ({exc!r}); falling back to "
-                    "the numpy scoring path",
-                    RuntimeWarning,
-                    stacklevel=2,
-                )
-            return None
+        be = _JAX_BACKENDS[key] = JaxScoringBackend(config)
     return be
 
 
 def _reset_backend_cache() -> None:
-    """Test hook: forget failed (or built) backends."""
-    global _JAX_FAILED, _WARNED_FALLBACK
+    """Test hook: forget built backends."""
     _JAX_BACKENDS.clear()
-    _JAX_FAILED = False
-    _WARNED_FALLBACK = False
+
+
+_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX read it at import and
+    it is left alone; otherwise the cache lives in one fixed, git-ignored
+    directory of the checkout (a moving path would never hit).
+    """
+    import jax
+
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", str(_CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+
+
+def accelerator_initialised() -> bool:
+    """True once this process has initialised a non-CPU JAX backend, and so
+    holds the chip (checked without initialising a backend itself)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    # private: no public call answers this without initialising a backend
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized() and jax.default_backend() != "cpu"
 
 
 def _bucket(n: int, lo: int = 8) -> int:
@@ -212,8 +221,8 @@ class JaxScoringBackend:
 
     name = "jax"
 
-    # compact residency codes (Pallas path) are int32: bit 0 = host,
-    # bit u+1 = unique mem u
+    # envelope of the fused path: residency masks are int64 with bit
+    # mem+1 per memory; wider machines take the numpy path (counted)
     _MAX_UNIQ_MEMS = 30
 
     def __init__(self, config=None) -> None:
@@ -221,32 +230,29 @@ class JaxScoringBackend:
         import jax.numpy as jnp
 
         config = _resolve_config(config)
+        enable_compile_cache()
 
         # x64 is scoped per backend call (see _x64), never flipped
         # process-wide: the repo's other jax stacks (models, linalg tiles,
         # Pallas kernels) must keep their f32 defaults regardless of
         # whether a scheduling strategy was instantiated first
-        from jax.experimental import enable_x64 as _enable_x64
-
-        with _enable_x64():
-            jnp.asarray(0.0)  # fail fast if the context is unsupported
-
         self.jax = jax
         self.jnp = jnp
-        self._x64 = _enable_x64
+        self._x64 = lambda: jax.enable_x64(True)
         platform = jax.default_backend()
+        self.platform = platform
         default_depth = 1 if platform == "cpu" else 5
         self.depth = (
             config.lambda_depth if config.lambda_depth is not None else default_depth
         )
         self._min_wide = config.jax_min
-        pallas = config.pallas
-        if pallas == "1":
-            self.pallas_mode = "interpret" if platform == "cpu" else "native"
-        elif pallas in ("0", "off", "false"):
-            self.pallas_mode = "off"
-        else:  # auto
-            self.pallas_mode = "native" if platform in ("gpu", "tpu") else "off"
+        # IEEE f64 in hardware, or integer-exact f64 where the platform has
+        # none (TPU): either way the device's bits are numpy's
+        self.f64 = f64_for_platform(platform)
+        # activations scored on the device vs returned to numpy because
+        # they fall outside the supported envelope (``outside``) or because
+        # the device's λ was not feasible on the host (``rejected``)
+        self.counts = {"device": 0, "outside": 0, "rejected": 0}
         self._matrix_fns: Dict[tuple, object] = {}
         self._search_fns: Dict[tuple, object] = {}
         self._heft_fns: Dict[tuple, object] = {}
@@ -284,15 +290,8 @@ class JaxScoringBackend:
             mem_shift=jnp.asarray(
                 [u + 1 for u in uniq], dtype=jnp.int64
             ),
-            col_bits=jnp.asarray(
-                [1 << (u + 1) for u in range(len(uniq))], dtype=jnp.int32
-            ),
             host_col=jnp.asarray([mem == HOST_MEM for mem in uniq], dtype=bool),
             accel_res=jnp.asarray(accel, dtype=bool),
-            cpu_idx=jnp.asarray(cpu_idx, dtype=jnp.int32),
-            gpu_idx=jnp.asarray(gpu_idx, dtype=jnp.int32),
-            n_cpu=len(cpu_idx),
-            n_gpu=len(gpu_idx),
             latency=transfer_model.latency,
             bandwidth=transfer_model.bandwidth,
         )
@@ -357,6 +356,7 @@ class JaxScoringBackend:
 
         mach = self._machine_arrays(resources, sim.transfer_model)
         if mach is None:
+            self.counts["outside"] += 1
             return None
         arr = sim.arrays
         residency = sim.residency
@@ -370,6 +370,7 @@ class JaxScoringBackend:
         aff_src = affinity_csr_source(affinity, arr) if affinity else None
         want_s = aff_src is not None
         if not (want_x or want_s or p_cpu is not None):
+            self.counts["outside"] += 1
             return None
         want_bias = want_x and x_bias is not None
         if want_bias:
@@ -387,9 +388,15 @@ class JaxScoringBackend:
             read_masks, read_sizes = self._pad_csr(
                 r_indptr, [r_masks, r_sizes], n_pad, r_pad
             )
+            # per-read one-hop times on the host, as the numpy path has them
+            per_read = np.where(
+                read_sizes <= 0.0, 0.0,
+                mach["latency"] + read_sizes / mach["bandwidth"],
+            )
         else:
             r_pad = 0
-            read_masks = read_sizes = np.zeros((n_pad, 1))
+            read_masks = np.zeros((n_pad, 1), dtype=np.int64)
+            per_read = np.zeros((n_pad, 1))
 
         if want_s:
             w_indptr_full, w_ids_full, w_weights_full, accel_only = aff_src
@@ -404,7 +411,8 @@ class JaxScoringBackend:
         else:
             w_pad = 0
             accel_only = False
-            write_masks = write_weights = np.zeros((n_pad, 1))
+            write_masks = np.zeros((n_pad, 1), dtype=np.int64)
+            write_weights = np.zeros((n_pad, 1))
 
         want_c = p_cpu is not None
         if want_c:
@@ -421,94 +429,80 @@ class JaxScoringBackend:
         if fn is None:
             fn = self._build_matrix_fn(key)
             self._matrix_fns[key] = fn
+        enc = self.f64.encode
         C, X, X_max, S = fn(
-            jnp.asarray(read_masks), jnp.asarray(read_sizes),
-            jnp.asarray(write_masks), jnp.asarray(write_weights),
-            jnp.asarray(pc), jnp.asarray(pg), jnp.asarray(bias),
-            mach["mem_shift"], mach["col_bits"], mach["host_col"],
+            jnp.asarray(read_masks), jnp.asarray(enc(per_read)),
+            jnp.asarray(write_masks), jnp.asarray(enc(write_weights)),
+            jnp.asarray(enc(pc)), jnp.asarray(enc(pg)), jnp.asarray(enc(bias)),
+            mach["mem_shift"], mach["host_col"],
             mach["col_of"], mach["accel_res"],
-            jnp.float64(mach["latency"]), jnp.float64(mach["bandwidth"]),
         )
+        self.counts["device"] += 1
+        dec = self.f64.decode
         out = dict(C=None, C_np=None, C_dev=None, X_np=None,
                    X_rowmax=None, S_np=None)
         if want_c:
             out["C_dev"] = C
-            out["C_np"] = np.asarray(C)[:n]
+            out["C_np"] = dec(C)[:n]
             out["C"] = out["C_np"].tolist()
         if want_x and x_rows:
-            out["X_np"] = np.asarray(X)[:n]
+            out["X_np"] = dec(X)[:n]
         if want_x and not x_rows:
-            out["X_rowmax"] = np.asarray(X_max)[:n].tolist()
+            out["X_rowmax"] = dec(X_max)[:n].tolist()
         if want_s:
-            out["S_np"] = np.asarray(S)[:n]
+            out["S_np"] = dec(S)[:n]
         return out
 
     def _build_matrix_fn(self, key):
         (n_pad, r_pad, w_pad, n_u, n_res,
          want_x, x_rows, want_s, want_c, accel_only, want_bias) = key
         jax, jnp = self.jax, self.jnp
-        pallas_mode = self.pallas_mode
+        F = self.f64
 
-        def fn(read_masks, read_sizes, write_masks, write_weights,
-               p_cpu, p_gpu, x_bias, mem_shift, col_bits, host_col, col_of,
-               accel_res, latency, bandwidth):
+        def fn(read_masks, per_read, write_masks, write_weights,
+               p_cpu, p_gpu, x_bias, mem_shift, host_col, col_of, accel_res):
             X_res = None
             X_max = None
             if want_x:
-                per_read = jnp.where(
-                    read_sizes <= 0.0, 0.0, latency + read_sizes / bandwidth
+                # in-order fold over the read axis: bit-equal to the
+                # reduceat fold of the numpy matrix path (hops come
+                # straight off the full residency masks; the formula
+                # lives once, in repro.kernels.sched_score)
+                from repro.kernels.sched_score import transfer_matrix_from_full
+
+                X_u = transfer_matrix_from_full(
+                    read_masks, per_read, mem_shift, host_col, add=F.add
                 )
-                if pallas_mode != "off":
-                    from repro.kernels.sched_score import transfer_matrix_pallas
-
-                    compact = _compact_masks_jnp(
-                        jnp, read_masks, mem_shift
-                    )
-                    X_u = transfer_matrix_pallas(
-                        compact, per_read, col_bits, host_col,
-                        interpret=pallas_mode == "interpret",
-                    )
-                else:
-                    # in-order fold over the read axis: bit-equal to the
-                    # reduceat fold of the numpy matrix path (hops come
-                    # straight off the full residency masks; the formula
-                    # lives once, in repro.kernels.sched_score)
-                    from repro.kernels.sched_score import (
-                        transfer_matrix_from_full,
-                    )
-
-                    X_u = transfer_matrix_from_full(
-                        read_masks, per_read, mem_shift, host_col
-                    )
                 X_res = X_u[:, col_of]
                 if want_bias:
                     # memory-pressure penalty: the same host-computed
                     # addend the numpy path folds, applied before C and
                     # the per-row maxima derive from X
-                    X_res = X_res + x_bias
+                    X_res = F.add(X_res, x_bias)
                 if not x_rows:
                     # max is order-independent: equals max(row) on host
-                    X_max = jnp.max(X_res, axis=1)
+                    X_max = F.max(X_res, axis=1)
             S_res = None
             if want_s:
                 def wbody(r, acc):
                     m = write_masks[:, r][:, None]
                     resident = ((m >> mem_shift[None, :]) & 1) != 0
                     w = write_weights[:, r][:, None]
-                    return acc + jnp.where(resident, w, 0.0)
+                    return F.add(acc, jnp.where(resident, w, 0))
 
                 S_u = jax.lax.fori_loop(
-                    0, w_pad, wbody, jnp.zeros((n_pad, n_u), dtype=jnp.float64)
+                    0, w_pad, wbody,
+                    jnp.zeros((n_pad, n_u), dtype=write_weights.dtype),
                 )
                 S_res = S_u[:, col_of]
                 if accel_only:
-                    S_res = jnp.where(accel_res[None, :], S_res, 0.0)
+                    S_res = jnp.where(accel_res[None, :], S_res, 0)
             C = None
             if want_c:
                 base = jnp.where(
                     accel_res[None, :], p_gpu[:, None], p_cpu[:, None]
                 )
-                C = base + X_res if want_x else jnp.broadcast_to(
+                C = F.add(base, X_res) if want_x else jnp.broadcast_to(
                     base, (n_pad, n_res)
                 )
             return C, X_res, X_max, S_res
@@ -571,6 +565,7 @@ class JaxScoringBackend:
         pg[:n] = p_gpu
         valid = np.zeros(n_pad, dtype=bool)
         valid[:n] = True
+        F = self.f64
         # padded flex_order entries point at row 0; the search masks them
         # with the position-validity of `valid` (True exactly for k < n)
         ford = np.zeros(n_pad, dtype=np.int32)
@@ -615,34 +610,36 @@ class JaxScoringBackend:
             fn = self._build_search_fn(key)
             self._search_fns[key] = fn
         upper = fn(
-            jnp.asarray(offsets, dtype=jnp.float64),
+            jnp.asarray(F.encode(offsets)),
             C_dev,
-            jnp.asarray(pc), jnp.asarray(pg), jnp.asarray(valid),
-            jnp.asarray(ford),
-            jnp.asarray(chain_cost), jnp.asarray(chain_valid),
+            jnp.asarray(F.encode(pc)), jnp.asarray(F.encode(pg)),
+            jnp.asarray(valid), jnp.asarray(ford),
+            jnp.asarray(F.encode(chain_cost)), jnp.asarray(chain_valid),
             jnp.asarray(task_slot),
             jnp.asarray(cpu_idx), jnp.asarray(gpu_idx),
             jnp.bool_(no_cpus), jnp.bool_(no_gpus),
-            jnp.float64(alpha), jnp.float64(2.0 + alpha),
-            jnp.float64(area), jnp.float64(off_total), jnp.float64(max_off),
-            jnp.float64(float(n_res)),
-            jnp.float64(eps_rel), jnp.int32(max_iters), jnp.float64(upper0),
+            F.const(alpha), F.const(2.0 + alpha),
+            F.const(area), F.const(off_total), F.const(max_off),
+            F.const(float(n_res)),
+            F.const(eps_rel), jnp.int32(max_iters), F.const(upper0),
         )
-        return float(upper)
+        return float(F.decode(upper))
 
     def _build_search_fn(self, key):
         (n_pad, chain_pad, n_res, n_cpu, n_gpu,
          have_both, area_bound, depth) = key
         jax, jnp = self.jax, self.jnp
         lax = jax.lax
+        F = self.f64
+        add, sub, mul, lt, le = F.add, F.sub, F.mul, F.lt, F.le
         K = 2 ** depth - 1
-        INF = float("inf")
 
         def fn(loads0, C, p_cpu, p_gpu, valid, flex_ord,
                chain_cost, chain_valid, task_slot,
                cpu_idx, gpu_idx, no_cpus, no_gpus,
                alpha, two_alpha, area, off_total, max_off, n_res_f,
                eps_rel, max_iters, upper0):
+            TINY, INF, HALF = F.const(_TINY), F.const(float("inf")), F.const(0.5)
             # probe-invariant gathers, done once per search
             if have_both:
                 C_g = C[:, gpu_idx]
@@ -657,27 +654,27 @@ class JaxScoringBackend:
                 ``try_build(lam) is not None`` yields (early-exit order
                 differs, the verdict cannot: overflow flags are sticky and
                 loads accumulate through the same op sequence)."""
-                cap = two_alpha * lam + _TINY
-                bad = max_off > cap
+                cap = add(mul(two_alpha, lam), TINY)
+                bad = lt(cap, max_off)
                 if area_bound:
-                    bad = bad | (area > (lam * n_res_f - off_total) + _TINY)
+                    bad = bad | lt(add(sub(mul(lam, n_res_f), off_total), TINY), area)
                 loads = loads0
 
                 if chain_pad:
-                    budget = alpha * lam + _TINY
+                    budget = add(mul(alpha, lam), TINY)
 
                     def astep(carry, x):
                         loads, bad = carry
                         costs, av = x
-                        take = av & (loads <= budget)
-                        v = loads + costs
-                        bad = bad | jnp.any(take & (v > cap))
+                        take = av & le(loads, budget)
+                        v = add(loads, costs)
+                        bad = bad | jnp.any(take & lt(cap, v))
                         loads = jnp.where(take, v, loads)
                         return (loads, bad), take
 
                     (loads, bad), takes = lax.scan(
                         astep, (loads, bad), (chain_cost, chain_valid),
-                        unroll=min(_UNROLL, chain_pad),
+                        unroll=min(F.unroll, chain_pad),
                     )
                     flat = jnp.append(takes.reshape(-1), False)
                     assigned = flat[task_slot]
@@ -685,35 +682,34 @@ class JaxScoringBackend:
                     assigned = jnp.zeros((n_pad,), dtype=bool)
 
                 rem = valid & ~assigned
-                big_cpu = no_cpus | (p_cpu > lam)
-                big_gpu = no_gpus | (p_gpu > lam)
+                big_cpu = no_cpus | lt(lam, p_cpu)
+                big_gpu = no_gpus | lt(lam, p_gpu)
                 bad = bad | jnp.any(rem & big_cpu & big_gpu)
 
                 def balance(args):
                     loads, bad = args
                     if have_both:
-                        flex = rem & (p_cpu <= lam) & (p_gpu <= lam)
+                        flex = rem & le(p_cpu, lam) & le(p_gpu, lam)
                         ded = rem & ~flex
-                        ded_gpu = p_cpu > lam
+                        ded_gpu = lt(lam, p_cpu)
                         lanes = jnp.arange(n_res)
 
                         def dstep(carry, x):
                             loads, bad = carry
                             on, to_gpu, crow = x
                             pool = jnp.where(to_gpu, gpu_mask, cpu_mask)
-                            vm = jnp.where(pool, loads + crow, INF)
-                            # one-hot select: jnp.min equals vm[argmin]
-                            # bitwise, first-occurrence argmin keeps the
-                            # scalar tie-break
-                            hot = lanes == jnp.argmin(vm)
-                            bv = jnp.min(vm)
-                            bad = bad | (on & (bv > cap))
-                            loads = jnp.where(hot & on, bv, loads)
+                            vm = jnp.where(pool, add(loads, crow), INF)
+                            # first-occurrence argmin keeps the scalar
+                            # tie-break
+                            j = jnp.argmin(F.key(vm))
+                            bv = vm[j]
+                            bad = bad | (on & lt(cap, bv))
+                            loads = jnp.where((lanes == j) & on, bv, loads)
                             return (loads, bad), None
 
                         def ded_pass(args):
                             (loads, bad), _ = lax.scan(
-                                dstep, args, (ded, ded_gpu, C), unroll=_UNROLL
+                                dstep, args, (ded, ded_gpu, C), unroll=F.unroll
                             )
                             return loads, bad
 
@@ -727,7 +723,7 @@ class JaxScoringBackend:
                         # only ever takes the min over one class at a time
                         loads_g = loads[gpu_idx]
                         loads_c = loads[cpu_idx]
-                        gpu_budget = lam + _TINY
+                        gpu_budget = add(lam, TINY)
                         # `valid` is a position mask (True exactly for
                         # k < n), so it also masks padded flex positions
                         flex_o = flex[flex_ord] & valid
@@ -735,19 +731,19 @@ class JaxScoringBackend:
                         def fstep(carry, x):
                             loads_g, loads_c, bad = carry
                             on, crow_g, crow_c = x
-                            g = jnp.argmin(loads_g)
+                            g = jnp.argmin(F.key(loads_g))
                             gl = loads_g[g]
-                            use_gpu = on & (gl <= gpu_budget)
-                            vg = gl + crow_g[g]
-                            bad = bad | (use_gpu & (vg > cap))
+                            use_gpu = on & le(gl, gpu_budget)
+                            vg = add(gl, crow_g[g])
+                            bad = bad | (use_gpu & lt(cap, vg))
                             loads_g = loads_g.at[g].set(
                                 jnp.where(use_gpu, vg, gl)
                             )
-                            vm = loads_c + crow_c
-                            j = jnp.argmin(vm)
+                            vm = add(loads_c, crow_c)
+                            j = jnp.argmin(F.key(vm))
                             bv = vm[j]
                             use_eft = on & ~use_gpu
-                            bad = bad | (use_eft & (bv > cap))
+                            bad = bad | (use_eft & lt(cap, bv))
                             loads_c = loads_c.at[j].set(
                                 jnp.where(use_eft, bv, loads_c[j])
                             )
@@ -755,7 +751,7 @@ class JaxScoringBackend:
 
                         (loads_g, loads_c, bad), _ = lax.scan(
                             fstep, (loads_g, loads_c, bad),
-                            (flex_o, Cf_g, Cf_c), unroll=_UNROLL,
+                            (flex_o, Cf_g, Cf_c), unroll=F.unroll,
                         )
                         # `loads` is returned un-merged: only `bad` is read
                         # after the balance phase
@@ -765,17 +761,17 @@ class JaxScoringBackend:
                         def sstep(carry, x):
                             loads, bad = carry
                             on, crow = x
-                            vm = loads + crow
-                            j = jnp.argmin(vm)
+                            vm = add(loads, crow)
+                            j = jnp.argmin(F.key(vm))
                             bv = vm[j]
-                            bad = bad | (on & (bv > cap))
+                            bad = bad | (on & lt(cap, bv))
                             loads = loads.at[j].set(
                                 jnp.where(on, bv, loads[j])
                             )
                             return (loads, bad), None
 
                         (loads, bad), _ = lax.scan(
-                            sstep, (loads, bad), (rem, C), unroll=_UNROLL
+                            sstep, (loads, bad), (rem, C), unroll=F.unroll
                         )
                     return loads, bad
 
@@ -787,9 +783,11 @@ class JaxScoringBackend:
 
             feasible_grid = jax.vmap(lambda lam: ~verdict(lam))
 
+            def searching(lower, upper, it):
+                return lt(mul(eps_rel, upper), sub(upper, lower)) & (it < max_iters)
+
             def cond(state):
-                lower, upper, it = state
-                return (upper - lower > eps_rel * upper) & (it < max_iters)
+                return searching(*state)
 
             def body(state):
                 lower, upper, it = state
@@ -803,7 +801,8 @@ class JaxScoringBackend:
                 mid = [None] * K
                 lo[0], hi[0] = lower, upper
                 for k in range(K):
-                    mid[k] = (lo[k] + hi[k]) / 2.0
+                    # (lo + hi) / 2: halving is exact, so * 0.5 is the same
+                    mid[k] = mul(add(lo[k], hi[k]), HALF)
                     if 2 * k + 2 < K:
                         lo[2 * k + 1], hi[2 * k + 1] = lo[k], mid[k]
                         lo[2 * k + 2], hi[2 * k + 2] = mid[k], hi[k]
@@ -819,7 +818,7 @@ class JaxScoringBackend:
                 # rule before each (exactly like the Python while loop)
                 idx = jnp.int32(0)
                 for _ in range(depth):
-                    go = (upper - lower > eps_rel * upper) & (it < max_iters)
+                    go = searching(lower, upper, it)
                     safe = jnp.minimum(idx, K - 1)
                     f = feas[safe]
                     lam = mids[safe]
@@ -830,7 +829,7 @@ class JaxScoringBackend:
                 return lower, upper, it
 
             _, upper, _ = lax.while_loop(
-                cond, body, (jnp.float64(0.0), upper0, jnp.int32(0))
+                cond, body, (F.const(0.0), upper0, jnp.int32(0))
             )
             return upper
 
@@ -855,6 +854,7 @@ class JaxScoringBackend:
         scalar loop in ``heft.place`` computes.
         """
         jnp = self.jnp
+        F = self.f64
         n, n_res = D_ord.shape
         n_pad = _bucket(n)
         D = np.zeros((n_pad, n_res), dtype=np.float64)
@@ -869,63 +869,54 @@ class JaxScoringBackend:
             fn = self._build_heft_fn(key)
             self._heft_fns[key] = fn
         rids, efts = fn(
-            jnp.asarray(D), jnp.asarray(X), jnp.asarray(valid),
-            jnp.asarray(load_ts, dtype=jnp.float64), jnp.float64(now),
+            jnp.asarray(F.encode(D)), jnp.asarray(F.encode(X)),
+            jnp.asarray(valid), jnp.asarray(F.encode(load_ts)), F.const(now),
         )
-        return np.asarray(rids)[:n], np.asarray(efts)[:n]
+        return np.asarray(rids)[:n], F.decode(efts)[:n]
 
     def _build_heft_fn(self, key):
         n_pad, n_res = key
         jax, jnp = self.jax, self.jnp
-        INF = float("inf")
+        F = self.f64
+        add, lt = F.add, F.lt
 
         def fn(D, X, valid, load_ts, now):
+            INF, EPS = F.const(float("inf")), F.const(1e-15)
+
             def step(lts, x):
                 drow, xrow, on = x
-                start = jnp.where(now > lts, now, lts)
-                eft = (start + xrow) + drow
+                start = jnp.where(lt(lts, now), now, lts)
+                eft = add(add(start, xrow), drow)
                 # the 1e-15 strict-improvement rule is a left fold over the
                 # resource lanes; n_res is small and static, so unroll it
                 # into scalar selects (no fori machinery per task)
-                if n_res <= 64:
-                    bv = jnp.float64(INF)
+                if n_res <= 64 and F.unroll > 1:
+                    bv = INF
                     bj = jnp.int32(0)
                     for r in range(n_res):
                         e = eft[r]
-                        upd = e < bv - 1e-15
+                        upd = lt(e, F.sub(bv, EPS))
                         bv = jnp.where(upd, e, bv)
                         bj = jnp.where(upd, jnp.int32(r), bj)
                 else:
                     def rstep(r, st):
                         bv, bj = st
                         e = eft[r]
-                        upd = e < bv - 1e-15
+                        upd = lt(e, F.sub(bv, EPS))
                         return (
                             jnp.where(upd, e, bv),
                             jnp.where(upd, r, bj),
                         )
 
                     bv, bj = jax.lax.fori_loop(
-                        0, n_res, rstep, (jnp.float64(INF), jnp.int32(0))
+                        0, n_res, rstep, (INF, jnp.int32(0))
                     )
                 lts = lts.at[bj].set(jnp.where(on, bv, lts[bj]))
                 return lts, (bj, bv)
 
             _, (rids, efts) = jax.lax.scan(
-                step, load_ts, (D, X, valid), unroll=_UNROLL
+                step, load_ts, (D, X, valid), unroll=F.unroll
             )
             return rids, efts
 
         return jax.jit(fn)
-
-
-def _compact_masks_jnp(jnp, full_masks, mem_shift):
-    """int32 residency codes from full int64 masks (Pallas-kernel input):
-    bit 0 = host copy, bit u+1 = a valid copy at unique memory u."""
-    out = (full_masks & 1).astype(jnp.int32)
-    n_u = mem_shift.shape[0]
-    for u in range(n_u):
-        out = out | (
-            ((full_masks >> mem_shift[u]) & 1).astype(jnp.int32) << (u + 1)
-        )
-    return out

@@ -481,8 +481,10 @@ class DADA(ScoringBackendMixin, Strategy):
                 upper = lam_final
                 kept = built
                 searched = True
-            # else: defensive — a divergent verdict would leave an
-            # infeasible λ; fall back to the Python search below
+            else:
+                # defensive — a divergent verdict would leave an
+                # infeasible λ; counted, then the Python search below
+                be.counts["rejected"] += 1
         if not searched:
             it = 0
             while upper - lower > self.eps_rel * upper and it < self.max_iters:

@@ -48,7 +48,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.backend import _bucket
+from repro.core.backend import _bucket, enable_compile_cache
 from repro.core.dag import TaskGraph
 from repro.core.machine import HOST_MEM, MachineModel
 
@@ -314,54 +314,21 @@ def machine_axes(
 # the compiled episode: lax.scan over steps, batch axis across configs
 
 _EPISODE_CACHE: Dict[tuple, object] = {}
-_DISK_CACHE_SET = False
 
 
-def _enable_disk_cache() -> None:
-    """Point jax's persistent compilation cache at a stable directory.
-
-    The episode jit compiles in ~1-2s per (kernel, shape) — the dominant
-    cost of a cold fast-validation run. The persistent cache makes every
-    later process start warm. Respects an explicit
-    ``JAX_COMPILATION_CACHE_DIR`` (read through ``SchedConfig`` — this
-    module does not touch ``os.environ``); best-effort otherwise.
-    """
-    global _DISK_CACHE_SET
-    if _DISK_CACHE_SET:
-        return
-    _DISK_CACHE_SET = True
-    import os
-    import tempfile
-
-    try:
-        import jax
-
-        from repro.sched.config import current_config
-
-        cache_dir = current_config().jax_cache_dir
-        if not cache_dir:
-            cache_dir = os.path.join(tempfile.gettempdir(), "repro-jax-cache")
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
-    except Exception:
-        pass  # older jax or read-only tmp: compiles stay in-process only
-
-
-def _pallas_mode(config) -> Tuple[bool, bool]:
-    """(use_pallas, interpret) from the validated config."""
-    import jax
-
-    mode = config.pallas
-    platform = jax.default_backend()
+def _pallas_mode(mode: str, platform: str) -> str:
+    """Transfer-row route from ``REPRO_SCHED_PALLAS`` and the platform:
+    ``native`` on gpu/tpu (auto or forced), ``interpret`` only when forced
+    on cpu, else ``off`` (the XLA fold). A TPU never interprets."""
     if mode in ("0", "off", "false"):
-        return False, False
-    if mode == "1":
-        return True, platform == "cpu"
-    return platform in ("gpu", "tpu"), False  # auto: native only
+        return "off"
+    if platform == "cpu":
+        return "interpret" if mode == "1" else "off"
+    return "native"
 
 
 def _build_episode_fn(shape_key: tuple):
-    _enable_disk_cache()
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
 
@@ -672,7 +639,8 @@ def run_episodes(
     if B_pad < B:
         raise ValueError(f"pad_to={B_pad} smaller than batch ({B})")
     use_cap = bool(np.isfinite(batch.cap).any())
-    use_pallas, interpret = _pallas_mode(config)
+    mode = _pallas_mode(config.pallas, jax.default_backend())
+    use_pallas, interpret = mode != "off", mode == "interpret"
     n_steps = plan.n + int(extra_steps)
 
     def padb(a: np.ndarray, fill=0) -> np.ndarray:
@@ -727,6 +695,36 @@ def run_episodes(
         out["schedule"] = {
             name: np.asarray(col)[:, :B].T for name, col in zip(names, res[3])
         }
+    return out
+
+
+def ranking_mismatches(
+    oracle: Dict[str, Tuple[float, ...]],
+    surrogate: Dict[str, Tuple[float, ...]],
+    axis: int,
+    specs: Sequence[str],
+    margin: float = 0.10,
+) -> List[str]:
+    """The surrogate's ranking contract against the exact engine.
+
+    ``oracle`` / ``surrogate`` map a policy spec to its mean metrics
+    (makespan, total bytes, ...). Every pair the oracle separates by more
+    than ``margin`` (relative) must be ordered alike on ``axis``; closer
+    pairs are near-ties inside the surrogate's documented relative error
+    and impose nothing. Returns one message per misordered pair.
+    """
+    out = []
+    for i, a in enumerate(specs):
+        for b in specs[i + 1:]:
+            oa, ob = oracle[a][axis], oracle[b][axis]
+            if abs(oa - ob) <= margin * max(abs(oa), abs(ob)):
+                continue
+            sa, sb = surrogate[a][axis], surrogate[b][axis]
+            if (oa < ob) != (sa < sb):
+                out.append(
+                    f"oracle orders {a} vs {b} as {oa:.4g} vs {ob:.4g} "
+                    f"but surrogate says {sa:.4g} vs {sb:.4g}"
+                )
     return out
 
 

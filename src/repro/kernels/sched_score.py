@@ -16,30 +16,34 @@ reduces its (bt × r_pad) read block into a (bt × n_u) output block. The
 reduction is an **in-order fori fold over the read axis**, so every output
 entry is bit-equal to the scalar reference in ``repro.core._reference``
 (padded reads carry mask 0 → hops 0 → exact +0.0). ``transfer_matrix_jnp``
-is the XLA fallback with the identical fold — the CPU path of the jax
-scheduling backend, and the reference the Pallas kernel is tested against
-(interpret mode on CPU).
+is the XLA form of the identical fold and the reference the Pallas kernel
+is tested against (interpret mode on CPU).
 
-TPU note: f64 is unsupported on real TPUs; deploying there means f32
-scores, which relaxes the bit-for-bit guarantee to decision-equality (the
-backend keeps the numpy path authoritative for the final build either way).
+Dtypes: the kernel lowers for the TPU in f32 only (Mosaic has no f64), which
+is what the surrogate episodes of ``repro.core.episode`` feed it. The f64
+scores of the jax scheduling backend go through the XLA fold
+(``transfer_matrix_from_full``) on every platform.
 """
 from __future__ import annotations
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _hop_fold(masks, per_read, resident_of, host_col, n_u):
+def _hop_fold(masks, per_read, resident_of, host_col, n_u, add=operator.add):
     """Shared in-order read fold: the single home of the hop formula.
 
     ``resident_of(r)`` returns the (n_pad, n_u) residency booleans of read
     column r; everything else (host short-circuit, 2-hop device→device,
     nowhere-yet data) is identical for the compact- and full-mask callers,
-    so the bit-for-bit-critical arithmetic lives exactly once.
+    so the bit-for-bit-critical arithmetic lives exactly once. A read
+    costs 0, 1 or 2 one-hop times, and ``p + p`` is ``2 p`` exactly, so
+    the fold needs nothing but ``add`` (which may be an integer-exact f64
+    add over bit patterns, see ``repro.core.f64``).
     """
     on_host = (masks & 1) != 0
     nowhere = masks == 0
@@ -47,16 +51,9 @@ def _hop_fold(masks, per_read, resident_of, host_col, n_u):
 
     def body(r, acc):
         skip = resident_of(r) | nowhere[:, r][:, None]
-        hops = jnp.where(
-            skip,
-            0.0,
-            jnp.where(
-                host_col[None, :],
-                1.0,
-                jnp.where(on_host[:, r][:, None], 1.0, 2.0),
-            ),
-        )
-        return acc + hops * per_read[:, r][:, None]
+        one_hop = host_col[None, :] | on_host[:, r][:, None]
+        p = per_read[:, r][:, None]
+        return add(acc, jnp.where(skip, 0, jnp.where(one_hop, p, add(p, p))))
 
     return jax.lax.fori_loop(
         0, masks.shape[1], body, jnp.zeros((n_pad, n_u), dtype=per_read.dtype)
@@ -82,13 +79,14 @@ def transfer_matrix_from_full(
     per_read: jax.Array,  # (n_pad, r_pad) per-read transfer times
     mem_shift: jax.Array,  # (n_u,) int64, mem+1 shift per unique memory
     host_col: jax.Array,  # (n_u,) bool, True where unique mem u is the host
+    add=operator.add,
 ) -> jax.Array:
-    """Same fold straight off the full int64 residency masks — the CPU
-    path of the jax scheduling backend (no compact remap needed)."""
+    """Same fold straight off the full int64 residency masks — the
+    transfer fold of the jax scheduling backend (no compact remap)."""
     return _hop_fold(
         masks, per_read,
         lambda r: ((masks[:, r][:, None] >> mem_shift[None, :]) & 1) != 0,
-        host_col, mem_shift.shape[0],
+        host_col, mem_shift.shape[0], add,
     )
 
 
@@ -97,19 +95,19 @@ def _xfer_kernel(masks_ref, pr_ref, bits_ref, host_ref, out_ref, *, r_pad):
     pr = pr_ref[...]
     bits = bits_ref[...]  # (1, n_u)
     hostc = host_ref[...] != 0  # (1, n_u)
-    on_host = (masks & 1) != 0
-    nowhere = masks == 0
     bt, n_u = out_ref.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, masks.shape, 1)
 
     def body(r, acc):
-        m = jax.lax.dynamic_slice_in_dim(masks, r, 1, axis=1)  # (bt, 1)
-        resident = (m & bits) != 0  # (bt, n_u)
-        skip = resident | jax.lax.dynamic_slice_in_dim(nowhere, r, 1, axis=1)
-        oh = jax.lax.dynamic_slice_in_dim(on_host, r, 1, axis=1)
+        # column r through an iota mask: Mosaic has no dynamic_slice, and
+        # a one-hot sum returns the selected entry exactly
+        sel = lane == r
+        m = jnp.sum(jnp.where(sel, masks, 0), axis=1, keepdims=True)  # (bt, 1)
+        prr = jnp.sum(jnp.where(sel, pr, 0.0), axis=1, keepdims=True)
+        skip = ((m & bits) != 0) | (m == 0)  # (bt, n_u)
         hops = jnp.where(
-            skip, 0.0, jnp.where(hostc, 1.0, jnp.where(oh, 1.0, 2.0))
+            skip, 0.0, jnp.where(hostc | ((m & 1) != 0), 1.0, 2.0)
         ).astype(pr.dtype)
-        prr = jax.lax.dynamic_slice_in_dim(pr, r, 1, axis=1)
         return acc + hops * prr
 
     out_ref[...] = jax.lax.fori_loop(
@@ -131,10 +129,15 @@ def transfer_matrix_pallas(
 
     ``bt`` tiles the task axis; reads and memory columns stay whole per
     program (r_pad and n_u are small — a handful of reads per task, ≤ ~32
-    memory spaces). ``interpret=True`` runs on CPU for testing.
+    memory spaces). ``interpret=True`` runs on CPU for testing, where any
+    float dtype works.
     """
     n_pad, r_pad = masks.shape
     n_u = col_bits.shape[0]
+    if not interpret and per_read.dtype != jnp.float32:
+        raise TypeError(
+            f"transfer_matrix_pallas lowers for f32 only, got {per_read.dtype}"
+        )
     bt = min(bt, n_pad)
     assert n_pad % bt == 0, (n_pad, bt)
     grid = (n_pad // bt,)

@@ -158,8 +158,6 @@ def build_and_compile(
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):  # older jax returned [dict]
-        cost = cost[0] if cost else {}
     coll = collective_bytes(compiled.as_text())
     ana = cell_cost(cfg, shape, micro_batches=micro)
 

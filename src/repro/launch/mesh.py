@@ -7,26 +7,13 @@ that the sharding rules treat as pure data parallelism.
 """
 from __future__ import annotations
 
-import numpy as np
-
 import jax
 
 
 def _mesh(shape, axes, devices):
-    """Version-tolerant mesh construction.
-
-    ``jax.make_mesh(..., axis_types=AxisType.Auto)`` only exists on recent
-    jax; older releases spell the same thing as a plain ``Mesh`` over a
-    reshaped device array (Auto is their only behavior).
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, devices=devices,
-            axis_types=(axis_type.Auto,) * len(axes),
-        )
-    return jax.sharding.Mesh(
-        np.asarray(devices, dtype=object).reshape(shape), axes
+    return jax.make_mesh(
+        shape, axes, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
     )
 
 

@@ -12,19 +12,21 @@ from typing import Optional
 
 from repro.core.dag import Mode, TaskGraph
 
-from .tiles import make_tile_objects
+from .tiles import f32_precise, make_tile_objects
 
 # jax is imported inside the tile kernels: the scheduler-only path
 # (with_fns=False, used by every benchmark sweep) never pays the ~0.8s
 # jax import.
 
 
+@f32_precise
 def _potrf(a_kk):
     import jax.numpy as jnp
 
     return (jnp.linalg.cholesky(a_kk),)
 
 
+@f32_precise
 def _trsm(l_kk, a_ik):
     import jax
 
@@ -33,10 +35,12 @@ def _trsm(l_kk, a_ik):
     return (x.T,)
 
 
+@f32_precise
 def _syrk(a_ik, a_ii):
     return (a_ii - a_ik @ a_ik.T,)
 
 
+@f32_precise
 def _gemm(a_ik, a_jk, a_ij):
     return (a_ij - a_ik @ a_jk.T,)
 

@@ -14,9 +14,10 @@ from __future__ import annotations
 
 from repro.core.dag import Mode, TaskGraph
 
-from .tiles import make_tile_objects
+from .tiles import f32_precise, make_tile_objects
 
 
+@f32_precise
 def _getrf(a_kk):
     """No-pivot in-tile LU: returns packed L\\U (unit lower not stored)."""
     import jax
@@ -44,6 +45,7 @@ def _split_lu(packed):
     return l, u
 
 
+@f32_precise
 def _gessm(a_kk, a_kj):
     import jax
 
@@ -51,6 +53,7 @@ def _gessm(a_kk, a_kj):
     return (jax.scipy.linalg.solve_triangular(l, a_kj, lower=True, unit_diagonal=True),)
 
 
+@f32_precise
 def _tstrf(a_kk, a_ik):
     import jax
 
@@ -60,6 +63,7 @@ def _tstrf(a_kk, a_ik):
     return (x.T,)
 
 
+@f32_precise
 def _ssssm(a_ik, a_kj, a_ij):
     return (a_ij - a_ik @ a_kj,)
 

@@ -14,9 +14,10 @@ from __future__ import annotations
 
 from repro.core.dag import DataObject, Mode, TaskGraph
 
-from .tiles import make_tile_objects, tile_name
+from .tiles import f32_precise, make_tile_objects, tile_name
 
 
+@f32_precise
 def _geqrt(a_kk):
     import jax.numpy as jnp
 
@@ -24,10 +25,12 @@ def _geqrt(a_kk):
     return (r, q)  # writes: A[k,k] <- R, T[k,k] <- Q
 
 
+@f32_precise
 def _ormqr(q_kk, a_kj):
     return (q_kk.T @ a_kj,)
 
 
+@f32_precise
 def _tsqrt(a_kk, a_ik):
     import jax.numpy as jnp
 
@@ -37,6 +40,7 @@ def _tsqrt(a_kk, a_ik):
     return (r[:b], jnp.zeros_like(a_ik), q)  # A[k,k]<-R, A[i,k]<-0, T[i,k]<-Q
 
 
+@f32_precise
 def _tsmqr(q_ik, a_kj, a_ij):
     import jax.numpy as jnp
 

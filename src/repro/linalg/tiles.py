@@ -1,6 +1,7 @@
 """Tiled-matrix helpers (PLASMA-style square tiles)."""
 from __future__ import annotations
 
+import functools
 from typing import Dict, Tuple
 
 import numpy as np
@@ -52,28 +53,61 @@ def join_tiles(tiles: Dict[str, "jnp.ndarray"], nt: int, tile: int) -> "jnp.ndar
     return jnp.concatenate(rows, axis=0)
 
 
+def f32_precise(body):
+    """Run a tile body with its matmuls at full f32 precision.
+
+    The TPU's default f32 dot is one bf16 pass, which the f32 rounding-error
+    bounds of the factorisations do not cover; on the CPU, f32 dots are f32
+    already and this changes nothing.
+    """
+
+    @functools.wraps(body)
+    def wrapper(*args):
+        import jax
+
+        with jax.default_matmul_precision("highest"):
+            return body(*args)
+
+    return wrapper
+
+
+def _normals(n: int, seed: int) -> np.ndarray:
+    return np.random.default_rng(seed).standard_normal((n, n), dtype=np.float32)
+
+
 def random_spd(n: int, seed: int = 0, dtype=None) -> "jnp.ndarray":
-    """Symmetric positive-definite test matrix."""
+    """Symmetric positive-definite test matrix ``G Gᵀ/n + n I``.
+
+    ``G`` is drawn in bulk on the host; the product is formed on the
+    device at full f32 precision (an N=16384 matrix is 1 GiB in f32) and
+    symmetrised exactly — a blocked product need not round (i, j) and
+    (j, i) alike, and the factorisations read one triangle only.
+    """
+    import jax
     import jax.numpy as jnp
 
-    rng = np.random.default_rng(seed)
-    a = rng.standard_normal((n, n))
-    spd = a @ a.T / n + np.eye(n) * n
-    return jnp.asarray(spd, dtype=dtype or jnp.float64)
+    g = jnp.asarray(_normals(n, seed), dtype=dtype or jnp.float32)
+    return jax.jit(_spd_of, static_argnums=1)(g, n)
+
+
+@f32_precise
+def _spd_of(g, n):
+    import jax.numpy as jnp
+
+    s = g @ g.T / n
+    return (s + s.T) / 2 + n * jnp.eye(n, dtype=g.dtype)
 
 
 def random_dd(n: int, seed: int = 0, dtype=None) -> "jnp.ndarray":
     """Diagonally-dominant matrix (safe for no-pivot LU)."""
     import jax.numpy as jnp
 
-    rng = np.random.default_rng(seed)
-    a = rng.standard_normal((n, n))
-    a = a + np.eye(n) * (np.abs(a).sum(axis=1).max() + n)
-    return jnp.asarray(a, dtype=dtype or jnp.float64)
+    a = _normals(n, seed)
+    a = a + np.eye(n, dtype=a.dtype) * (np.abs(a).sum(axis=1).max() + n)
+    return jnp.asarray(a, dtype=dtype or jnp.float32)
 
 
 def random_dense(n: int, seed: int = 0, dtype=None) -> "jnp.ndarray":
     import jax.numpy as jnp
 
-    rng = np.random.default_rng(seed)
-    return jnp.asarray(rng.standard_normal((n, n)), dtype=dtype or jnp.float64)
+    return jnp.asarray(_normals(n, seed), dtype=dtype or jnp.float32)

@@ -111,7 +111,8 @@ class SchedConfig:
     - ``jax_min``: ready-set width from which the jax path engages.
     - ``lambda_depth``: speculative λ-bisection depth (``None`` = platform
       default: 1 on cpu, 5 on gpu/tpu), clamped to [1, 8].
-    - ``pallas``: Pallas transfer-kernel mode (``auto``/``1``/``0``).
+    - ``pallas``: Pallas transfer-kernel mode of the surrogate episodes
+      (``auto``/``1``/``0``; see ``repro.core.episode``).
     - ``mem_capacity``: device-memory capacity in bytes (0 = unbounded,
       the default; see ``repro.runtime.memory``).
     - ``eviction``: victim-selection policy under capacity pressure,
@@ -165,10 +166,6 @@ class SchedConfig:
       evictions and fault windows, consumed by the independent schedule
       verifier. Off by default — audit-off runs are bit-for-bit
       identical to pre-audit behavior (see docs/verification.md).
-    - ``jax_cache_dir``: mirror of ``JAX_COMPILATION_CACHE_DIR`` (the one
-      non-``REPRO_*`` variable this config owns), so the surrogate
-      engine's persistent-compilation-cache setup reads it from here
-      instead of touching ``os.environ`` itself.
     - ``batch``: per-dispatch batch-size cap for the surrogate engine
       (``api.run_batch`` splits larger sweeps into chunks of this many
       configurations).
@@ -202,7 +199,6 @@ class SchedConfig:
     rescore: str = "off"
     admit_defer_s: float = 0.005
     audit: bool = False
-    jax_cache_dir: Optional[str] = None
     batch: int = 256
     bench_backends: Optional[Tuple[str, ...]] = None
     regression_tol: float = 0.25
@@ -331,12 +327,6 @@ class SchedConfig:
                 "unknown scheduling configuration variable(s): "
                 f"{', '.join(sorted(unknown))} (known: {known})"
             )
-        # non-REPRO-prefixed variables this config mirrors (jax owns the
-        # name; we only read it so sched/config.py stays the single env
-        # source and the repo lint needs no exception for episode.py)
-        raw = env.get("JAX_COMPILATION_CACHE_DIR")
-        if raw:
-            kw["jax_cache_dir"] = raw
         return cls(**kw)
 
     def env_items(self) -> Tuple[Tuple[str, str], ...]:
@@ -403,8 +393,6 @@ _ENV_SCHEMA = {
 }
 
 _FIELD_TO_ENV = {field: var for var, (field, _) in _ENV_SCHEMA.items()}
-# mirrored non-REPRO variables (special-cased in from_env)
-_FIELD_TO_ENV["jax_cache_dir"] = "JAX_COMPILATION_CACHE_DIR"
 
 KNOWN_ENV_VARS: Tuple[str, ...] = tuple(sorted(_ENV_SCHEMA))
 
@@ -420,9 +408,7 @@ def _env_snapshot() -> Tuple[Tuple[str, str], ...]:
         sorted(
             (k, v)
             for k, v in os.environ.items()
-            if k.startswith(SCHED_PREFIX)
-            or k.startswith(BENCH_PREFIX)
-            or k == "JAX_COMPILATION_CACHE_DIR"
+            if k.startswith(SCHED_PREFIX) or k.startswith(BENCH_PREFIX)
         )
     )
 

@@ -1,7 +1,8 @@
 """The jax placement-scoring backend must be a pure speed refactor:
 decisions, λ trajectories and score values bit-identical to the numpy
-path, lazy fallback when jax is unavailable, bounded jit retraces via
-padded shapes, and a Pallas transfer kernel that matches the XLA fold."""
+path, a hard failure when the jax backend cannot be built, bounded jit
+retraces via padded shapes, and a Pallas transfer kernel that matches the
+XLA fold."""
 import numpy as np
 import pytest
 
@@ -258,34 +259,50 @@ def test_min_wide_env(monkeypatch):
 
 
 def test_missing_jax_falls_back_with_warning(monkeypatch):
-    """A broken/missing jax must degrade to the numpy path (satellite:
-    numpy-only environments keep passing tier-1) with one warning."""
+    """A jax backend that cannot be built raises — every resolution, and
+    through a strategy that asked for it — instead of degrading to the
+    numpy path behind the caller's back."""
     import repro.core.backend as backend_mod
 
     class _Broken:
-        def __init__(self):
+        def __init__(self, config=None):
             raise ImportError("no module named jax (simulated)")
 
     _reset_backend_cache()
     monkeypatch.setattr(backend_mod, "JaxScoringBackend", _Broken)
     try:
-        with pytest.warns(RuntimeWarning, match="falling back"):
-            assert get_backend("jax") is None
-        # second resolution: silent, still numpy
-        assert get_backend("jax") is None
-        # simulations still run (and match numpy bit-for-bit, trivially)
-        machine = paper_machine(2)
-        a = run_simulation(
-            cholesky_graph(4, 256, with_fns=False), machine,
-            DADA(alpha=0.5, backend="jax"), seed=0,
-        )
-        b = run_simulation(
-            cholesky_graph(4, 256, with_fns=False), machine,
-            DADA(alpha=0.5, backend="numpy"), seed=0,
-        )
-        assert _fingerprint(a) == _fingerprint(b)
+        with pytest.raises(ImportError, match="simulated"):
+            get_backend("jax")
+        # nothing was cached: the second resolution raises again
+        with pytest.raises(ImportError, match="simulated"):
+            get_backend("jax")
+        with pytest.raises(ImportError, match="simulated"):
+            run_simulation(
+                cholesky_graph(4, 256, with_fns=False), paper_machine(2),
+                DADA(alpha=0.5, backend="jax"), seed=0,
+            )
+        # the numpy path needs no jax backend at all
+        assert get_backend("numpy") is None
     finally:
         _reset_backend_cache()
+
+
+def test_get_backend_jax_is_real(force_jax):
+    """On the installed jax the backend builds, and a wide activation is
+    scored on the device rather than handed back to numpy."""
+    from repro.core.backend import JaxScoringBackend
+
+    be = get_backend("jax")
+    assert isinstance(be, JaxScoringBackend)
+    assert be.platform == jax.default_backend()
+    before = dict(be.counts)
+    run_simulation(
+        cholesky_graph(5, 256, with_fns=False), paper_machine(3),
+        DADA(alpha=0.5, use_cp=True, backend="jax"), seed=0,
+    )
+    assert be.counts["device"] > before["device"]
+    assert be.counts["outside"] == before["outside"]
+    assert be.counts["rejected"] == before["rejected"]
 
 
 def test_backend_does_not_leak_x64(force_jax):

@@ -136,3 +136,29 @@ def test_sched_regression_gate(monkeypatch, tmp_path, capsys):
     base.unlink()
     assert gate.main() == 0
     capsys.readouterr()
+
+
+def test_jax_work_never_forks(monkeypatch):
+    """A forked worker never touches JAX: jax-scored strategies, and any
+    run once this process holds an accelerator, stay in-process (with
+    summaries identical to the pool's)."""
+    import repro.core.backend as backend_mod
+    from repro.core import pool_allowed
+
+    assert pool_allowed([partial(DADA, alpha=0.5)])
+    assert not pool_allowed([partial(DADA, alpha=0.5, backend="jax")])
+    monkeypatch.setenv("REPRO_SCHED_BACKEND", "jax")
+    assert not pool_allowed([partial(DADA, alpha=0.5)])
+    monkeypatch.delenv("REPRO_SCHED_BACKEND")
+    monkeypatch.setattr(backend_mod, "accelerator_initialised", lambda: True)
+    assert not pool_allowed([partial(DADA, alpha=0.5)])
+
+    machine = paper_machine(2)
+    gfac = partial(cholesky_graph, 4, 256, with_fns=False)
+    sfac = partial(DADA, alpha=0.5)
+    pools = []
+    monkeypatch.setattr("repro.core.api._get_pool", pools.append)
+    assert run_many(gfac, machine, sfac, n_runs=4, n_jobs=2) == run_many(
+        gfac, machine, sfac, n_runs=4, n_jobs=1
+    )
+    assert pools == []  # the pool was never asked for

@@ -89,17 +89,8 @@ def _assert_separated_pairs_ordered_alike(
 ):
     """Every pair the oracle clearly separates, the surrogate orders the
     same way; oracle near-ties impose nothing."""
-    for i, a in enumerate(specs):
-        for b in specs[i + 1:]:
-            oa, ob = oracle[a][axis], oracle[b][axis]
-            if abs(oa - ob) <= MARGIN * max(abs(oa), abs(ob)):
-                continue
-            sa, sb = surrogate[a][axis], surrogate[b][axis]
-            assert (oa < ob) == (sa < sb), (
-                f"{label}: oracle orders {a} vs {b} as "
-                f"{oa:.4g} vs {ob:.4g} but surrogate says "
-                f"{sa:.4g} vs {sb:.4g}"
-            )
+    bad = ep.ranking_mismatches(oracle, surrogate, axis, specs, MARGIN)
+    assert not bad, f"{label}: " + "; ".join(bad)
 
 
 @pytest.mark.parametrize("kernel", sorted(KERNELS))

@@ -27,10 +27,7 @@ def test_xla_cost_analysis_counts_scan_body_once():
         return x
 
     def _flops(compiled):
-        ca = compiled.cost_analysis()
-        if isinstance(ca, list):  # older jax returned [dict]
-            ca = ca[0]
-        return ca["flops"]
+        return compiled.cost_analysis()["flops"]
 
     x = jnp.zeros((128, 128))
     f1 = _flops(jax.jit(f_scan).lower(x).compile())

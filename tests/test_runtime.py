@@ -358,10 +358,6 @@ def _wide_wave(graph):
 @pytest.mark.parametrize("spec", ["dada?alpha=0.5&use_cp=1", "heft"])
 def test_jax_backend_pressure_fold_matches_numpy(spec):
     pytest.importorskip("jax")
-    from repro.core.backend import get_backend
-
-    if get_backend("jax") is None:
-        pytest.skip("jax backend unavailable")
     graph = cholesky_graph(10, 256, with_fns=False)
     wave = _wide_wave(graph)
     assert len(wave) >= 32  # wide enough for the jax path to engage

@@ -1,0 +1,179 @@
+"""Compile the scheduler's device path for a described TPU v5e chip.
+
+Nothing runs: each program is lowered and compiled by the TPU compiler for
+one chip of a ``v5e:2x2`` topology that is described, not attached, at the
+widths ``chip_smoke.py`` uses. A kernel that interpret mode accepts but
+the chip's compiler refuses fails here, at no chip time. The topology is
+described inside a fixture (never at import), and the persistent
+compilation cache is off around the compiles: a program compiled for a
+described chip cannot be read back from it.
+"""
+import os
+from functools import partial
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.paper_machine import paper_machine, scaled_machine
+from repro.core import DADA, HEFT, Simulator
+from repro.core import episode as ep
+from repro.core import f64
+from repro.core.backend import JaxScoringBackend
+from repro.linalg import cholesky
+from repro.linalg.cholesky import cholesky_graph
+from repro.sched.config import SchedConfig
+
+# phase (a) of chip_smoke.py: Cholesky NT=32 at tile 512 on 32 resources
+NT, TILE = 32, 512
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, args, sharding):
+    sds = [jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype, sharding=sharding)
+           for a in args]
+    return jax.jit(fn).lower(*sds).compile()
+
+
+def _recording(build, calls):
+    """Wrap a jit factory so the function it makes records its arguments."""
+
+    def wrapped(key):
+        fn = build(key)
+
+        def rec(*args):
+            calls.append((key, fn, args))
+            return fn(*args)
+
+        return rec
+
+    return wrapped
+
+
+def _wide_wave(graph):
+    """The widest same-depth task set: the trailing-update wave."""
+    depth = [0] * len(graph)
+    for t in graph.tasks:
+        preds = graph.pred[t.tid]
+        depth[t.tid] = max((depth[p] + 1 for p in preds), default=0)
+    widest = max(set(depth), key=depth.count)
+    return [t for t in graph.tasks if depth[t.tid] == widest]
+
+
+@pytest.fixture(scope="module")
+def backend_calls():
+    """One wide activation of DADA+cp and of HEFT on the CPU, with the
+    backend's jitted functions and their real arguments recorded."""
+    graph = cholesky_graph(NT, TILE, with_fns=False)
+    machine = scaled_machine()
+    wave = _wide_wave(graph)
+    be = JaxScoringBackend(SchedConfig(backend="jax", lambda_depth=5))
+    be.f64 = f64.for_platform("tpu")  # the chip has no IEEE f64
+    calls = {"matrix": [], "search": [], "heft": []}
+    be._build_matrix_fn = _recording(be._build_matrix_fn, calls["matrix"])
+    be._build_search_fn = _recording(be._build_search_fn, calls["search"])
+    be._build_heft_fn = _recording(be._build_heft_fn, calls["heft"])
+    for strat in (DADA(alpha=0.5, use_cp=True, backend="jax"), HEFT(backend="jax")):
+        strat._backend, strat._backend_resolved = be, True
+        sim = Simulator(graph, machine, strat, seed=0)
+        for k, name in enumerate(sim.arrays.data_names):
+            if k % 3 == 0:
+                sim.residency.write(name, k % 24)
+        sim.push = lambda task, rid: None
+        strat.place(sim, wave, None)
+    assert len(wave) >= 256 and all(calls.values()), (len(wave), calls.keys())
+    return calls
+
+
+@pytest.mark.parametrize("kind", ["matrix", "search", "heft"])
+def test_backend_functions_compile(one_chip, backend_calls, kind):
+    """The score-matrix, λ-search (depth 5, the TPU default) and HEFT EFT
+    programs in the chip's integer-exact f64, at a phase-(a) width."""
+    for key, fn, args in backend_calls[kind]:
+        with jax.enable_x64(True):
+            compiled = _compile(fn, args, one_chip)
+        assert compiled.as_text()
+
+
+def test_transfer_folds_compile(one_chip):
+    """The Pallas transfer kernel natively in f32 (the dtype the surrogate
+    feeds it), and the backend's XLA fold over the int64 bit patterns of
+    its f64 times (what the backend passes on a TPU), at phase-(a) widths."""
+    from repro.kernels.sched_score import (
+        transfer_matrix_from_full,
+        transfer_matrix_pallas,
+    )
+
+    n_pad, r_pad, n_u = 512, 4, 25
+    compiled = _compile(
+        transfer_matrix_pallas,
+        [np.zeros((n_pad, r_pad), np.int32), np.zeros((n_pad, r_pad), np.float32),
+         np.zeros(n_u, np.int32), np.zeros(n_u, bool)],
+        one_chip,
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+    with jax.enable_x64(True):
+        compiled = _compile(
+            partial(transfer_matrix_from_full, add=f64.for_platform("tpu").add),
+            [np.zeros((n_pad, r_pad), np.int64)] * 2
+            + [np.zeros(n_u, np.int64), np.zeros(n_u, bool)],
+            one_chip,
+        )
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_surrogate_episode_compiles(one_chip, monkeypatch):
+    """One surrogate episode of phase (b) — Cholesky NT=16 on
+    paper_machine(8), a 16-wide batch — through the native Pallas route."""
+    from repro.core import run_batch
+
+    calls = []
+    monkeypatch.setattr(ep, "_EPISODE_CACHE", {})
+    monkeypatch.setattr(ep, "_build_episode_fn",
+                        _recording(ep._build_episode_fn, calls))
+    graph = partial(cholesky_graph, 16, TILE, with_fns=False)
+    run_batch(
+        [{"graph": graph, "machine": paper_machine(8), "strategy": s, "seed": i}
+         for s in ("heft", "dada?alpha=0.5&use_cp=1", "ws") for i in range(5)],
+        config=SchedConfig(backend="jax", exact=False),
+    )
+    key, _, args = calls[0]
+    native = key[:10] + (True, False) + key[12:]  # use_pallas, not interpret
+    compiled = _compile(ep._build_episode_fn(native), args, one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("body,n_in", [
+    (cholesky._potrf, 1), (cholesky._trsm, 2), (cholesky._syrk, 2),
+    (cholesky._gemm, 3),
+])
+def test_cholesky_tile_bodies_compile(one_chip, body, n_in):
+    compiled = _compile(
+        body, [np.zeros((TILE, TILE), np.float32)] * n_in, one_chip
+    )
+    assert compiled.as_text()
