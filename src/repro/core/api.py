@@ -17,6 +17,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import obs
 from .dag import TaskGraph
 from .machine import MachineModel
 from .simulator import SimResult, Simulator, Strategy
@@ -369,94 +370,103 @@ def run_batch(configs: Sequence[dict], config=None) -> List[BatchResult]:
     so use it for sweeps and searches, and the exact engine
     (:func:`run_simulation` / :func:`run_many`) for verification.
     """
+    with obs.span("batch.run"):
+        return _run_batch(configs, config)
+
+
+def _run_batch(configs: Sequence[dict], config) -> List[BatchResult]:
     from repro.core import episode as ep
 
     if config is None:
         from repro.sched.config import current_config
 
         config = current_config()
-    # resolve graphs and group by (graph, machine template)
-    items = []
-    for i, c in enumerate(configs):
-        g = c["graph"]
-        if not isinstance(g, TaskGraph):
-            g = cached_graph(g)
-        items.append((i, g, c))
+    with obs.span("batch.plan"):
+        # resolve graphs and group by (graph, machine template)
+        items = []
+        for i, c in enumerate(configs):
+            g = c["graph"]
+            if not isinstance(g, TaskGraph):
+                g = cached_graph(g)
+            items.append((i, g, c))
 
-    groups: Dict[tuple, list] = {}
-    for i, g, c in items:
-        m: MachineModel = c["machine"]
-        cpu = next((r.cls for r in m.resources if not r.is_accelerator), None)
-        gpu = next((r.cls for r in m.resources if r.is_accelerator), None)
-        key = (
-            id(g), len(m.resources),
-            cpu.name if cpu else None, gpu.name if gpu else None,
-            m.link.bandwidth, m.link.latency,
-        )
-        groups.setdefault(key, []).append((i, g, c))
+        groups: Dict[tuple, list] = {}
+        for i, g, c in items:
+            m: MachineModel = c["machine"]
+            cpu = next((r.cls for r in m.resources if not r.is_accelerator), None)
+            gpu = next((r.cls for r in m.resources if r.is_accelerator), None)
+            key = (
+                id(g), len(m.resources),
+                cpu.name if cpu else None, gpu.name if gpu else None,
+                m.link.bandwidth, m.link.latency,
+            )
+            groups.setdefault(key, []).append((i, g, c))
 
     out: List[Optional[BatchResult]] = [None] * len(items)
     chunk_cap = max(1, int(config.batch))
     for group in groups.values():
-        g = group[0][1]
-        machines = {}
-        max_mem = -1
-        for _, _, c in group:
-            m = c["machine"]
-            if id(m) not in machines:
-                machines[id(m)] = m
-            max_mem = max(
-                max_mem,
-                max((r.mem for r in m.resources if r.is_accelerator), default=-1),
-            )
-        plan = ep.build_plan(g, group[0][2]["machine"], n_u=max_mem + 2)
-        axes = {
-            mid: ep.machine_axes(m, plan.n_res) for mid, m in machines.items()
-        }
-        # One dispatch shape for the whole group: episode cost is linear
-        # in the batch axis (no fixed-overhead amortisation from bigger
-        # batches), so split into same-shaped chunks — one compile per
-        # (kernel, shape) key — and fan the dispatches out over threads
-        # (XLA drops the GIL during execution).
-        from repro.core.backend import _bucket
+        with obs.span("batch.plan"):
+            g = group[0][1]
+            machines = {}
+            max_mem = -1
+            for _, _, c in group:
+                m = c["machine"]
+                if id(m) not in machines:
+                    machines[id(m)] = m
+                max_mem = max(
+                    max_mem,
+                    max((r.mem for r in m.resources if r.is_accelerator), default=-1),
+                )
+            plan = ep.build_plan(g, group[0][2]["machine"], n_u=max_mem + 2)
+            axes = {
+                mid: ep.machine_axes(m, plan.n_res) for mid, m in machines.items()
+            }
+            # One dispatch shape for the whole group: episode cost is linear
+            # in the batch axis (no fixed-overhead amortisation from bigger
+            # batches), so split into same-shaped chunks — one compile per
+            # (kernel, shape) key — and fan the dispatches out over threads
+            # (XLA drops the GIL during execution).
+            from repro.core.backend import _bucket
 
-        # 16 rows per dispatch: episode cost per config is flat across
-        # B∈{16..256} on CPU, so narrow chunks minimise padding waste and
-        # let every group share one compiled shape; REPRO_SCHED_BATCH
-        # caps it lower for memory-constrained runs
-        n_workers = min(8, os.cpu_count() or 1)
-        size = min(chunk_cap, 16)
-        pad_to = _bucket(min(size, len(group)), lo=8)
-        chunks = [group[lo : lo + size] for lo in range(0, len(group), size)]
+            # 16 rows per dispatch: episode cost per config is flat across
+            # B∈{16..256} on CPU, so narrow chunks minimise padding waste and
+            # let every group share one compiled shape; REPRO_SCHED_BATCH
+            # caps it lower for memory-constrained runs
+            n_workers = min(8, os.cpu_count() or 1)
+            size = min(chunk_cap, 16)
+            pad_to = _bucket(min(size, len(group)), lo=8)
+            chunks = [group[lo : lo + size] for lo in range(0, len(group), size)]
 
         def dispatch(chunk):
-            isg, val, mc, lg = [], [], [], []
-            al, cp, ws, nz, cap = [], [], [], [], []
-            for _, _, c in chunk:
-                a, u, w = ep.surrogate_params(c["strategy"])
-                ig, vl, m_c, l_g = axes[id(c["machine"])]
-                isg.append(ig)
-                val.append(vl)
-                mc.append(m_c)
-                lg.append(l_g)
-                al.append(a)
-                cp.append(u)
-                ws.append(w)
-                nz.append(
-                    ep.noise_factors(
-                        int(c.get("seed", 0)), float(c.get("noise", 0.03)),
-                        plan.n, plan.n_pad,
+            # the chunk's configuration arrays: the first part of its pack
+            with obs.span("episode.pack"):
+                isg, val, mc, lg = [], [], [], []
+                al, cp, ws, nz, cap = [], [], [], [], []
+                for _, _, c in chunk:
+                    a, u, w = ep.surrogate_params(c["strategy"])
+                    ig, vl, m_c, l_g = axes[id(c["machine"])]
+                    isg.append(ig)
+                    val.append(vl)
+                    mc.append(m_c)
+                    lg.append(l_g)
+                    al.append(a)
+                    cp.append(u)
+                    ws.append(w)
+                    nz.append(
+                        ep.noise_factors(
+                            int(c.get("seed", 0)), float(c.get("noise", 0.03)),
+                            plan.n, plan.n_pad,
+                        )
                     )
+                    capacity = float(c.get("capacity", 0) or 0)
+                    cap.append(capacity if capacity > 0 else np.inf)
+                batch = ep.EpisodeBatch(
+                    is_gpu=np.stack(isg), valid_res=np.stack(val),
+                    mem_col=np.stack(mc), link_grp=np.stack(lg),
+                    alpha=np.array(al),
+                    use_cp=np.array(cp), ws_pref=np.array(ws, dtype=bool),
+                    noise=np.stack(nz), cap=np.array(cap),
                 )
-                capacity = float(c.get("capacity", 0) or 0)
-                cap.append(capacity if capacity > 0 else np.inf)
-            batch = ep.EpisodeBatch(
-                is_gpu=np.stack(isg), valid_res=np.stack(val),
-                mem_col=np.stack(mc), link_grp=np.stack(lg),
-                alpha=np.array(al),
-                use_cp=np.array(cp), ws_pref=np.array(ws, dtype=bool),
-                noise=np.stack(nz), cap=np.array(cap),
-            )
             return ep.run_episodes(plan, batch, config=config, pad_to=pad_to)
 
         if len(chunks) > 1 and n_workers > 1:
@@ -464,7 +474,7 @@ def run_batch(configs: Sequence[dict], config=None) -> List[BatchResult]:
             # concurrently against the cached executable
             results = [dispatch(chunks[0])]
             with ThreadPoolExecutor(max_workers=n_workers) as tp:
-                results += list(tp.map(dispatch, chunks[1:]))
+                results += list(tp.map(obs.carry(dispatch), chunks[1:]))
         else:
             results = [dispatch(ch) for ch in chunks]
 

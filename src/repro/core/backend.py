@@ -72,6 +72,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import obs
 from .f64 import for_platform as f64_for_platform
 from .machine import HOST_MEM
 
@@ -209,6 +210,30 @@ def _x64_scoped(method):
     return wrapper
 
 
+def call_program(prog: str, fn, args, reads, counts: Optional[dict] = None):
+    """Run the jitted ``fn`` as three traced phases: ``<prog>.upload`` puts
+    the host values of ``args`` on the device, ``<prog>.dispatch`` calls the
+    program, ``<prog>.readback`` copies ``reads`` of its outputs back.
+
+    ``args`` are ``(to_device, value)`` pairs in ``fn``'s argument order,
+    ``to_device`` None for a value already on the device; ``reads`` are
+    ``(output, decode)`` pairs, ``output`` the index into the program's
+    output tuple (None for a lone output). Returns the raw outputs and the
+    decoded ones, in the order of ``reads``. ``counts``, where given, gains
+    the ``uploads`` and ``readbacks`` made.
+    """
+    with obs.span(prog + ".upload"):
+        dev = [x if up is None else up(x) for up, x in args]
+    with obs.span(prog + ".dispatch"):
+        out = fn(*dev)
+    with obs.span(prog + ".readback"):
+        host = [dec(out if i is None else out[i]) for i, dec in reads]
+    if counts is not None:
+        counts["uploads"] += sum(up is not None for up, _ in args)
+        counts["readbacks"] += len(reads)
+    return out, host
+
+
 class JaxScoringBackend:
     """JAX implementation of the placement-scoring hot paths.
 
@@ -251,8 +276,12 @@ class JaxScoringBackend:
         self.f64 = f64_for_platform(platform)
         # activations scored on the device vs returned to numpy because
         # they fall outside the supported envelope (``outside``) or because
-        # the device's λ was not feasible on the host (``rejected``)
-        self.counts = {"device": 0, "outside": 0, "rejected": 0}
+        # the device's λ was not feasible on the host (``rejected``); the
+        # host->device arrays (scalars included) and device->host reads the
+        # programs made (``uploads``, ``readbacks``; the per-machine arrays,
+        # uploaded once, are not counted)
+        self.counts = {"device": 0, "outside": 0, "rejected": 0,
+                       "uploads": 0, "readbacks": 0}
         self._matrix_fns: Dict[tuple, object] = {}
         self._search_fns: Dict[tuple, object] = {}
         self._heft_fns: Dict[tuple, object] = {}
@@ -354,103 +383,110 @@ class JaxScoringBackend:
         """
         from .affinity import affinity_csr_source
 
-        mach = self._machine_arrays(resources, sim.transfer_model)
-        if mach is None:
-            self.counts["outside"] += 1
-            return None
-        arr = sim.arrays
-        residency = sim.residency
-        n = len(tids)
-        n_pad = _bucket(n)
-        tids_arr = np.asarray(tids, dtype=np.int64)
-        uniq = mach["uniq"]
-        jnp = self.jnp
+        with obs.span("score.pack"):
+            mach = self._machine_arrays(resources, sim.transfer_model)
+            if mach is None:
+                self.counts["outside"] += 1
+                return None
+            arr = sim.arrays
+            residency = sim.residency
+            n = len(tids)
+            n_pad = _bucket(n)
+            tids_arr = np.asarray(tids, dtype=np.int64)
+            uniq = mach["uniq"]
+            jnp = self.jnp
 
-        want_x = use_cp
-        aff_src = affinity_csr_source(affinity, arr) if affinity else None
-        want_s = aff_src is not None
-        if not (want_x or want_s or p_cpu is not None):
-            self.counts["outside"] += 1
-            return None
-        want_bias = want_x and x_bias is not None
-        if want_bias:
-            bias = np.zeros((n_pad, len(resources)), dtype=np.float64)
-            bias[:n] = x_bias
-        else:
-            bias = np.zeros((1, 1), dtype=np.float64)
+            want_x = use_cp
+            aff_src = affinity_csr_source(affinity, arr) if affinity else None
+            want_s = aff_src is not None
+            if not (want_x or want_s or p_cpu is not None):
+                self.counts["outside"] += 1
+                return None
+            want_bias = want_x and x_bias is not None
+            if want_bias:
+                bias = np.zeros((n_pad, len(resources)), dtype=np.float64)
+                bias[:n] = x_bias
+            else:
+                bias = np.zeros((1, 1), dtype=np.float64)
 
-        if want_x:
-            r_indptr, r_ids, r_sizes = arr.gather_csr(
-                tids_arr, arr.read_indptr, arr.read_ids, arr.read_sizes
-            )
-            r_pad = _bucket(int((r_indptr[1:] - r_indptr[:-1]).max(initial=1)), lo=1)
-            r_masks = residency.mask_of_ids(r_ids)
-            read_masks, read_sizes = self._pad_csr(
-                r_indptr, [r_masks, r_sizes], n_pad, r_pad
-            )
-            # per-read one-hop times on the host, as the numpy path has them
-            per_read = np.where(
-                read_sizes <= 0.0, 0.0,
-                mach["latency"] + read_sizes / mach["bandwidth"],
-            )
-        else:
-            r_pad = 0
-            read_masks = np.zeros((n_pad, 1), dtype=np.int64)
-            per_read = np.zeros((n_pad, 1))
+            if want_x:
+                r_indptr, r_ids, r_sizes = arr.gather_csr(
+                    tids_arr, arr.read_indptr, arr.read_ids, arr.read_sizes
+                )
+                r_pad = _bucket(int((r_indptr[1:] - r_indptr[:-1]).max(initial=1)), lo=1)
+                r_masks = residency.mask_of_ids(r_ids)
+                read_masks, read_sizes = self._pad_csr(
+                    r_indptr, [r_masks, r_sizes], n_pad, r_pad
+                )
+                # per-read one-hop times on the host, as the numpy path has them
+                per_read = np.where(
+                    read_sizes <= 0.0, 0.0,
+                    mach["latency"] + read_sizes / mach["bandwidth"],
+                )
+            else:
+                r_pad = 0
+                read_masks = np.zeros((n_pad, 1), dtype=np.int64)
+                per_read = np.zeros((n_pad, 1))
 
-        if want_s:
-            w_indptr_full, w_ids_full, w_weights_full, accel_only = aff_src
-            w_indptr, w_ids, w_weights = arr.gather_csr(
-                tids_arr, w_indptr_full, w_ids_full, w_weights_full
-            )
-            w_pad = _bucket(int((w_indptr[1:] - w_indptr[:-1]).max(initial=1)), lo=1)
-            w_masks = residency.mask_of_ids(w_ids)
-            write_masks, write_weights = self._pad_csr(
-                w_indptr, [w_masks, w_weights.astype(np.float64)], n_pad, w_pad
-            )
-        else:
-            w_pad = 0
-            accel_only = False
-            write_masks = np.zeros((n_pad, 1), dtype=np.int64)
-            write_weights = np.zeros((n_pad, 1))
+            if want_s:
+                w_indptr_full, w_ids_full, w_weights_full, accel_only = aff_src
+                w_indptr, w_ids, w_weights = arr.gather_csr(
+                    tids_arr, w_indptr_full, w_ids_full, w_weights_full
+                )
+                w_pad = _bucket(int((w_indptr[1:] - w_indptr[:-1]).max(initial=1)), lo=1)
+                w_masks = residency.mask_of_ids(w_ids)
+                write_masks, write_weights = self._pad_csr(
+                    w_indptr, [w_masks, w_weights.astype(np.float64)], n_pad, w_pad
+                )
+            else:
+                w_pad = 0
+                accel_only = False
+                write_masks = np.zeros((n_pad, 1), dtype=np.int64)
+                write_weights = np.zeros((n_pad, 1))
 
-        want_c = p_cpu is not None
-        if want_c:
-            pc = np.zeros(n_pad, dtype=np.float64)
-            pg = np.zeros(n_pad, dtype=np.float64)
-            pc[:n] = p_cpu
-            pg[:n] = p_gpu
-        else:
-            pc = pg = np.zeros(n_pad, dtype=np.float64)
+            want_c = p_cpu is not None
+            if want_c:
+                pc = np.zeros(n_pad, dtype=np.float64)
+                pg = np.zeros(n_pad, dtype=np.float64)
+                pc[:n] = p_cpu
+                pg[:n] = p_gpu
+            else:
+                pc = pg = np.zeros(n_pad, dtype=np.float64)
 
-        key = (n_pad, r_pad, w_pad, len(uniq), len(resources),
-               want_x, bool(x_rows), want_s, want_c, accel_only, want_bias)
-        fn = self._matrix_fns.get(key)
-        if fn is None:
-            fn = self._build_matrix_fn(key)
-            self._matrix_fns[key] = fn
-        enc = self.f64.encode
-        C, X, X_max, S = fn(
-            jnp.asarray(read_masks), jnp.asarray(enc(per_read)),
-            jnp.asarray(write_masks), jnp.asarray(enc(write_weights)),
-            jnp.asarray(enc(pc)), jnp.asarray(enc(pg)), jnp.asarray(enc(bias)),
-            mach["mem_shift"], mach["host_col"],
-            mach["col_of"], mach["accel_res"],
-        )
+            key = (n_pad, r_pad, w_pad, len(uniq), len(resources),
+                   want_x, bool(x_rows), want_s, want_c, accel_only, want_bias)
+            fn = self._matrix_fns.get(key)
+            if fn is None:
+                fn = self._build_matrix_fn(key)
+                self._matrix_fns[key] = fn
+            enc = self.f64.encode
+            up = jnp.asarray
+            args = [
+                (up, read_masks), (up, enc(per_read)),
+                (up, write_masks), (up, enc(write_weights)),
+                (up, enc(pc)), (up, enc(pg)), (up, enc(bias)),
+                (None, mach["mem_shift"]), (None, mach["host_col"]),
+                (None, mach["col_of"]), (None, mach["accel_res"]),
+            ]
+            # outputs (C, X, X_max, S): the ones this activation reads
+            wanted = [(k, i) for i, (k, on) in enumerate((
+                ("C_np", want_c), ("X_np", want_x and x_rows),
+                ("X_rowmax", want_x and not x_rows), ("S_np", want_s))) if on]
+
+        def dec(x):
+            return self.f64.decode(x)[:n]
+
+        raw, host = call_program("score", fn, args, [(i, dec) for _, i in wanted],
+                                 self.counts)
         self.counts["device"] += 1
-        dec = self.f64.decode
         out = dict(C=None, C_np=None, C_dev=None, X_np=None,
                    X_rowmax=None, S_np=None)
+        out.update(zip((k for k, _ in wanted), host))
         if want_c:
-            out["C_dev"] = C
-            out["C_np"] = dec(C)[:n]
+            out["C_dev"] = raw[0]
             out["C"] = out["C_np"].tolist()
-        if want_x and x_rows:
-            out["X_np"] = dec(X)[:n]
-        if want_x and not x_rows:
-            out["X_rowmax"] = dec(X_max)[:n].tolist()
-        if want_s:
-            out["S_np"] = dec(S)[:n]
+        if out["X_rowmax"] is not None:
+            out["X_rowmax"] = out["X_rowmax"].tolist()
         return out
 
     def _build_matrix_fn(self, key):
@@ -459,8 +495,9 @@ class JaxScoringBackend:
         jax, jnp = self.jax, self.jnp
         F = self.f64
 
-        def fn(read_masks, per_read, write_masks, write_weights,
-               p_cpu, p_gpu, x_bias, mem_shift, host_col, col_of, accel_res):
+        def dada_score_matrices(read_masks, per_read, write_masks, write_weights,
+                                p_cpu, p_gpu, x_bias, mem_shift, host_col,
+                                col_of, accel_res):
             X_res = None
             X_max = None
             if want_x:
@@ -507,7 +544,7 @@ class JaxScoringBackend:
                 )
             return C, X_res, X_max, S_res
 
-        return jax.jit(fn)
+        return jax.jit(dada_score_matrices)
 
     # ------------------------------------------------------------------
     # DADA λ-probe search
@@ -547,83 +584,82 @@ class JaxScoringBackend:
         ``try_build``. ``C_dev`` is the device-resident padded cost matrix
         from :meth:`score_matrices` (same ``_bucket(n)`` padding).
         """
-        jnp = self.jnp
-        n_pad = _bucket(n)
-        assert C_dev.shape == (n_pad, n_res), (C_dev.shape, n_pad, n_res)
+        with obs.span("search.pack"):
+            jnp = self.jnp
+            n_pad = _bucket(n)
+            assert C_dev.shape == (n_pad, n_res), (C_dev.shape, n_pad, n_res)
 
-        accel = [r.is_accelerator for r in resources]
-        cpu_idx = np.asarray(
-            [j for j, a in enumerate(accel) if not a], dtype=np.int32
-        )
-        gpu_idx = np.asarray(
-            [j for j, a in enumerate(accel) if a], dtype=np.int32
-        )
-
-        pc = np.zeros(n_pad, dtype=np.float64)
-        pg = np.zeros(n_pad, dtype=np.float64)
-        pc[:n] = p_cpu
-        pg[:n] = p_gpu
-        valid = np.zeros(n_pad, dtype=bool)
-        valid[:n] = True
-        F = self.f64
-        # padded flex_order entries point at row 0; the search masks them
-        # with the position-validity of `valid` (True exactly for k < n)
-        ford = np.zeros(n_pad, dtype=np.int32)
-        ford[:n] = flex_order
-
-        # Affinity phase → per-resource chains: entry k of by_score only
-        # reads/writes loads[rid_k], so entries of different resources are
-        # independent; within one resource the by-score order is preserved
-        # by the stable sort. The scan then runs max-chain-length steps
-        # with one lane per resource instead of len(by_score) steps, and
-        # each task reads its own take-flag back through one gather
-        # (task_slot points at the task's (chain position, rid) cell; the
-        # appended always-False cell absorbs tasks without a preference).
-        m = len(by_score)
-        task_slot = np.full(n_pad, 0, dtype=np.int32)
-        if m:
-            rids = np.fromiter((e[2] for e in by_score), np.int64, m)
-            costs = np.fromiter((e[3] for e in by_score), np.float64, m)
-            tis = np.fromiter(
-                (tid_index[e[1]] for e in by_score), np.int64, m
+            accel = [r.is_accelerator for r in resources]
+            cpu_idx = np.asarray(
+                [j for j, a in enumerate(accel) if not a], dtype=np.int32
             )
-            perm = np.argsort(rids, kind="stable")
-            srid = rids[perm]
-            first = np.searchsorted(srid, srid, side="left")
-            pos = np.arange(m, dtype=np.int64) - first
-            chain_pad = _bucket(int(pos.max()) + 1, lo=1)
-            chain_cost = np.zeros((chain_pad, n_res), dtype=np.float64)
-            chain_valid = np.zeros((chain_pad, n_res), dtype=bool)
-            chain_cost[pos, srid] = costs[perm]
-            chain_valid[pos, srid] = True
-            task_slot[:] = chain_pad * n_res  # the appended False cell
-            task_slot[tis[perm]] = (pos * n_res + srid).astype(np.int32)
-        else:
-            chain_pad = 0
-            chain_cost = np.zeros((1, n_res), dtype=np.float64)
-            chain_valid = np.zeros((1, n_res), dtype=bool)
+            gpu_idx = np.asarray(
+                [j for j, a in enumerate(accel) if a], dtype=np.int32
+            )
 
-        key = (n_pad, chain_pad, n_res, len(cpu_idx), len(gpu_idx),
-               bool(have_both), bool(area_bound), self.depth)
-        fn = self._search_fns.get(key)
-        if fn is None:
-            fn = self._build_search_fn(key)
-            self._search_fns[key] = fn
-        upper = fn(
-            jnp.asarray(F.encode(offsets)),
-            C_dev,
-            jnp.asarray(F.encode(pc)), jnp.asarray(F.encode(pg)),
-            jnp.asarray(valid), jnp.asarray(ford),
-            jnp.asarray(F.encode(chain_cost)), jnp.asarray(chain_valid),
-            jnp.asarray(task_slot),
-            jnp.asarray(cpu_idx), jnp.asarray(gpu_idx),
-            jnp.bool_(no_cpus), jnp.bool_(no_gpus),
-            F.const(alpha), F.const(2.0 + alpha),
-            F.const(area), F.const(off_total), F.const(max_off),
-            F.const(float(n_res)),
-            F.const(eps_rel), jnp.int32(max_iters), F.const(upper0),
-        )
-        return float(F.decode(upper))
+            pc = np.zeros(n_pad, dtype=np.float64)
+            pg = np.zeros(n_pad, dtype=np.float64)
+            pc[:n] = p_cpu
+            pg[:n] = p_gpu
+            valid = np.zeros(n_pad, dtype=bool)
+            valid[:n] = True
+            F = self.f64
+            # padded flex_order entries point at row 0; the search masks them
+            # with the position-validity of `valid` (True exactly for k < n)
+            ford = np.zeros(n_pad, dtype=np.int32)
+            ford[:n] = flex_order
+
+            # Affinity phase → per-resource chains: entry k of by_score only
+            # reads/writes loads[rid_k], so entries of different resources are
+            # independent; within one resource the by-score order is preserved
+            # by the stable sort. The scan then runs max-chain-length steps
+            # with one lane per resource instead of len(by_score) steps, and
+            # each task reads its own take-flag back through one gather
+            # (task_slot points at the task's (chain position, rid) cell; the
+            # appended always-False cell absorbs tasks without a preference).
+            m = len(by_score)
+            task_slot = np.full(n_pad, 0, dtype=np.int32)
+            if m:
+                rids = np.fromiter((e[2] for e in by_score), np.int64, m)
+                costs = np.fromiter((e[3] for e in by_score), np.float64, m)
+                tis = np.fromiter(
+                    (tid_index[e[1]] for e in by_score), np.int64, m
+                )
+                perm = np.argsort(rids, kind="stable")
+                srid = rids[perm]
+                first = np.searchsorted(srid, srid, side="left")
+                pos = np.arange(m, dtype=np.int64) - first
+                chain_pad = _bucket(int(pos.max()) + 1, lo=1)
+                chain_cost = np.zeros((chain_pad, n_res), dtype=np.float64)
+                chain_valid = np.zeros((chain_pad, n_res), dtype=bool)
+                chain_cost[pos, srid] = costs[perm]
+                chain_valid[pos, srid] = True
+                task_slot[:] = chain_pad * n_res  # the appended False cell
+                task_slot[tis[perm]] = (pos * n_res + srid).astype(np.int32)
+            else:
+                chain_pad = 0
+                chain_cost = np.zeros((1, n_res), dtype=np.float64)
+                chain_valid = np.zeros((1, n_res), dtype=bool)
+
+            key = (n_pad, chain_pad, n_res, len(cpu_idx), len(gpu_idx),
+                   bool(have_both), bool(area_bound), self.depth)
+            fn = self._search_fns.get(key)
+            if fn is None:
+                fn = self._build_search_fn(key)
+                self._search_fns[key] = fn
+            up, enc, const = jnp.asarray, F.encode, F.const
+            args = [
+                (up, enc(offsets)), (None, C_dev), (up, enc(pc)), (up, enc(pg)),
+                (up, valid), (up, ford), (up, enc(chain_cost)), (up, chain_valid),
+                (up, task_slot), (up, cpu_idx), (up, gpu_idx),
+                (jnp.bool_, no_cpus), (jnp.bool_, no_gpus),
+                (const, alpha), (const, 2.0 + alpha), (const, area),
+                (const, off_total), (const, max_off), (const, float(n_res)),
+                (const, eps_rel), (jnp.int32, max_iters), (const, upper0),
+            ]
+        _, (upper,) = call_program(
+            "search", fn, args, [(None, lambda x: float(F.decode(x)))], self.counts)
+        return upper
 
     def _build_search_fn(self, key):
         (n_pad, chain_pad, n_res, n_cpu, n_gpu,
@@ -634,11 +670,11 @@ class JaxScoringBackend:
         add, sub, mul, lt, le = F.add, F.sub, F.mul, F.lt, F.le
         K = 2 ** depth - 1
 
-        def fn(loads0, C, p_cpu, p_gpu, valid, flex_ord,
-               chain_cost, chain_valid, task_slot,
-               cpu_idx, gpu_idx, no_cpus, no_gpus,
-               alpha, two_alpha, area, off_total, max_off, n_res_f,
-               eps_rel, max_iters, upper0):
+        def search(loads0, C, p_cpu, p_gpu, valid, flex_ord,
+                   chain_cost, chain_valid, task_slot,
+                   cpu_idx, gpu_idx, no_cpus, no_gpus,
+                   alpha, two_alpha, area, off_total, max_off, n_res_f,
+                   eps_rel, max_iters, upper0):
             TINY, INF, HALF = F.const(_TINY), F.const(float("inf")), F.const(0.5)
             # probe-invariant gathers, done once per search
             if have_both:
@@ -833,7 +869,10 @@ class JaxScoringBackend:
             )
             return upper
 
-        return jax.jit(fn)
+        # the program's name in a device trace (jit_dada_lambda_search); the
+        # def is named apart from the method, which the lint would take for it
+        search.__name__ = "dada_lambda_search"
+        return jax.jit(search)
 
     # ------------------------------------------------------------------
     # HEFT earliest-finish-time selection
@@ -853,26 +892,30 @@ class JaxScoringBackend:
         the same values (1e-15 strict-improvement tie-break included) the
         scalar loop in ``heft.place`` computes.
         """
-        jnp = self.jnp
-        F = self.f64
-        n, n_res = D_ord.shape
-        n_pad = _bucket(n)
-        D = np.zeros((n_pad, n_res), dtype=np.float64)
-        X = np.zeros((n_pad, n_res), dtype=np.float64)
-        valid = np.zeros(n_pad, dtype=bool)
-        D[:n] = D_ord
-        X[:n] = X_ord
-        valid[:n] = True
-        key = (n_pad, n_res)
-        fn = self._heft_fns.get(key)
-        if fn is None:
-            fn = self._build_heft_fn(key)
-            self._heft_fns[key] = fn
-        rids, efts = fn(
-            jnp.asarray(F.encode(D)), jnp.asarray(F.encode(X)),
-            jnp.asarray(valid), jnp.asarray(F.encode(load_ts)), F.const(now),
-        )
-        return np.asarray(rids)[:n], F.decode(efts)[:n]
+        with obs.span("heft.pack"):
+            jnp = self.jnp
+            F = self.f64
+            n, n_res = D_ord.shape
+            n_pad = _bucket(n)
+            D = np.zeros((n_pad, n_res), dtype=np.float64)
+            X = np.zeros((n_pad, n_res), dtype=np.float64)
+            valid = np.zeros(n_pad, dtype=bool)
+            D[:n] = D_ord
+            X[:n] = X_ord
+            valid[:n] = True
+            key = (n_pad, n_res)
+            fn = self._heft_fns.get(key)
+            if fn is None:
+                fn = self._build_heft_fn(key)
+                self._heft_fns[key] = fn
+            up = jnp.asarray
+            args = [(up, F.encode(D)), (up, F.encode(X)), (up, valid),
+                    (up, F.encode(load_ts)), (F.const, now)]
+        _, (rids, efts) = call_program(
+            "heft", fn, args,
+            [(0, lambda x: np.asarray(x)[:n]), (1, lambda x: F.decode(x)[:n])],
+            self.counts)
+        return rids, efts
 
     def _build_heft_fn(self, key):
         n_pad, n_res = key
@@ -880,7 +923,7 @@ class JaxScoringBackend:
         F = self.f64
         add, lt = F.add, F.lt
 
-        def fn(D, X, valid, load_ts, now):
+        def select(D, X, valid, load_ts, now):
             INF, EPS = F.const(float("inf")), F.const(1e-15)
 
             def step(lts, x):
@@ -919,4 +962,5 @@ class JaxScoringBackend:
             )
             return rids, efts
 
-        return jax.jit(fn)
+        select.__name__ = "heft_select"  # as search.__name__ above
+        return jax.jit(select)

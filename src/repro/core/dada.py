@@ -30,6 +30,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from . import obs
 from .affinity import AFFINITY_FUNCTIONS, AffinityFn, affinity_rows
 from .backend import ScoringBackendMixin
 from .dag import Task
@@ -95,6 +96,10 @@ class DADA(ScoringBackendMixin, Strategy):
 
     # ------------------------------------------------------------------
     def place(self, sim: Simulator, ready: List[Task], src: Optional[int]) -> None:
+        with obs.span("dada.place"):
+            self._place(sim, ready, src)
+
+    def _place(self, sim: Simulator, ready: List[Task], src: Optional[int]) -> None:
         machine = sim.machine
         resources = machine.resources
         cpus = machine.cpus
@@ -105,54 +110,55 @@ class DADA(ScoringBackendMixin, Strategy):
         n = len(ready)
         tids = [t.tid for t in ready]
 
-        # --- λ-independent precomputation (batched for wide activations,
-        # --- scalar over the same arrays for narrow ones) ----------------
-        if n >= _WIDE:
-            tids_arr = np.asarray(tids, dtype=np.int64)
-            p_cpu = sim.predictor(cpu_cls).times(tids_arr).tolist()
-            p_gpu = sim.predictor(gpu_cls).times(tids_arr).tolist()
-        else:
-            p_cpu = sim.predictor(cpu_cls).times_list(tids)
-            p_gpu = sim.predictor(gpu_cls).times_list(tids)
+        with obs.span("dada.predict"):
+            # --- λ-independent precomputation (batched for wide activations,
+            # --- scalar over the same arrays for narrow ones) ----------------
+            if n >= _WIDE:
+                tids_arr = np.asarray(tids, dtype=np.int64)
+                p_cpu = sim.predictor(cpu_cls).times(tids_arr).tolist()
+                p_gpu = sim.predictor(gpu_cls).times(tids_arr).tolist()
+            else:
+                p_cpu = sim.predictor(cpu_cls).times_list(tids)
+                p_gpu = sim.predictor(gpu_cls).times_list(tids)
 
-        # memory-pressure penalty under +CP (capacity-bounded memories):
-        # predicted eviction seconds folded into the transfer matrix on
-        # the numpy and jax scoring paths alike. fault_mask=False: DADA
-        # handles detached resources by filtering its placement pools
-        # below — an +inf fold would blow up `upper` (the λ search's
-        # feasibility anchor) and every probe's load updates
-        from repro.runtime.memory import fold_pressure, pressure_rows_for
+            # memory-pressure penalty under +CP (capacity-bounded memories):
+            # predicted eviction seconds folded into the transfer matrix on
+            # the numpy and jax scoring paths alike. fault_mask=False: DADA
+            # handles detached resources by filtering its placement pools
+            # below — an +inf fold would blow up `upper` (the λ search's
+            # feasibility anchor) and every probe's load updates
+            from repro.runtime.memory import fold_pressure, pressure_rows_for
 
-        P = (
-            pressure_rows_for(sim, tids, resources, fault_mask=False)
-            if self.use_cp
-            else None
-        )
+            P = (
+                pressure_rows_for(sim, tids, resources, fault_mask=False)
+                if self.use_cp
+                else None
+            )
 
-        # detached resources (repro.runtime.faults): excluded from every
-        # placement pool and load update; with no resource detached the
-        # sets below are unchanged and the fused path stays available
-        faults = getattr(sim, "faults", None)
-        dead = (
-            faults.dead_rids
-            if faults is not None and faults.any_dead
-            else frozenset()
-        )
+            # detached resources (repro.runtime.faults): excluded from every
+            # placement pool and load update; with no resource detached the
+            # sets below are unchanged and the fused path stays available
+            faults = getattr(sim, "faults", None)
+            dead = (
+                faults.dead_rids
+                if faults is not None and faults.any_dead
+                else frozenset()
+            )
 
-        # notice-aware recovery (recover=True only): a condemned column
-        # pays the remaining notice window, by resource position — the
-        # same finite decaying signal pressure_rows_for feeds score-matrix
-        # policies, folded into C below so every phase of the λ search
-        # steers off a dying device. Empty whenever no notice is pending,
-        # keeping recover=True bit-identical outside notice windows.
-        noticed_pen: Dict[int, float] = {}
-        if self.recover and faults is not None and faults.noticed:
-            for j, r in enumerate(resources):
-                pending = faults.noticed.get(r.rid)
-                if pending is not None:
-                    p = pending[1] - sim.now
-                    if p > 0.0:
-                        noticed_pen[j] = p
+            # notice-aware recovery (recover=True only): a condemned column
+            # pays the remaining notice window, by resource position — the
+            # same finite decaying signal pressure_rows_for feeds score-matrix
+            # policies, folded into C below so every phase of the λ search
+            # steers off a dying device. Empty whenever no notice is pending,
+            # keeping recover=True bit-identical outside notice windows.
+            noticed_pen: Dict[int, float] = {}
+            if self.recover and faults is not None and faults.noticed:
+                for j, r in enumerate(resources):
+                    pending = faults.noticed.get(r.rid)
+                    if pending is not None:
+                        p = pending[1] - sim.now
+                        if p > 0.0:
+                            noticed_pen[j] = p
 
         # accelerated fused scoring (wide activations, jax backend): C, X
         # and the affinity matrix come out of one jitted dispatch, bit-equal
@@ -168,286 +174,286 @@ class DADA(ScoringBackendMixin, Strategy):
                 affinity=self.affinity_name if self.alpha > 0.0 else None,
                 x_bias=P,
             )
-        use_backend_search = fused is not None
 
-        if fused is not None:
-            X = None  # worst-case transfer bound: fused["X_rowmax"] below
-            C_rows = fused["C"]
-        elif self.use_cp:
-            X = fold_pressure(
-                sim.transfer_model.task_input_transfer_rows(
-                    sim.arrays, tids, [r.mem for r in resources], sim.residency
-                ),
-                P,
-            )
-        else:
-            X = None
+        with obs.span("dada.order"):
+            use_backend_search = fused is not None
 
-        # cost matrix C[i][rid] = duration-on-class + predicted transfer
-        if fused is None:
-            gpu_pos = [j for j, r in enumerate(resources) if r.is_accelerator]
-            if X is None:
-                C_rows = []
-                for pc, pg in zip(p_cpu, p_gpu):
-                    row = [pc] * n_res
-                    for j in gpu_pos:
-                        row[j] = pg
-                    C_rows.append(row)
-            else:
-                C_rows = []
-                for pc, pg, xrow in zip(p_cpu, p_gpu, X):
-                    row = [pc + x for x in xrow]
-                    for j in gpu_pos:
-                        row[j] = pg + xrow[j]
-                    C_rows.append(row)
-        if noticed_pen:
-            # condemned columns pay the remaining notice window (the fused
-            # path is disabled above, so C_rows is always the list form)
-            for row in C_rows:
-                for j, p in noticed_pen.items():
-                    row[j] += p
-        offsets = [
-            lt - sim.now if lt - sim.now > 0.0 else 0.0
-            for lt in (sim.load_ts[r.rid] for r in resources)
-        ]
-        if dead:
-            # dead resources receive no load and contribute no backlog
-            # (their stale load_ts must not gate the λ feasibility test)
-            for j, r in enumerate(resources):
-                if r.rid in dead:
-                    offsets[j] = 0.0
-
-        # affinity preferences per task, with the placement cost prefetched
-        pref: List[Tuple[float, int, int, float]] = []  # (score, tid, rid, cost)
-        S_np = fused["S_np"] if fused is not None else None
-        if self.alpha > 0.0 and S_np is not None:
-            # vectorized best-resource selection: one pass per resource
-            # column reproduces the scalar rid-ascending tolerance scan
-            # row-by-row, and the (-score, tid) lexsort matches sorted()
-            # because tids are unique
-            best = np.zeros(n, dtype=np.float64)
-            best_rid = np.full(n, -1, dtype=np.int64)
-            for rid in range(n_res):
-                col = S_np[:, rid]
-                upd = col > best + _TINY
-                if upd.any():
-                    best[upd] = col[upd]
-                    best_rid[upd] = rid
-            sel = np.nonzero(best_rid >= 0)[0]
-            if len(sel):
-                scores = best[sel]
-                prids = best_rid[sel]
-                ptids = np.asarray(tids, dtype=np.int64)[sel]
-                pcosts = fused["C_np"][sel, prids]
-                order_p = np.lexsort((ptids, -scores))
-                by_score = list(
-                    zip(
-                        scores[order_p].tolist(),
-                        ptids[order_p].tolist(),
-                        prids[order_p].tolist(),
-                        pcosts[order_p].tolist(),
-                    )
+            if fused is not None:
+                X = None  # worst-case transfer bound: fused["X_rowmax"] below
+                C_rows = fused["C"]
+            elif self.use_cp:
+                X = fold_pressure(
+                    sim.transfer_model.task_input_transfer_rows(
+                        sim.arrays, tids, [r.mem for r in resources], sim.residency
+                    ),
+                    P,
                 )
             else:
-                by_score = []
-        else:
-            if self.alpha > 0.0:
-                S_rows = affinity_rows(
-                    self.affinity_name, sim.arrays, tids, ready, resources,
-                    sim.residency,
-                )
-                for i, row in enumerate(S_rows):
-                    if not any(row):
-                        continue  # all-zero (C-level falsy) row: no preference
-                    best_score, best_rid = 0.0, -1
-                    for rid in range(n_res):
-                        if rid in dead:
-                            continue  # affinity to a vanished memory is void
-                        if rid in noticed_pen:
-                            # affinity to a condemned memory is a trap:
-                            # the data is leaving with the device
-                            continue
-                        s = row[rid]
-                        if s > best_score + _TINY:
-                            best_score, best_rid = s, rid
-                    if best_rid >= 0:
-                        pref.append(
-                            (best_score, tids[i], best_rid, C_rows[i][best_rid])
+                X = None
+
+            # cost matrix C[i][rid] = duration-on-class + predicted transfer
+            if fused is None:
+                gpu_pos = [j for j, r in enumerate(resources) if r.is_accelerator]
+                if X is None:
+                    C_rows = []
+                    for pc, pg in zip(p_cpu, p_gpu):
+                        row = [pc] * n_res
+                        for j in gpu_pos:
+                            row[j] = pg
+                        C_rows.append(row)
+                else:
+                    C_rows = []
+                    for pc, pg, xrow in zip(p_cpu, p_gpu, X):
+                        row = [pc + x for x in xrow]
+                        for j in gpu_pos:
+                            row[j] = pg + xrow[j]
+                        C_rows.append(row)
+            if noticed_pen:
+                # condemned columns pay the remaining notice window (the fused
+                # path is disabled above, so C_rows is always the list form)
+                for row in C_rows:
+                    for j, p in noticed_pen.items():
+                        row[j] += p
+            offsets = [
+                lt - sim.now if lt - sim.now > 0.0 else 0.0
+                for lt in (sim.load_ts[r.rid] for r in resources)
+            ]
+            if dead:
+                # dead resources receive no load and contribute no backlog
+                # (their stale load_ts must not gate the λ feasibility test)
+                for j, r in enumerate(resources):
+                    if r.rid in dead:
+                        offsets[j] = 0.0
+
+            # affinity preferences per task, with the placement cost prefetched
+            pref: List[Tuple[float, int, int, float]] = []  # (score, tid, rid, cost)
+            S_np = fused["S_np"] if fused is not None else None
+            if self.alpha > 0.0 and S_np is not None:
+                # vectorized best-resource selection: one pass per resource
+                # column reproduces the scalar rid-ascending tolerance scan
+                # row-by-row, and the (-score, tid) lexsort matches sorted()
+                # because tids are unique
+                best = np.zeros(n, dtype=np.float64)
+                best_rid = np.full(n, -1, dtype=np.int64)
+                for rid in range(n_res):
+                    col = S_np[:, rid]
+                    upd = col > best + _TINY
+                    if upd.any():
+                        best[upd] = col[upd]
+                        best_rid[upd] = rid
+                sel = np.nonzero(best_rid >= 0)[0]
+                if len(sel):
+                    scores = best[sel]
+                    prids = best_rid[sel]
+                    ptids = np.asarray(tids, dtype=np.int64)[sel]
+                    pcosts = fused["C_np"][sel, prids]
+                    order_p = np.lexsort((ptids, -scores))
+                    by_score = list(
+                        zip(
+                            scores[order_p].tolist(),
+                            ptids[order_p].tolist(),
+                            prids[order_p].tolist(),
+                            pcosts[order_p].tolist(),
                         )
-            by_score = sorted(pref, key=lambda x: (-x[0], x[1]))
-
-        # speedup sort keys for the flexible phase (λ-independent)
-        skey = [-(pc / max(pg, _TINY)) for pc, pg in zip(p_cpu, p_gpu)]
-
-        cpu_rids = [r.rid for r in cpus if r.rid not in dead]
-        gpu_rids = [r.rid for r in gpus if r.rid not in dead]
-        any_rids = cpu_rids or gpu_rids
-        if not any_rids:
-            raise RuntimeError("DADA: every resource is detached")
-        have_both = bool(cpu_rids and gpu_rids)
-        no_cpus = not cpu_rids
-        no_gpus = not gpu_rids
-
-        if self.area_bound:
-            area = sum(min(pc, pg) for pc, pg in zip(p_cpu, p_gpu))
-            off_total = sum(offsets)
-
-        all_idx = list(range(n))
-        # global flex order (λ-independent): per-probe flex sets are subsets
-        # of ready, so filtering this order equals sorting each subset.
-        # (skey, tid) keys are unique per task (tids are unique), so the
-        # wide-activation lexsort yields the identical permutation.
-        if n >= _WIDE:
-            flex_order = np.lexsort(
-                (np.asarray(tids, dtype=np.int64), np.asarray(skey))
-            ).tolist()
-        else:
-            flex_order = sorted(all_idx, key=lambda i: (skey[i], tids[i]))
-        alpha = self.alpha
-        two_alpha = 2.0 + alpha
-        area_bound = self.area_bound
-        max_off = max(offsets, default=0.0)
-        n_res_alive = n_res - len(dead)
-
-        # ------------------------------------------------------------------
-        def try_build(lam: float) -> Optional[Tuple[Dict[int, int], List[float]]]:
-            # try_build is pure (touches only its locals), so the acceptance
-            # test `all(load <= (2+α)λ)` is folded into every load update:
-            # loads only grow, hence the first overflow already decides the
-            # probe — same verdict as building fully, minus the wasted work.
-            cap = two_alpha * lam + _TINY
-            if max_off > cap:
-                return None
-            if area_bound:
-                capacity = lam * n_res_alive - off_total
-                if area > capacity + _TINY:
-                    return None  # certificate: no λ-schedule exists
-            loads = offsets.copy()
-            assign: Dict[int, int] = {}
-
-            # ---- local affinity phase (line 5-7) -------------------------
-            if by_score:
-                budget = alpha * lam + _TINY
-                for sc, tid, rid, c in by_score:
-                    if loads[rid] <= budget:
-                        assign[tid] = rid
-                        v = loads[rid] + c
-                        if v > cap:
-                            return None
-                        loads[rid] = v
-
-            # ---- global balance phase (line 8-9) -------------------------
-            if assign:
-                rem = [i for i in all_idx if tids[i] not in assign]
+                    )
+                else:
+                    by_score = []
             else:
-                rem = all_idx
-            for i in rem:  # reject if a task is larger than λ everywhere
-                big_cpu = no_cpus or p_cpu[i] > lam
-                big_gpu = no_gpus or p_gpu[i] > lam
-                if big_cpu and big_gpu:
+                if self.alpha > 0.0:
+                    S_rows = affinity_rows(
+                        self.affinity_name, sim.arrays, tids, ready, resources,
+                        sim.residency,
+                    )
+                    for i, row in enumerate(S_rows):
+                        if not any(row):
+                            continue  # all-zero (C-level falsy) row: no preference
+                        best_score, best_rid = 0.0, -1
+                        for rid in range(n_res):
+                            if rid in dead:
+                                continue  # affinity to a vanished memory is void
+                            if rid in noticed_pen:
+                                # affinity to a condemned memory is a trap:
+                                # the data is leaving with the device
+                                continue
+                            s = row[rid]
+                            if s > best_score + _TINY:
+                                best_score, best_rid = s, rid
+                        if best_rid >= 0:
+                            pref.append(
+                                (best_score, tids[i], best_rid, C_rows[i][best_rid])
+                            )
+                by_score = sorted(pref, key=lambda x: (-x[0], x[1]))
+
+            # speedup sort keys for the flexible phase (λ-independent)
+            skey = [-(pc / max(pg, _TINY)) for pc, pg in zip(p_cpu, p_gpu)]
+
+            cpu_rids = [r.rid for r in cpus if r.rid not in dead]
+            gpu_rids = [r.rid for r in gpus if r.rid not in dead]
+            any_rids = cpu_rids or gpu_rids
+            if not any_rids:
+                raise RuntimeError("DADA: every resource is detached")
+            have_both = bool(cpu_rids and gpu_rids)
+            no_cpus = not cpu_rids
+            no_gpus = not gpu_rids
+
+            if self.area_bound:
+                area = sum(min(pc, pg) for pc, pg in zip(p_cpu, p_gpu))
+                off_total = sum(offsets)
+
+            all_idx = list(range(n))
+            # global flex order (λ-independent): per-probe flex sets are subsets
+            # of ready, so filtering this order equals sorting each subset.
+            # (skey, tid) keys are unique per task (tids are unique), so the
+            # wide-activation lexsort yields the identical permutation.
+            if n >= _WIDE:
+                flex_order = np.lexsort(
+                    (np.asarray(tids, dtype=np.int64), np.asarray(skey))
+                ).tolist()
+            else:
+                flex_order = sorted(all_idx, key=lambda i: (skey[i], tids[i]))
+            alpha = self.alpha
+            two_alpha = 2.0 + alpha
+            area_bound = self.area_bound
+            max_off = max(offsets, default=0.0)
+            n_res_alive = n_res - len(dead)
+
+            # ------------------------------------------------------------------
+            def try_build(lam: float) -> Optional[Tuple[Dict[int, int], List[float]]]:
+                # try_build is pure (touches only its locals), so the acceptance
+                # test `all(load <= (2+α)λ)` is folded into every load update:
+                # loads only grow, hence the first overflow already decides the
+                # probe — same verdict as building fully, minus the wasted work.
+                cap = two_alpha * lam + _TINY
+                if max_off > cap:
                     return None
+                if area_bound:
+                    capacity = lam * n_res_alive - off_total
+                    if area > capacity + _TINY:
+                        return None  # certificate: no λ-schedule exists
+                loads = offsets.copy()
+                assign: Dict[int, int] = {}
 
-            flex = None
-            if have_both:
-                flex = bytearray(n)
-                for i in rem:
-                    if p_cpu[i] > lam:
-                        pool_rids = gpu_rids  # dedicated to GPUs
-                    elif p_gpu[i] > lam:
-                        pool_rids = cpu_rids  # dedicated to CPUs
-                    else:
-                        flex[i] = 1
-                        continue
-                    # earliest finish time; first minimum wins (== min by
-                    # (finish, rid): pool rids are ascending)
-                    crow = C_rows[i]
-                    best_v = float("inf")
-                    best_rid = pool_rids[0]
-                    for rid in pool_rids:
-                        v = loads[rid] + crow[rid]
-                        if v < best_v:
-                            best_v = v
-                            best_rid = rid
-                    if best_v > cap:
-                        return None
-                    assign[tids[i]] = best_rid
-                    loads[best_rid] = best_v
-            else:
-                for i in rem:
-                    crow = C_rows[i]
-                    best_v = float("inf")
-                    best_rid = any_rids[0]
-                    for rid in any_rids:
-                        v = loads[rid] + crow[rid]
-                        if v < best_v:
-                            best_v = v
-                            best_rid = rid
-                    if best_v > cap:
-                        return None
-                    assign[tids[i]] = best_rid
-                    loads[best_rid] = best_v
-
-            # flexible tasks: largest speedup first, to GPUs up to
-            # overreaching λ, the rest to CPUs (earliest finish time)
-            if flex is not None:
-                gpu_budget = lam + _TINY
-                for i in flex_order:
-                    if not flex[i]:
-                        continue
-                    if gpu_rids:
-                        g = gpu_rids[0]
-                        gl = loads[g]
-                        for rid in gpu_rids[1:]:
-                            if loads[rid] < gl:
-                                gl = loads[rid]
-                                g = rid
-                        if gl <= gpu_budget:
-                            v = gl + C_rows[i][g]
+                # ---- local affinity phase (line 5-7) -------------------------
+                if by_score:
+                    budget = alpha * lam + _TINY
+                    for sc, tid, rid, c in by_score:
+                        if loads[rid] <= budget:
+                            assign[tid] = rid
+                            v = loads[rid] + c
                             if v > cap:
                                 return None
-                            assign[tids[i]] = g
-                            loads[g] = v
-                            continue
-                    crow = C_rows[i]
-                    best_v = float("inf")
-                    best_rid = any_rids[0]
-                    for rid in any_rids:
-                        v = loads[rid] + crow[rid]
-                        if v < best_v:
-                            best_v = v
-                            best_rid = rid
-                    if best_v > cap:
+                            loads[rid] = v
+
+                # ---- global balance phase (line 8-9) -------------------------
+                if assign:
+                    rem = [i for i in all_idx if tids[i] not in assign]
+                else:
+                    rem = all_idx
+                for i in rem:  # reject if a task is larger than λ everywhere
+                    big_cpu = no_cpus or p_cpu[i] > lam
+                    big_gpu = no_gpus or p_gpu[i] > lam
+                    if big_cpu and big_gpu:
                         return None
-                    assign[tids[i]] = best_rid
-                    loads[best_rid] = best_v
 
-            # acceptance (line 10) already enforced incrementally above
-            return assign, loads
+                flex = None
+                if have_both:
+                    flex = bytearray(n)
+                    for i in rem:
+                        if p_cpu[i] > lam:
+                            pool_rids = gpu_rids  # dedicated to GPUs
+                        elif p_gpu[i] > lam:
+                            pool_rids = cpu_rids  # dedicated to CPUs
+                        else:
+                            flex[i] = 1
+                            continue
+                        # earliest finish time; first minimum wins (== min by
+                        # (finish, rid): pool rids are ascending)
+                        crow = C_rows[i]
+                        best_v = float("inf")
+                        best_rid = pool_rids[0]
+                        for rid in pool_rids:
+                            v = loads[rid] + crow[rid]
+                            if v < best_v:
+                                best_v = v
+                                best_rid = rid
+                        if best_v > cap:
+                            return None
+                        assign[tids[i]] = best_rid
+                        loads[best_rid] = best_v
+                else:
+                    for i in rem:
+                        crow = C_rows[i]
+                        best_v = float("inf")
+                        best_rid = any_rids[0]
+                        for rid in any_rids:
+                            v = loads[rid] + crow[rid]
+                            if v < best_v:
+                                best_v = v
+                                best_rid = rid
+                        if best_v > cap:
+                            return None
+                        assign[tids[i]] = best_rid
+                        loads[best_rid] = best_v
 
-        # ------------------------------------------------------------------
-        # binary search on λ (classical dual-approximation driver)
-        worst_xfer = 0.0
-        if fused is not None and fused["X_rowmax"] is not None:
-            # device-reduced per-row maxima equal max(xrow) (max is
-            # order-independent); the host fold order is unchanged
-            for v in fused["X_rowmax"]:
-                worst_xfer += v
-        elif X is not None:
-            for xrow in X:
-                worst_xfer += max(xrow)
-        upper = (
-            sum(max(pc, pg) for pc, pg in zip(p_cpu, p_gpu))
-            + max_off
-            + worst_xfer
-            + _TINY
-        )
-        if noticed_pen:
-            # the notice penalties inflate C, so the feasibility anchor
-            # must cover them too (λ=upper stays provably feasible)
-            upper += n * max(noticed_pen.values())
-        lower = 0.0
-        kept: Optional[Tuple[Dict[int, int], List[float]]] = None
-        searched = False
+                # flexible tasks: largest speedup first, to GPUs up to
+                # overreaching λ, the rest to CPUs (earliest finish time)
+                if flex is not None:
+                    gpu_budget = lam + _TINY
+                    for i in flex_order:
+                        if not flex[i]:
+                            continue
+                        if gpu_rids:
+                            g = gpu_rids[0]
+                            gl = loads[g]
+                            for rid in gpu_rids[1:]:
+                                if loads[rid] < gl:
+                                    gl = loads[rid]
+                                    g = rid
+                            if gl <= gpu_budget:
+                                v = gl + C_rows[i][g]
+                                if v > cap:
+                                    return None
+                                assign[tids[i]] = g
+                                loads[g] = v
+                                continue
+                        crow = C_rows[i]
+                        best_v = float("inf")
+                        best_rid = any_rids[0]
+                        for rid in any_rids:
+                            v = loads[rid] + crow[rid]
+                            if v < best_v:
+                                best_v = v
+                                best_rid = rid
+                        if best_v > cap:
+                            return None
+                        assign[tids[i]] = best_rid
+                        loads[best_rid] = best_v
+
+                # acceptance (line 10) already enforced incrementally above
+                return assign, loads
+
+            # ------------------------------------------------------------------
+            # binary search on λ (classical dual-approximation driver)
+            worst_xfer = 0.0
+            if fused is not None and fused["X_rowmax"] is not None:
+                # device-reduced per-row maxima equal max(xrow) (max is
+                # order-independent); the host fold order is unchanged
+                for v in fused["X_rowmax"]:
+                    worst_xfer += v
+            elif X is not None:
+                for xrow in X:
+                    worst_xfer += max(xrow)
+            upper = (
+                sum(max(pc, pg) for pc, pg in zip(p_cpu, p_gpu))
+                + max_off
+                + worst_xfer
+                + _TINY
+            )
+            if noticed_pen:
+                # the notice penalties inflate C, so the feasibility anchor
+                # must cover them too (λ=upper stays provably feasible)
+                upper += n * max(noticed_pen.values())
+            lower = 0.0
         if use_backend_search:
             # the whole λ binary search runs as one backend dispatch; the
             # returned λ is bit-identical to the Python loop's final
@@ -476,39 +482,47 @@ class DADA(ScoringBackendMixin, Strategy):
                 max_iters=self.max_iters,
                 upper0=upper,
             )
-            built = try_build(lam_final)
-            if built is not None:
-                upper = lam_final
-                kept = built
-                searched = True
-            else:
-                # defensive — a divergent verdict would leave an
-                # infeasible λ; counted, then the Python search below
-                be.counts["rejected"] += 1
-        if not searched:
-            it = 0
-            while upper - lower > self.eps_rel * upper and it < self.max_iters:
-                lam = (upper + lower) / 2.0
-                built = try_build(lam)
+        # the placement at the final λ; where the search runs on the host
+        # (narrow activations, or a rejected device λ) dada.search_host
+        # nests inside this span
+        with obs.span("dada.rebuild"):
+            kept: Optional[Tuple[Dict[int, int], List[float]]] = None
+            searched = False
+            if use_backend_search:
+                built = try_build(lam_final)
                 if built is not None:
-                    upper = lam
+                    upper = lam_final
                     kept = built
+                    searched = True
                 else:
-                    lower = lam
-                it += 1
-            if kept is None:
-                kept = try_build(upper)
-                assert kept is not None, "λ=upper must always be feasible"
+                    # defensive — a divergent verdict would leave an
+                    # infeasible λ; counted, then the Python search below
+                    be.counts["rejected"] += 1
+            if not searched:
+                with obs.span("dada.search_host"):
+                    it = 0
+                    while upper - lower > self.eps_rel * upper and it < self.max_iters:
+                        lam = (upper + lower) / 2.0
+                        built = try_build(lam)
+                        if built is not None:
+                            upper = lam
+                            kept = built
+                        else:
+                            lower = lam
+                        it += 1
+                    if kept is None:
+                        kept = try_build(upper)
+                        assert kept is not None, "λ=upper must always be feasible"
 
-        assign, loads = kept
-        # expose the accepted guess for tests / introspection
-        self.last_lambda = upper
-        self.last_loads = {r.rid: loads[j] for j, r in enumerate(resources)}
-        for t in ready:
-            rid = assign[t.tid]
-            sim.push(t, rid)
-        for j, r in enumerate(resources):
-            sim.load_ts[r.rid] = sim.now + loads[j]
+            assign, loads = kept
+            # expose the accepted guess for tests / introspection
+            self.last_lambda = upper
+            self.last_loads = {r.rid: loads[j] for j, r in enumerate(resources)}
+            for t in ready:
+                rid = assign[t.tid]
+                sim.push(t, rid)
+            for j, r in enumerate(resources):
+                sim.load_ts[r.rid] = sim.now + loads[j]
 
 
 class DualApprox(DADA):

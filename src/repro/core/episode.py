@@ -44,11 +44,13 @@ in CI, not bit-equality.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.backend import _bucket, enable_compile_cache
+from repro.core import obs
+from repro.core.backend import _bucket, call_program, enable_compile_cache
 from repro.core.dag import TaskGraph
 from repro.core.machine import HOST_MEM, MachineModel
 
@@ -368,7 +370,7 @@ def _build_episode_fn(shape_key: tuple):
             mat, jnp.minimum(idx, mat.shape[1] - 1), axis=1
         )
 
-    def episode(
+    def surrogate_episode(
         read_ids, read_t, read_sz, write_ids, write_sz, succ_ids,
         indeg0, prio, dur_cpu, dur_gpu, sizes, col_bits, host_col,
         is_gpu, valid_res, mem_col, link_grp, alpha, use_cp, ws_pref,
@@ -601,7 +603,7 @@ def _build_episode_fn(shape_key: tuple):
             return mk, total_b, npl, ys
         return mk, total_b, npl
 
-    return jax.jit(episode)
+    return jax.jit(surrogate_episode)
 
 
 def run_episodes(
@@ -629,73 +631,78 @@ def run_episodes(
     import jax
     import jax.numpy as jnp
 
-    if config is None:
-        from repro.sched.config import current_config
+    with obs.span("episode.pack"):
+        if config is None:
+            from repro.sched.config import current_config
 
-        config = current_config()
+            config = current_config()
 
-    B = len(batch)
-    B_pad = pad_to if pad_to is not None else _bucket(B, lo=8)
-    if B_pad < B:
-        raise ValueError(f"pad_to={B_pad} smaller than batch ({B})")
-    use_cap = bool(np.isfinite(batch.cap).any())
-    mode = _pallas_mode(config.pallas, jax.default_backend())
-    use_pallas, interpret = mode != "off", mode == "interpret"
-    n_steps = plan.n + int(extra_steps)
+        B = len(batch)
+        B_pad = pad_to if pad_to is not None else _bucket(B, lo=8)
+        if B_pad < B:
+            raise ValueError(f"pad_to={B_pad} smaller than batch ({B})")
+        use_cap = bool(np.isfinite(batch.cap).any())
+        mode = _pallas_mode(config.pallas, jax.default_backend())
+        use_pallas, interpret = mode != "off", mode == "interpret"
+        n_steps = plan.n + int(extra_steps)
 
-    def padb(a: np.ndarray, fill=0) -> np.ndarray:
-        if B_pad == B:
-            return a
-        pad = np.full((B_pad - B,) + a.shape[1:], fill, dtype=a.dtype)
-        return np.concatenate([a, pad], axis=0)
+        def padb(a: np.ndarray, fill=0) -> np.ndarray:
+            if B_pad == B:
+                return a
+            pad = np.full((B_pad - B,) + a.shape[1:], fill, dtype=a.dtype)
+            return np.concatenate([a, pad], axis=0)
 
-    shape_key = (
-        B_pad, plan.n_pad, plan.r_pad, plan.w_pad, plan.s_pad,
-        plan.n_res, plan.n_u, plan.n_data + 1, n_steps,
-        use_cap, use_pallas, interpret, bool(emit_schedule),
-    )
-    fn = _EPISODE_CACHE.get(shape_key)
-    if fn is None:
-        fn = _EPISODE_CACHE[shape_key] = _build_episode_fn(shape_key)
+        shape_key = (
+            B_pad, plan.n_pad, plan.r_pad, plan.w_pad, plan.s_pad,
+            plan.n_res, plan.n_u, plan.n_data + 1, n_steps,
+            use_cap, use_pallas, interpret, bool(emit_schedule),
+        )
+        fn = _EPISODE_CACHE.get(shape_key)
+        if fn is None:
+            fn = _EPISODE_CACHE[shape_key] = _build_episode_fn(shape_key)
 
-    # the surrogate runs in f32: it reports *rankings* and relative error,
-    # and halving the scan's state traffic is most of its speed advantage
-    f32 = np.float32
-    res = fn(
-        jnp.asarray(plan.read_ids), jnp.asarray(plan.read_t, dtype=f32),
-        jnp.asarray(plan.read_sz, dtype=f32), jnp.asarray(plan.write_ids),
-        jnp.asarray(plan.write_sz, dtype=f32), jnp.asarray(plan.succ_ids),
-        jnp.asarray(plan.indeg0), jnp.asarray(plan.prio, dtype=f32),
-        jnp.asarray(plan.dur_cpu, dtype=f32),
-        jnp.asarray(plan.dur_gpu, dtype=f32),
-        jnp.asarray(plan.sizes, dtype=f32), jnp.asarray(plan.col_bits),
-        jnp.asarray(plan.host_col),
-        # padded batch rows: no valid resources -> every step inactive
-        jnp.asarray(padb(batch.is_gpu)),
-        jnp.asarray(padb(batch.valid_res)),
-        jnp.asarray(padb(batch.mem_col)),
-        jnp.asarray(padb(batch.link_grp)),
-        jnp.asarray(padb(batch.alpha), dtype=f32),
-        jnp.asarray(padb(batch.use_cp), dtype=f32),
-        jnp.asarray(padb(batch.ws_pref)),
-        jnp.asarray(padb(batch.noise, fill=1), dtype=f32),
-        jnp.asarray(padb(batch.cap, fill=np.inf), dtype=f32),
-        jnp.asarray(plan.bandwidth, dtype=f32),
-    )
-    mk, total_b, n_placed = res[0], res[1], res[2]
-    out = {
-        "makespan": np.asarray(mk)[:B].astype(np.float64),
-        "total_bytes": np.asarray(total_b)[:B].astype(np.float64),
-        "n_placed": np.asarray(n_placed)[:B],
-    }
-    if emit_schedule:
-        # scan stacks along the step axis: (n_steps, B) -> (B, n_steps)
-        names = ("tid", "rid", "act", "start", "xfer_t", "fin", "xfer_b",
-                 "evict_b")
-        out["schedule"] = {
-            name: np.asarray(col)[:, :B].T for name, col in zip(names, res[3])
-        }
-    return out
+        # the surrogate runs in f32: it reports *rankings* and relative error,
+        # and halving the scan's state traffic is most of its speed advantage
+        up = jnp.asarray
+        f32 = partial(jnp.asarray, dtype=np.float32)
+        args = [
+            (up, plan.read_ids), (f32, plan.read_t),
+            (f32, plan.read_sz), (up, plan.write_ids),
+            (f32, plan.write_sz), (up, plan.succ_ids),
+            (up, plan.indeg0), (f32, plan.prio),
+            (f32, plan.dur_cpu),
+            (f32, plan.dur_gpu),
+            (f32, plan.sizes), (up, plan.col_bits),
+            (up, plan.host_col),
+            # padded batch rows: no valid resources -> every step inactive
+            (up, padb(batch.is_gpu)),
+            (up, padb(batch.valid_res)),
+            (up, padb(batch.mem_col)),
+            (up, padb(batch.link_grp)),
+            (f32, padb(batch.alpha)),
+            (f32, padb(batch.use_cp)),
+            (up, padb(batch.ws_pref)),
+            (f32, padb(batch.noise, fill=1)),
+            (f32, padb(batch.cap, fill=np.inf)),
+            (f32, plan.bandwidth),
+        ]
+
+        def first_b(x):
+            return np.asarray(x)[:B]
+
+        def first_b_f64(x):
+            return first_b(x).astype(np.float64)
+
+        # outputs (makespan, total bytes, tasks placed[, schedule])
+        reads = [(0, first_b_f64), (1, first_b_f64), (2, first_b)]
+        if emit_schedule:
+            # scan stacks along the step axis: (n_steps, B) -> (B, n_steps)
+            names = ("tid", "rid", "act", "start", "xfer_t", "fin", "xfer_b",
+                     "evict_b")
+            reads.append((3, lambda cols: {
+                name: np.asarray(col)[:, :B].T for name, col in zip(names, cols)}))
+    _, host = call_program("episode", fn, args, reads)
+    return dict(zip(("makespan", "total_bytes", "n_placed", "schedule"), host))
 
 
 def ranking_mismatches(

@@ -330,3 +330,83 @@ def test_padded_shapes_bound_retraces(force_jax):
     # ready widths 1..15 at NT=6 → buckets {8, 16} × (chain, flags) variants
     grown = len(be._search_fns) - n_search_before
     assert grown <= 8, f"unbounded retraces: {grown} new search signatures"
+
+
+# ---------------------------------------------------------------------------
+# program spans and transfer counters (repro.core.obs)
+
+
+PHASES = ("pack", "upload", "dispatch", "readback")
+
+
+def test_spans_leave_placements_bit_equal_and_count_transfers(force_jax):
+    """With the recorder on, a device-scored DADA+CP schedule places as it
+    does with it off; every device-scored activation has one span of each
+    phase of both programs, and makes 28 uploads (7 for the score
+    matrices, 21 for the λ search) and 4 read-backs (C, the row maxima of
+    X and the affinity scores; λ)."""
+    from repro.core import obs
+
+    graph = cholesky_graph(5, 256, with_fns=False)
+    machine = paper_machine(3)
+    be = get_backend("jax")
+
+    def run():
+        before = dict(be.counts)
+        res = run_simulation(graph, machine, DADA(alpha=0.5, use_cp=True, backend="jax"),
+                             seed=0)
+        return res, {k: be.counts[k] - before[k] for k in be.counts}
+
+    off, n_off = run()
+    obs.drain()
+    obs.enable(True)
+    try:
+        on, n_on = run()
+    finally:
+        obs.enable(False)
+    spans = obs.drain()
+    assert _fingerprint(on) == _fingerprint(off)
+    assert n_on == n_off
+    assert n_on["device"] > 0 and n_on["outside"] == n_on["rejected"] == 0
+    assert n_on["uploads"] == 28 * n_on["device"]
+    assert n_on["readbacks"] == 4 * n_on["device"]
+
+    roots = {s.id for s in spans if s.name == "dada.place"}
+    assert all(s.root in roots for s in spans)
+    per_root = {}
+    for s in spans:
+        per_root.setdefault(s.root, []).append(s.name)
+    scored = [names for names in per_root.values() if "score.dispatch" in names]
+    assert len(scored) == n_on["device"]
+    for names in scored:
+        for prog in ("score", "search"):
+            for phase in PHASES:
+                assert names.count(f"{prog}.{phase}") == 1, (prog, phase, names)
+        assert "dada.search_host" not in names
+        for phase in ("predict", "order", "rebuild"):
+            assert names.count(f"dada.{phase}") == 1
+
+
+def test_programs_have_stable_names(force_jax):
+    """The jitted programs lower as modules named after what they do, so a
+    device trace can tell them apart."""
+    be = get_backend("jax")
+    run_simulation(
+        cholesky_graph(4, 256, with_fns=False), paper_machine(3),
+        HEFT(backend="jax"), seed=0,
+    )
+    run_simulation(
+        cholesky_graph(4, 256, with_fns=False), paper_machine(3),
+        DADA(alpha=0.5, use_cp=True, backend="jax"), seed=0,
+    )
+    names = {f.__name__ for fns in (be._matrix_fns, be._search_fns, be._heft_fns)
+             for f in fns.values()}
+    assert names == {"dada_score_matrices", "dada_lambda_search", "heft_select"}
+    (n_pad, n_res), fn = next(iter(be._heft_fns.items()))
+    f64 = be.f64.encode(np.zeros(0)).dtype
+    rows = jax.ShapeDtypeStruct((n_pad, n_res), f64)
+    with jax.enable_x64(True):
+        text = fn.lower(rows, rows, jax.ShapeDtypeStruct((n_pad,), bool),
+                        jax.ShapeDtypeStruct((n_res,), f64),
+                        jax.ShapeDtypeStruct((), f64)).as_text()
+    assert "@jit_heft_select" in text

@@ -228,6 +228,32 @@ def test_run_batch_preserves_input_order():
         assert a.total_bytes == b.total_bytes
 
 
+def test_run_batch_spans_share_one_root_across_worker_threads():
+    """With the span recorder on, every span of one run_batch call, those of
+    the chunks dispatched on worker threads included, has the call's
+    ``batch.run`` as its root, and results do not change."""
+    from repro.core import obs
+
+    graph = _graph("cholesky", 4)
+    items = [{"graph": graph, "machine": paper_machine(2), "strategy": "heft",
+              "seed": sd, "noise": NOISE} for sd in range(40)]  # three chunks
+    off = run_batch(items, config=CFG)
+    obs.drain()
+    obs.enable(True)
+    try:
+        on = run_batch(items, config=CFG)
+    finally:
+        obs.enable(False)
+    spans = obs.drain()
+    assert [r.makespan for r in on] == [r.makespan for r in off]
+    (root,) = [s for s in spans if s.name == "batch.run"]
+    assert all(s.root == root.id for s in spans)
+    names = [s.name for s in spans]
+    for phase in ("upload", "dispatch", "readback"):
+        assert names.count("episode." + phase) == 3
+    assert names.count("episode.pack") == 6  # configuration arrays, then the plan's
+
+
 def test_pallas_route_matches_jnp():
     """REPRO_SCHED_PALLAS=1 routes the episode's transfer rows through the
     Pallas CSR kernel (interpret mode on CPU) with identical results."""
