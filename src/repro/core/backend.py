@@ -21,7 +21,11 @@ accelerates the two placement hot spots on wide activations:
     Python binary-search loop. On CPU the default depth is 1 (the tree
     degenerates to plain bisection — speculative probes cost real time on
     a single core); on gpu/tpu it is 5, where the 31-probe vmap rides the
-    accelerator for free.
+    accelerator for free;
+  * **one transfer each way** — each DADA program takes its per-call
+    inputs as one packed int64 buffer and the score program returns what
+    the host reads as one (:class:`Packed`): a TPU pays a fixed latency
+    per transfer, whatever its size.
 
 Bit-for-bit contract: the backend only ever computes *score values* (which
 are IEEE-f64 op-for-op identical to the numpy path) and *feasibility
@@ -66,6 +70,7 @@ reads ``os.environ`` directly):
 """
 from __future__ import annotations
 
+import functools
 import sys
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -168,6 +173,14 @@ def accelerator_initialised() -> bool:
     return xla_bridge.backends_are_initialized() and jax.default_backend() != "cpu"
 
 
+def _pad_rows(x, n_pad: int) -> np.ndarray:
+    """The f64 rows of ``x``, then zero rows up to ``n_pad``."""
+    x = np.asarray(x, dtype=np.float64)
+    out = np.zeros((n_pad,) + x.shape[1:])
+    out[:len(x)] = x
+    return out
+
+
 def _bucket(n: int, lo: int = 8) -> int:
     """Next power-of-two ≥ n (≥ lo): bounds distinct jit signatures."""
     b = lo
@@ -200,14 +213,133 @@ class ScoringBackendMixin:
 def _x64_scoped(method):
     """Run a backend method under a temporarily-enabled x64 context so the
     f64 scoring math never leaks into the process-wide jax config."""
-    import functools
-
     @functools.wraps(method)
     def wrapper(self, *args, **kwargs):
         with self._x64():
             return method(self, *args, **kwargs)
 
     return wrapper
+
+
+class Packed:
+    """The layout of one int64 buffer that carries a program's per-call
+    values across the host–device boundary in one transfer: a TPU pays a
+    fixed latency per transfer (about 0.35 ms on a v5e), whatever its size.
+
+    ``fields`` are ``(name, shape, kind)``, ``kind`` one of ``"f64"`` (the
+    value's bit pattern), ``"i64"``, ``"i32"`` and ``"bool"`` (0 or 1).
+    Offsets follow from the shapes alone, so a program whose key fixes the
+    shapes slices its buffer at offsets known when it is traced. ``pack``
+    and ``split`` run on the host, ``unpack`` and ``join`` inside the
+    program, in the f64 arithmetic ``F`` (``repro.core.f64``). Every value
+    crosses bit for bit.
+    """
+
+    def __init__(self, fields: Sequence[Tuple[str, tuple, str]]) -> None:
+        self.fields = []
+        off = 0
+        for name, shape, kind in fields:
+            size = int(np.prod(shape, dtype=np.int64))
+            self.fields.append((name, tuple(shape), kind, off, size))
+            off += size
+        self.size = off
+
+    def pack(self, values: Dict[str, object]) -> np.ndarray:
+        """``values`` (each of its field's shape) in one host buffer."""
+        buf = np.empty(self.size, dtype=np.int64)
+        as_f64 = buf.view(np.float64)
+        for name, shape, kind, off, size in self.fields:
+            v = np.asarray(values[name], dtype=np.float64 if kind == "f64" else None)
+            if v.shape != shape:
+                raise ValueError(f"packed field {name!r}: shape {v.shape}, not {shape}")
+            (as_f64 if kind == "f64" else buf)[off:off + size] = v.reshape(-1)
+        return buf
+
+    def unpack(self, buf, F) -> Dict[str, object]:
+        """The fields of the device buffer ``buf``, typed as ``F`` computes."""
+        import jax.numpy as jnp
+
+        out = {}
+        for name, shape, kind, off, size in self.fields:
+            x = buf[off:off + size].reshape(shape)
+            if kind == "f64":
+                x = F.from_bits(x)
+            elif kind == "i32":
+                x = x.astype(jnp.int32)
+            elif kind == "bool":
+                x = x != 0
+            out[name] = x
+        return out
+
+    def join(self, values: Dict[str, object], F):
+        """``values`` (device arrays, as ``F`` computes) in one device buffer."""
+        import jax.numpy as jnp
+
+        parts = []
+        for name, shape, kind, _, _ in self.fields:
+            x = values[name]
+            x = F.to_bits(x) if kind == "f64" else x.astype(jnp.int64)
+            parts.append(x.reshape(-1))
+        return jnp.concatenate(parts)
+
+    def split(self, buf) -> Dict[str, np.ndarray]:
+        """The fields of a buffer read back to the host (f64 as ``float64``)."""
+        buf = np.asarray(buf, dtype=np.int64)
+        as_f64 = buf.view(np.float64)
+        out = {}
+        for name, shape, kind, off, size in self.fields:
+            x = (as_f64 if kind == "f64" else buf)[off:off + size].reshape(shape)
+            if kind == "i32":
+                x = x.astype(np.int32)
+            elif kind == "bool":
+                x = x != 0
+            out[name] = x
+        return out
+
+
+@functools.lru_cache(maxsize=None)
+def _score_layout(key) -> Tuple[Packed, Packed]:
+    """The score program's packed inputs and outputs, from its key."""
+    (n_pad, r_pad, w_pad, _, n_res,
+     want_x, x_rows, want_s, want_c, _, want_bias) = key
+    ins, outs = [], []
+    if want_x:
+        ins += [("read_masks", (n_pad, r_pad), "i64"), ("per_read", (n_pad, r_pad), "f64")]
+        outs += [("X", (n_pad, n_res), "f64") if x_rows else ("X_max", (n_pad,), "f64")]
+    if want_bias:
+        ins.append(("x_bias", (n_pad, n_res), "f64"))
+    if want_s:
+        ins += [("write_masks", (n_pad, w_pad), "i64"),
+                ("write_weights", (n_pad, w_pad), "f64")]
+        outs.append(("S", (n_pad, n_res), "f64"))
+    if want_c:
+        ins += [("p_cpu", (n_pad,), "f64"), ("p_gpu", (n_pad,), "f64")]
+        outs.insert(0, ("C", (n_pad, n_res), "f64"))
+    return Packed(ins), Packed(outs)
+
+
+# the λ search's scalars, in the order of its packed inputs
+_SEARCH_SCALARS = (
+    ("no_cpus", "bool"), ("no_gpus", "bool"), ("alpha", "f64"), ("two_alpha", "f64"),
+    ("area", "f64"), ("off_total", "f64"), ("max_off", "f64"), ("n_res_f", "f64"),
+    ("eps_rel", "f64"), ("max_iters", "i32"), ("upper0", "f64"),
+)
+
+
+@functools.lru_cache(maxsize=None)
+def _search_layout(key) -> Packed:
+    """The λ search's packed inputs, from its key."""
+    n_pad, chain_pad, n_res, n_cpu, n_gpu = key[:5]
+    fields = [("loads0", (n_res,), "f64"), ("p_cpu", (n_pad,), "f64"),
+              ("p_gpu", (n_pad,), "f64"), ("valid", (n_pad,), "bool"),
+              ("flex_ord", (n_pad,), "i32")]
+    if chain_pad:
+        fields += [("chain_cost", (chain_pad, n_res), "f64"),
+                   ("chain_valid", (chain_pad, n_res), "bool"),
+                   ("task_slot", (n_pad,), "i32")]
+    fields += [("cpu_idx", (n_cpu,), "i32"), ("gpu_idx", (n_gpu,), "i32")]
+    fields += [(name, (), kind) for name, kind in _SEARCH_SCALARS]
+    return Packed(fields)
 
 
 def call_program(prog: str, fn, args, reads, counts: Optional[dict] = None):
@@ -277,9 +409,10 @@ class JaxScoringBackend:
         # activations scored on the device vs returned to numpy because
         # they fall outside the supported envelope (``outside``) or because
         # the device's λ was not feasible on the host (``rejected``); the
-        # host->device arrays (scalars included) and device->host reads the
-        # programs made (``uploads``, ``readbacks``; the per-machine arrays,
-        # uploaded once, are not counted)
+        # host->device and device->host transfers the programs made
+        # (``uploads``, ``readbacks``: one packed buffer each way for each
+        # DADA program call; the per-machine arrays, uploaded once, are not
+        # counted)
         self.counts = {"device": 0, "outside": 0, "rejected": 0,
                        "uploads": 0, "readbacks": 0}
         self._matrix_fns: Dict[tuple, object] = {}
@@ -310,8 +443,6 @@ class JaxScoringBackend:
         if len(uniq) > self._MAX_UNIQ_MEMS:
             return None
         jnp = self.jnp
-        cpu_idx = [j for j, a in enumerate(accel) if not a]
-        gpu_idx = [j for j, a in enumerate(accel) if a]
         m = dict(
             uniq=tuple(uniq),
             col_of=jnp.asarray(col_of, dtype=jnp.int32),
@@ -394,7 +525,6 @@ class JaxScoringBackend:
             n_pad = _bucket(n)
             tids_arr = np.asarray(tids, dtype=np.int64)
             uniq = mach["uniq"]
-            jnp = self.jnp
 
             want_x = use_cp
             aff_src = affinity_csr_source(affinity, arr) if affinity else None
@@ -403,12 +533,12 @@ class JaxScoringBackend:
                 self.counts["outside"] += 1
                 return None
             want_bias = want_x and x_bias is not None
+            vals = {}
             if want_bias:
-                bias = np.zeros((n_pad, len(resources)), dtype=np.float64)
-                bias[:n] = x_bias
-            else:
-                bias = np.zeros((1, 1), dtype=np.float64)
+                vals["x_bias"] = _pad_rows(x_bias, n_pad)
 
+            r_pad = w_pad = 0
+            accel_only = False
             if want_x:
                 r_indptr, r_ids, r_sizes = arr.gather_csr(
                     tids_arr, arr.read_indptr, arr.read_ids, arr.read_sizes
@@ -418,15 +548,12 @@ class JaxScoringBackend:
                 read_masks, read_sizes = self._pad_csr(
                     r_indptr, [r_masks, r_sizes], n_pad, r_pad
                 )
+                vals["read_masks"] = read_masks
                 # per-read one-hop times on the host, as the numpy path has them
-                per_read = np.where(
+                vals["per_read"] = np.where(
                     read_sizes <= 0.0, 0.0,
                     mach["latency"] + read_sizes / mach["bandwidth"],
                 )
-            else:
-                r_pad = 0
-                read_masks = np.zeros((n_pad, 1), dtype=np.int64)
-                per_read = np.zeros((n_pad, 1))
 
             if want_s:
                 w_indptr_full, w_ids_full, w_weights_full, accel_only = aff_src
@@ -435,23 +562,13 @@ class JaxScoringBackend:
                 )
                 w_pad = _bucket(int((w_indptr[1:] - w_indptr[:-1]).max(initial=1)), lo=1)
                 w_masks = residency.mask_of_ids(w_ids)
-                write_masks, write_weights = self._pad_csr(
+                vals["write_masks"], vals["write_weights"] = self._pad_csr(
                     w_indptr, [w_masks, w_weights.astype(np.float64)], n_pad, w_pad
                 )
-            else:
-                w_pad = 0
-                accel_only = False
-                write_masks = np.zeros((n_pad, 1), dtype=np.int64)
-                write_weights = np.zeros((n_pad, 1))
 
             want_c = p_cpu is not None
             if want_c:
-                pc = np.zeros(n_pad, dtype=np.float64)
-                pg = np.zeros(n_pad, dtype=np.float64)
-                pc[:n] = p_cpu
-                pg[:n] = p_gpu
-            else:
-                pc = pg = np.zeros(n_pad, dtype=np.float64)
+                vals["p_cpu"], vals["p_gpu"] = _pad_rows(p_cpu, n_pad), _pad_rows(p_gpu, n_pad)
 
             key = (n_pad, r_pad, w_pad, len(uniq), len(resources),
                    want_x, bool(x_rows), want_s, want_c, accel_only, want_bias)
@@ -459,34 +576,25 @@ class JaxScoringBackend:
             if fn is None:
                 fn = self._build_matrix_fn(key)
                 self._matrix_fns[key] = fn
-            enc = self.f64.encode
-            up = jnp.asarray
+            ins, outs = _score_layout(key)
             args = [
-                (up, read_masks), (up, enc(per_read)),
-                (up, write_masks), (up, enc(write_weights)),
-                (up, enc(pc)), (up, enc(pg)), (up, enc(bias)),
+                (self.jax.device_put, ins.pack(vals)),
                 (None, mach["mem_shift"]), (None, mach["host_col"]),
                 (None, mach["col_of"]), (None, mach["accel_res"]),
             ]
-            # outputs (C, X, X_max, S): the ones this activation reads
-            wanted = [(k, i) for i, (k, on) in enumerate((
-                ("C_np", want_c), ("X_np", want_x and x_rows),
-                ("X_rowmax", want_x and not x_rows), ("S_np", want_s))) if on]
 
         def dec(x):
-            return self.f64.decode(x)[:n]
+            return {k: v[:n] for k, v in outs.split(x).items()}
 
-        raw, host = call_program("score", fn, args, [(i, dec) for _, i in wanted],
-                                 self.counts)
+        raw, (host,) = call_program("score", fn, args, [(1, dec)], self.counts)
         self.counts["device"] += 1
-        out = dict(C=None, C_np=None, C_dev=None, X_np=None,
-                   X_rowmax=None, S_np=None)
-        out.update(zip((k for k, _ in wanted), host))
+        out = dict(C=None, C_np=host.get("C"), C_dev=None, X_np=host.get("X"),
+                   X_rowmax=None, S_np=host.get("S"))
         if want_c:
             out["C_dev"] = raw[0]
             out["C"] = out["C_np"].tolist()
-        if out["X_rowmax"] is not None:
-            out["X_rowmax"] = out["X_rowmax"].tolist()
+        if "X_max" in host:
+            out["X_rowmax"] = host["X_max"].tolist()
         return out
 
     def _build_matrix_fn(self, key):
@@ -494,10 +602,15 @@ class JaxScoringBackend:
          want_x, x_rows, want_s, want_c, accel_only, want_bias) = key
         jax, jnp = self.jax, self.jnp
         F = self.f64
+        ins, outs = _score_layout(key)
 
-        def dada_score_matrices(read_masks, per_read, write_masks, write_weights,
-                                p_cpu, p_gpu, x_bias, mem_shift, host_col,
-                                col_of, accel_res):
+        def dada_score_matrices(packed, mem_shift, host_col, col_of, accel_res):
+            """``(C, bits)``: the cost matrix, kept on the device for the λ
+            search, and one buffer of the outputs the host reads."""
+            (read_masks, per_read, x_bias, write_masks, write_weights,
+             p_cpu, p_gpu) = map(ins.unpack(packed, F).get, (
+                 "read_masks", "per_read", "x_bias", "write_masks",
+                 "write_weights", "p_cpu", "p_gpu"))
             X_res = None
             X_max = None
             if want_x:
@@ -542,7 +655,7 @@ class JaxScoringBackend:
                 C = F.add(base, X_res) if want_x else jnp.broadcast_to(
                     base, (n_pad, n_res)
                 )
-            return C, X_res, X_max, S_res
+            return C, outs.join(dict(C=C, X=X_res, X_max=X_max, S=S_res), F)
 
         return jax.jit(dada_score_matrices)
 
@@ -585,28 +698,23 @@ class JaxScoringBackend:
         from :meth:`score_matrices` (same ``_bucket(n)`` padding).
         """
         with obs.span("search.pack"):
-            jnp = self.jnp
             n_pad = _bucket(n)
             assert C_dev.shape == (n_pad, n_res), (C_dev.shape, n_pad, n_res)
 
             accel = [r.is_accelerator for r in resources]
-            cpu_idx = np.asarray(
-                [j for j, a in enumerate(accel) if not a], dtype=np.int32
+            vals = dict(
+                loads0=offsets, alpha=alpha, two_alpha=2.0 + alpha, area=area,
+                off_total=off_total, max_off=max_off, n_res_f=float(n_res),
+                eps_rel=eps_rel, max_iters=max_iters, upper0=upper0,
+                no_cpus=no_cpus, no_gpus=no_gpus,
+                cpu_idx=[j for j, a in enumerate(accel) if not a],
+                gpu_idx=[j for j, a in enumerate(accel) if a],
+                p_cpu=_pad_rows(p_cpu, n_pad), p_gpu=_pad_rows(p_gpu, n_pad),
+                valid=np.arange(n_pad) < n,
             )
-            gpu_idx = np.asarray(
-                [j for j, a in enumerate(accel) if a], dtype=np.int32
-            )
-
-            pc = np.zeros(n_pad, dtype=np.float64)
-            pg = np.zeros(n_pad, dtype=np.float64)
-            pc[:n] = p_cpu
-            pg[:n] = p_gpu
-            valid = np.zeros(n_pad, dtype=bool)
-            valid[:n] = True
-            F = self.f64
             # padded flex_order entries point at row 0; the search masks them
             # with the position-validity of `valid` (True exactly for k < n)
-            ford = np.zeros(n_pad, dtype=np.int32)
+            vals["flex_ord"] = ford = np.zeros(n_pad, dtype=np.int64)
             ford[:n] = flex_order
 
             # Affinity phase → per-resource chains: entry k of by_score only
@@ -618,7 +726,7 @@ class JaxScoringBackend:
             # (task_slot points at the task's (chain position, rid) cell; the
             # appended always-False cell absorbs tasks without a preference).
             m = len(by_score)
-            task_slot = np.full(n_pad, 0, dtype=np.int32)
+            chain_pad = 0
             if m:
                 rids = np.fromiter((e[2] for e in by_score), np.int64, m)
                 costs = np.fromiter((e[3] for e in by_score), np.float64, m)
@@ -634,29 +742,19 @@ class JaxScoringBackend:
                 chain_valid = np.zeros((chain_pad, n_res), dtype=bool)
                 chain_cost[pos, srid] = costs[perm]
                 chain_valid[pos, srid] = True
-                task_slot[:] = chain_pad * n_res  # the appended False cell
-                task_slot[tis[perm]] = (pos * n_res + srid).astype(np.int32)
-            else:
-                chain_pad = 0
-                chain_cost = np.zeros((1, n_res), dtype=np.float64)
-                chain_valid = np.zeros((1, n_res), dtype=bool)
+                task_slot = np.full(n_pad, chain_pad * n_res, dtype=np.int64)
+                task_slot[tis[perm]] = pos * n_res + srid
+                vals.update(chain_cost=chain_cost, chain_valid=chain_valid,
+                            task_slot=task_slot)
 
-            key = (n_pad, chain_pad, n_res, len(cpu_idx), len(gpu_idx),
+            key = (n_pad, chain_pad, n_res, len(vals["cpu_idx"]), len(vals["gpu_idx"]),
                    bool(have_both), bool(area_bound), self.depth)
             fn = self._search_fns.get(key)
             if fn is None:
                 fn = self._build_search_fn(key)
                 self._search_fns[key] = fn
-            up, enc, const = jnp.asarray, F.encode, F.const
-            args = [
-                (up, enc(offsets)), (None, C_dev), (up, enc(pc)), (up, enc(pg)),
-                (up, valid), (up, ford), (up, enc(chain_cost)), (up, chain_valid),
-                (up, task_slot), (up, cpu_idx), (up, gpu_idx),
-                (jnp.bool_, no_cpus), (jnp.bool_, no_gpus),
-                (const, alpha), (const, 2.0 + alpha), (const, area),
-                (const, off_total), (const, max_off), (const, float(n_res)),
-                (const, eps_rel), (jnp.int32, max_iters), (const, upper0),
-            ]
+            args = [(self.jax.device_put, _search_layout(key).pack(vals)), (None, C_dev)]
+        F = self.f64
         _, (upper,) = call_program(
             "search", fn, args, [(None, lambda x: float(F.decode(x)))], self.counts)
         return upper
@@ -669,12 +767,17 @@ class JaxScoringBackend:
         F = self.f64
         add, sub, mul, lt, le = F.add, F.sub, F.mul, F.lt, F.le
         K = 2 ** depth - 1
+        layout = _search_layout(key)
 
-        def search(loads0, C, p_cpu, p_gpu, valid, flex_ord,
-                   chain_cost, chain_valid, task_slot,
-                   cpu_idx, gpu_idx, no_cpus, no_gpus,
-                   alpha, two_alpha, area, off_total, max_off, n_res_f,
-                   eps_rel, max_iters, upper0):
+        def search(packed, C):
+            (loads0, p_cpu, p_gpu, valid, flex_ord, chain_cost, chain_valid,
+             task_slot, cpu_idx, gpu_idx, no_cpus, no_gpus, alpha, two_alpha,
+             area, off_total, max_off, n_res_f, eps_rel, max_iters,
+             upper0) = map(layout.unpack(packed, F).get, (
+                 "loads0", "p_cpu", "p_gpu", "valid", "flex_ord", "chain_cost",
+                 "chain_valid", "task_slot", "cpu_idx", "gpu_idx", "no_cpus",
+                 "no_gpus", "alpha", "two_alpha", "area", "off_total", "max_off",
+                 "n_res_f", "eps_rel", "max_iters", "upper0"))
             TINY, INF, HALF = F.const(_TINY), F.const(float("inf")), F.const(0.5)
             # probe-invariant gathers, done once per search
             if have_both:

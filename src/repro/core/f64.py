@@ -20,6 +20,8 @@ Both give the same bits. Inputs are finite or +inf; no NaN reaches here.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 _FRAC = (1 << 52) - 1
@@ -28,6 +30,26 @@ _MAG = (1 << 63) - 1  # every bit but the sign
 _INF_BITS = 0x7FF << 52
 _SIGN = -(1 << 63)  # the sign bit, as an int64
 _NEG_INF_BITS = _INF_BITS | _SIGN
+
+
+def _traced_once(fn):
+    """``fn`` as a nested ``jax.jit``: inside a program it is traced once per
+    argument shape and dtype, not at every call site. A soft operation is
+    dozens of integer ops, and tracing them anew at each of a program's call
+    sites was most of the time it took to build (or to look up in the
+    compile cache) a scoring program. XLA inlines the nested calls, so the
+    compiled program computes the same ops and the same bits."""
+    jitted = []
+
+    @functools.wraps(fn)
+    def call(*args):
+        if not jitted:
+            import jax
+
+            jitted.append(jax.jit(fn))
+        return jitted[0](*args)
+
+    return call
 
 
 class _Native:
@@ -51,6 +73,22 @@ class _Native:
         import jax.numpy as jnp
 
         return jnp.float64(x)
+
+    @staticmethod
+    def from_bits(x):
+        """On the device: the f64 values whose int64 bit patterns are ``x``."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        return lax.bitcast_convert_type(x, jnp.float64)
+
+    @staticmethod
+    def to_bits(x):
+        """On the device: the int64 bit patterns of the f64 values ``x``."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        return lax.bitcast_convert_type(x, jnp.int64)
 
     @staticmethod
     def add(a, b):
@@ -113,19 +151,31 @@ class _Soft:
         return jnp.int64(np.float64(x).view(np.int64))
 
     @staticmethod
+    def from_bits(x):
+        # the values already are their bit patterns
+        return x
+
+    @staticmethod
+    def to_bits(x):
+        return x
+
+    @staticmethod
+    @_traced_once
     def key(a):
         # sign-magnitude -> two's-complement order; -0.0 and +0.0 tie
         import jax.numpy as jnp
 
         return jnp.where(a < 0, -(a & _MAG), a)
 
-    @classmethod
-    def lt(cls, a, b):
-        return cls.key(a) < cls.key(b)
+    @staticmethod
+    @_traced_once
+    def lt(a, b):
+        return _Soft.key(a) < _Soft.key(b)
 
-    @classmethod
-    def le(cls, a, b):
-        return cls.key(a) <= cls.key(b)
+    @staticmethod
+    @_traced_once
+    def le(a, b):
+        return _Soft.key(a) <= _Soft.key(b)
 
     @classmethod
     def min(cls, a, axis=None):
@@ -174,8 +224,9 @@ class _Soft:
         lost = (x & ((jnp.int64(1) << d) - 1)) != 0
         return (x >> d) | lost.astype(jnp.int64)
 
-    @classmethod
-    def add(cls, a, b):
+    @staticmethod
+    @_traced_once
+    def add(a, b):
         import jax.numpy as jnp
         from jax import lax
 
@@ -189,7 +240,7 @@ class _Soft:
         fx = (x & _FRAC) | jnp.where(ex > 0, _IMPLICIT, 0)
         fy = (y & _FRAC) | jnp.where(ey > 0, _IMPLICIT, 0)
         ex, ey = jnp.maximum(ex, 1), jnp.maximum(ey, 1)
-        fy = cls._shift_right_sticky(fy << 3, ex - ey)
+        fy = _Soft._shift_right_sticky(fy << 3, ex - ey)
         fx = fx << 3
         same = sx == sy
         s = jnp.where(same, fx + fy, fx - fy)
@@ -203,7 +254,7 @@ class _Soft:
         sh = jnp.where(s == 0, 0, jnp.minimum(lz, e - 1))
         s = s << sh
         e = e - sh
-        out = cls._round(sx, e, s)
+        out = _Soft._round(sx, e, s)
         zero = jnp.where(sx & sy, _SIGN, jnp.int64(0))
         out = jnp.where(s == 0, zero, out)
         return jnp.where(x >> 52 == 0x7FF, jnp.where(sx, x | _SIGN, x), out)
@@ -214,8 +265,9 @@ class _Soft:
 
         return cls.add(a, jnp.asarray(b, jnp.int64) ^ _SIGN)
 
-    @classmethod
-    def mul(cls, a, b):
+    @staticmethod
+    @_traced_once
+    def mul(a, b):
         import jax.numpy as jnp
         from jax import lax
 
@@ -243,9 +295,9 @@ class _Soft:
         s = (hi << (54 - k)) | (lo >> k) | ((lo & ((jnp.int64(1) << k) - 1)) != 0)
         e = e + top.astype(jnp.int64)
         # results below the normal range: shift into the subnormal range
-        s = jnp.where(e < 1, cls._shift_right_sticky(s, 1 - e), s)
+        s = jnp.where(e < 1, _Soft._shift_right_sticky(s, 1 - e), s)
         e = jnp.maximum(e, 1)
-        out = cls._round(sign, e, s)
+        out = _Soft._round(sign, e, s)
         zero = jnp.where(sign, _SIGN, jnp.int64(0))
         out = jnp.where((fa == 0) | (fb == 0), zero, out)
         inf = (ea == 0x7FF) | (eb == 0x7FF)

@@ -110,3 +110,69 @@ def test_soft_backend_places_like_numpy(soft_backend, graph, machine, strat):
     assert _fingerprint(a) == _fingerprint(b)
     assert soft_backend.counts["device"] > 0
     assert soft_backend.counts["outside"] == soft_backend.counts["rejected"] == 0
+
+
+# every f64 bit pattern the scoring programs can be handed: signed zeros,
+# infinities, the subnormal range's ends, the normal range's ends
+_EDGES = np.array([
+    0.0, -0.0, np.inf, -np.inf, 5e-324, -5e-324, 2.225073858507201e-308,
+    np.finfo(np.float64).tiny, np.finfo(np.float64).max, -np.finfo(np.float64).max,
+    1.0, 0.1, -1e-300, 1.5e300,
+])
+_I32 = np.iinfo(np.int32)
+
+
+@pytest.mark.parametrize("arith", [f64.NATIVE, f64.SOFT], ids=["native", "soft"])
+def test_packed_boundary_round_trips_every_bit(arith):
+    """One packed buffer in, typed fields in the program, one packed buffer
+    out: every f64 bit pattern, flag and index survives unchanged."""
+    layout = backend_mod.Packed([
+        ("edges", _EDGES.shape, "f64"), ("grid", (2, 7), "f64"), ("lam", (), "f64"),
+        ("flags", (4,), "bool"), ("on", (), "bool"),
+        ("idx", (4,), "i32"), ("iters", (), "i32"), ("masks", (3,), "i64"),
+    ])
+    values = dict(
+        edges=_EDGES, grid=_EDGES.reshape(2, 7)[::-1], lam=-0.0,
+        flags=np.array([True, False, False, True]), on=True,
+        idx=np.array([_I32.min, -1, 0, _I32.max]), iters=_I32.max,
+        masks=np.array([np.iinfo(np.int64).min, 1 << 31, np.iinfo(np.int64).max]),
+    )
+    buf = layout.pack(values)
+    assert buf.dtype == np.int64 and buf.shape == (layout.size,) == (42,)
+    with jax.enable_x64(True):
+        @jax.jit
+        def through(packed):
+            typed = layout.unpack(packed, arith)
+            return typed, layout.join(typed, arith)
+
+        typed, joined = through(jax.device_put(buf))
+        native = arith is f64.NATIVE
+        assert typed["edges"].dtype == (jnp.float64 if native else jnp.int64)
+        assert typed["lam"].shape == () and typed["on"].dtype == jnp.bool_
+        assert typed["idx"].dtype == jnp.int32 and typed["masks"].dtype == jnp.int64
+    # inside the program, each field holds its value ...
+    for name in ("edges", "grid", "lam"):
+        got = np.asarray(arith.decode(typed[name]))
+        want = np.asarray(values[name], dtype=np.float64)
+        assert (got.view(np.int64) == want.view(np.int64)).all(), name
+    for name in ("flags", "on", "idx", "iters", "masks"):
+        assert (np.asarray(typed[name]) == values[name]).all(), name
+    # ... and the buffer the program writes is the one the host packed
+    assert (np.asarray(joined) == buf).all()
+    back = layout.split(joined)
+    assert back["edges"].dtype == np.float64 and back["idx"].dtype == np.int32
+    assert back["flags"].dtype == bool
+    for name, want in values.items():
+        want = np.asarray(want)
+        got = back[name]
+        if want.dtype == np.float64:
+            got, want = got.view(np.int64), want.view(np.int64)
+        assert got.shape == want.shape and (got == want).all(), name
+
+
+def test_packed_refuses_a_misshapen_field():
+    layout = backend_mod.Packed([("p", (4,), "f64"), ("n", (), "i32")])
+    with pytest.raises(ValueError, match="'p'"):
+        layout.pack({"p": np.zeros(3), "n": 1})
+    with pytest.raises(ValueError, match="'n'"):
+        layout.pack({"p": np.zeros(4), "n": [1]})
