@@ -301,10 +301,12 @@ class Packed:
 def _score_layout(key) -> Tuple[Packed, Packed]:
     """The score program's packed inputs and outputs, from its key."""
     (n_pad, r_pad, w_pad, _, n_res,
-     want_x, x_rows, want_s, want_c, _, want_bias) = key
+     want_x, x_rows, want_s, want_c, _, want_bias, peer) = key
     ins, outs = [], []
     if want_x:
         ins += [("read_masks", (n_pad, r_pad), "i64"), ("per_read", (n_pad, r_pad), "f64")]
+        if peer:
+            ins.append(("per_read_peer", (n_pad, r_pad), "f64"))
         outs += [("X", (n_pad, n_res), "f64") if x_rows else ("X_max", (n_pad,), "f64")]
     if want_bias:
         ins.append(("x_bias", (n_pad, n_res), "f64"))
@@ -412,9 +414,12 @@ class JaxScoringBackend:
         # host->device and device->host transfers the programs made
         # (``uploads``, ``readbacks``: one packed buffer each way for each
         # DADA program call; the per-machine arrays, uploaded once, are not
-        # counted)
+        # counted); task x resource score cells computed on the device
+        # (``cells_device``) and, by the strategies, on the host
+        # (``cells_host``)
         self.counts = {"device": 0, "outside": 0, "rejected": 0,
-                       "uploads": 0, "readbacks": 0}
+                       "uploads": 0, "readbacks": 0,
+                       "cells_device": 0, "cells_host": 0}
         self._matrix_fns: Dict[tuple, object] = {}
         self._search_fns: Dict[tuple, object] = {}
         self._heft_fns: Dict[tuple, object] = {}
@@ -435,7 +440,9 @@ class JaxScoringBackend:
         """Activation-invariant per-machine device arrays (cached)."""
         mems = tuple(r.mem for r in resources)
         accel = tuple(r.is_accelerator for r in resources)
-        key = (mems, accel, transfer_model.latency, transfer_model.bandwidth)
+        tm = transfer_model
+        key = (mems, accel, tm.latency, tm.bandwidth,
+               tm.peer_mems, tm.peer_latency, tm.peer_bandwidth)
         m = self._machine_cache.get(key)
         if m is not None:
             return m
@@ -452,8 +459,15 @@ class JaxScoringBackend:
             ),
             host_col=jnp.asarray([mem == HOST_MEM for mem in uniq], dtype=bool),
             accel_res=jnp.asarray(accel, dtype=bool),
-            latency=transfer_model.latency,
-            bandwidth=transfer_model.bandwidth,
+            latency=tm.latency,
+            bandwidth=tm.bandwidth,
+            # per unique memory, the residency bits one fabric hop away
+            # (None on a machine without a fabric)
+            peer_bits=jnp.asarray(
+                [tm.peer_reach(u) for u in uniq], dtype=jnp.int64
+            ) if tm.peer_mems else None,
+            peer_latency=tm.peer_latency,
+            peer_bandwidth=tm.peer_bandwidth,
         )
         self._machine_cache[key] = m
         return m
@@ -527,6 +541,7 @@ class JaxScoringBackend:
             uniq = mach["uniq"]
 
             want_x = use_cp
+            peer = want_x and mach["peer_bits"] is not None
             aff_src = affinity_csr_source(affinity, arr) if affinity else None
             want_s = aff_src is not None
             if not (want_x or want_s or p_cpu is not None):
@@ -554,6 +569,11 @@ class JaxScoringBackend:
                     read_sizes <= 0.0, 0.0,
                     mach["latency"] + read_sizes / mach["bandwidth"],
                 )
+                if peer:
+                    vals["per_read_peer"] = np.where(
+                        read_sizes <= 0.0, 0.0,
+                        mach["peer_latency"] + read_sizes / mach["peer_bandwidth"],
+                    )
 
             if want_s:
                 w_indptr_full, w_ids_full, w_weights_full, accel_only = aff_src
@@ -571,7 +591,7 @@ class JaxScoringBackend:
                 vals["p_cpu"], vals["p_gpu"] = _pad_rows(p_cpu, n_pad), _pad_rows(p_gpu, n_pad)
 
             key = (n_pad, r_pad, w_pad, len(uniq), len(resources),
-                   want_x, bool(x_rows), want_s, want_c, accel_only, want_bias)
+                   want_x, bool(x_rows), want_s, want_c, accel_only, want_bias, peer)
             fn = self._matrix_fns.get(key)
             if fn is None:
                 fn = self._build_matrix_fn(key)
@@ -582,12 +602,15 @@ class JaxScoringBackend:
                 (None, mach["mem_shift"]), (None, mach["host_col"]),
                 (None, mach["col_of"]), (None, mach["accel_res"]),
             ]
+            if peer:
+                args.append((None, mach["peer_bits"]))
 
         def dec(x):
             return {k: v[:n] for k, v in outs.split(x).items()}
 
         raw, (host,) = call_program("score", fn, args, [(1, dec)], self.counts)
         self.counts["device"] += 1
+        self.counts["cells_device"] += n * len(resources)
         out = dict(C=None, C_np=host.get("C"), C_dev=None, X_np=host.get("X"),
                    X_rowmax=None, S_np=host.get("S"))
         if want_c:
@@ -599,18 +622,19 @@ class JaxScoringBackend:
 
     def _build_matrix_fn(self, key):
         (n_pad, r_pad, w_pad, n_u, n_res,
-         want_x, x_rows, want_s, want_c, accel_only, want_bias) = key
+         want_x, x_rows, want_s, want_c, accel_only, want_bias, peer) = key
         jax, jnp = self.jax, self.jnp
         F = self.f64
         ins, outs = _score_layout(key)
 
-        def dada_score_matrices(packed, mem_shift, host_col, col_of, accel_res):
+        def dada_score_matrices(packed, mem_shift, host_col, col_of, accel_res,
+                                peer_bits=None):
             """``(C, bits)``: the cost matrix, kept on the device for the λ
             search, and one buffer of the outputs the host reads."""
-            (read_masks, per_read, x_bias, write_masks, write_weights,
-             p_cpu, p_gpu) = map(ins.unpack(packed, F).get, (
-                 "read_masks", "per_read", "x_bias", "write_masks",
-                 "write_weights", "p_cpu", "p_gpu"))
+            (read_masks, per_read, per_read_peer, x_bias, write_masks,
+             write_weights, p_cpu, p_gpu) = map(ins.unpack(packed, F).get, (
+                 "read_masks", "per_read", "per_read_peer", "x_bias",
+                 "write_masks", "write_weights", "p_cpu", "p_gpu"))
             X_res = None
             X_max = None
             if want_x:
@@ -621,7 +645,8 @@ class JaxScoringBackend:
                 from repro.kernels.sched_score import transfer_matrix_from_full
 
                 X_u = transfer_matrix_from_full(
-                    read_masks, per_read, mem_shift, host_col, add=F.add
+                    read_masks, per_read, mem_shift, host_col, add=F.add,
+                    peer_bits=peer_bits, per_read_peer=per_read_peer,
                 )
                 X_res = X_u[:, col_of]
                 if want_bias:

@@ -174,6 +174,8 @@ class DADA(ScoringBackendMixin, Strategy):
                 affinity=self.affinity_name if self.alpha > 0.0 else None,
                 x_bias=P,
             )
+        if be is not None and fused is None:
+            be.counts["cells_host"] += n * n_res
 
         with obs.span("dada.order"):
             use_backend_search = fused is not None

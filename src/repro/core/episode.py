@@ -113,6 +113,15 @@ def _pad2(rows: List[List[Tuple[int, float]]], n_pad: int, width: int, fill_id: 
     return ids, val
 
 
+def _refuse_fabric(machine: MachineModel) -> None:
+    if machine.fabric is not None:
+        raise ValueError(
+            "the surrogate episode engine stages every device->device copy "
+            "through the host; a machine with a peer fabric runs on the "
+            "exact engine (repro.core.run_simulation)"
+        )
+
+
 def build_plan(
     graph: TaskGraph, machine: MachineModel, n_u: Optional[int] = None
 ) -> EpisodePlan:
@@ -123,6 +132,7 @@ def build_plan(
     batch needs (1 + the largest device-memory id across the batch);
     defaults to this machine's own layout.
     """
+    _refuse_fabric(machine)
     arr = graph.arrays()
     cpu_cls = next((r.cls for r in machine.resources if not r.is_accelerator), None)
     gpu_cls = next((r.cls for r in machine.resources if r.is_accelerator), None)
@@ -291,6 +301,7 @@ def machine_axes(
     and host-side pulls don't contend with each other. Group ids stay
     below the resource count, so the episode's link clock is (B, R).
     """
+    _refuse_fabric(machine)
     is_gpu = np.zeros(n_res, dtype=bool)
     valid = np.zeros(n_res, dtype=bool)
     mem_col = np.zeros(n_res, dtype=np.int32)

@@ -120,6 +120,8 @@ class HEFT(ScoringBackendMixin, Strategy):
                     load_ts[rid] = float(efts[k])
                     sim.push(ready[i], rid)
                 return
+        if be is not None:
+            be.counts["cells_host"] += n * len(resources)
 
         X = fold_pressure(
             sim.transfer_model.task_input_transfer_rows(

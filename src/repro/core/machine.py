@@ -7,8 +7,13 @@ Faithful to the paper's platform abstraction:
   * each *running* GPU monopolizes one CPU core to manage its worker
     (paper §4.1), so ``k`` GPUs leave ``total_cores - k`` compute CPUs.
 
-The same abstraction covers the TPU adaptation (device groups connected by
-ICI/DCN links); see configs/paper_machine.py and dist/sched_bridge.py.
+Beyond the paper's box, a machine may declare a peer :class:`Fabric`
+(NVLink/NVSwitch between GPUs, ICI between TPU chips): one direct hop
+between any two of its device memories, bypassing the host. Without one,
+a device→device copy is staged through host memory (two PCIe hops). How a
+copy is routed and priced is decided in one place,
+``repro.core.perfmodel.TransferModel.route``. Multi-hop fabrics (a torus
+whose far chips are several links apart) are not modelled.
 """
 from __future__ import annotations
 
@@ -75,11 +80,22 @@ class LinkModel:
 
 
 @dataclass
+class Fabric:
+    """A peer fabric between device memories: one direct hop between any
+    two of ``mems``, timed by ``link`` (bandwidth each way per device).
+    Copies into one device serialize on that device's fabric port."""
+
+    link: LinkModel
+    mems: Tuple[int, ...]
+
+
+@dataclass
 class MachineModel:
     resources: List[Resource]
     link: LinkModel
     # link group id -> list of resource ids attached (for contention)
     link_groups: Dict[int, List[int]] = field(default_factory=dict)
+    fabric: Optional[Fabric] = None
 
     def __post_init__(self) -> None:
         if not self.link_groups:
@@ -88,6 +104,12 @@ class MachineModel:
                 if r.link is not None:
                     groups.setdefault(r.link, []).append(r.rid)
             self.link_groups = groups
+        # fabric port (contention group) of each fabric memory, numbered
+        # after the host links so the two never share a queue
+        self.fabric_ports: Dict[int, int] = {}
+        if self.fabric is not None:
+            base = 1 + max(self.link_groups, default=-1)
+            self.fabric_ports = {m: base + i for i, m in enumerate(self.fabric.mems)}
         # cached partitions (resources never change after construction)
         self._cpus = [r for r in self.resources if not r.is_accelerator]
         self._gpus = [r for r in self.resources if r.is_accelerator]
@@ -127,12 +149,14 @@ def make_machine(
     pcie_latency: float = 1e-5,
     gpus_per_switch: int = 2,
     gpu_pins_cpu: bool = True,
+    fabric: Optional[LinkModel] = None,
 ) -> MachineModel:
     """Build the paper-style machine.
 
     ``n_cpus`` is the number of *cores in the box*; if ``gpu_pins_cpu`` each
     GPU removes one compute core (paper: "Each running GPU monopolizes a CPU
-    to manage its worker").
+    to manage its worker"). ``fabric``, where given, joins every GPU memory
+    in one peer fabric of that link.
     """
     compute_cpus = n_cpus - n_gpus if gpu_pins_cpu else n_cpus
     if compute_cpus < 0:
@@ -151,4 +175,5 @@ def make_machine(
     return MachineModel(
         resources=resources,
         link=LinkModel(bandwidth=pcie_bandwidth, latency=pcie_latency),
+        fabric=None if fabric is None else Fabric(fabric, tuple(range(n_gpus))),
     )

@@ -147,26 +147,94 @@ class ClassPredictor:
         return out
 
 
+# How a copy reaches its destination memory (``route``): it does not move
+# (already there, or nowhere yet), one host link hop (host→device or
+# device→host), one fabric hop between peers, or device→host→device.
+ROUTE_NONE, ROUTE_HOST, ROUTE_PEER, ROUTE_STAGED = range(4)
+ROUTE_HOPS = (0, 1, 1, 2)
+
+
+def route(mask: int, dst_mem: int, peer_bits: int = 0) -> int:
+    """The route of a copy to ``dst_mem`` of data whose valid copies are
+    ``mask``; ``peer_bits`` are the memories one fabric hop from
+    ``dst_mem`` (0 where it has no fabric). A peer on the fabric is
+    preferred to the host, and staging through the host is the last
+    resort: the paper-era PCIe path."""
+    if mask == 0 or mask & (1 << (dst_mem + 1)):
+        return ROUTE_NONE
+    if mask & peer_bits:
+        return ROUTE_PEER
+    if dst_mem == HOST_MEM or mask & 1:
+        return ROUTE_HOST
+    return ROUTE_STAGED
+
+
 @dataclass
 class TransferModel:
-    """Asymptotic-bandwidth estimator for host<->device transfers.
+    """Asymptotic-bandwidth estimator for host<->device transfers, and the
+    one owner of how a copy is routed (:meth:`route`).
 
     ``predict`` ignores contention (a *prediction*, as in the paper — the
     simulator's ground truth does model switch contention, which is exactly
-    the modeling error the paper discusses).
+    the modeling error the paper discusses). ``peer_mems``, where the
+    machine has a fabric, are the memories one fabric hop apart, with the
+    fabric's own ``peer_bandwidth`` and ``peer_latency``. A copy costs one
+    hop's time on its route, and a staged one two host hops (``2 × t``).
     """
 
     bandwidth: float
     latency: float = 1e-5
+    peer_bandwidth: float = 0.0
+    peer_latency: float = 0.0
+    peer_mems: Tuple[int, ...] = ()
 
     def __post_init__(self) -> None:
         # memoized unique-memory decompositions, keyed by the mems tuple
         self._mem_plans: Dict[tuple, tuple] = {}
+        # per destination memory: the residency bits one fabric hop away
+        bits = 0
+        for m in self.peer_mems:
+            bits |= _mem_bit(m)
+        self._peer_reach: Dict[int, int] = {m: bits for m in self.peer_mems}
+
+    @classmethod
+    def of(cls, machine: MachineModel) -> "TransferModel":
+        """The prediction of ``machine``'s own links."""
+        fab = machine.fabric
+        if fab is None:
+            return cls(bandwidth=machine.link.bandwidth, latency=machine.link.latency)
+        return cls(bandwidth=machine.link.bandwidth, latency=machine.link.latency,
+                   peer_bandwidth=fab.link.bandwidth, peer_latency=fab.link.latency,
+                   peer_mems=tuple(fab.mems))
+
+    def peer_reach(self, dst_mem: int) -> int:
+        """Residency bits of the memories one fabric hop from ``dst_mem``."""
+        return self._peer_reach.get(dst_mem, 0)
+
+    def route(self, mask: int, dst_mem: int) -> int:
+        """The route (``ROUTE_*``) of a copy to ``dst_mem`` on this machine."""
+        return route(mask, dst_mem, self._peer_reach.get(dst_mem, 0))
 
     def time(self, nbytes: int) -> float:
         if nbytes <= 0:
             return 0.0
         return self.latency + nbytes / self.bandwidth
+
+    def peer_time(self, nbytes: int) -> float:
+        if nbytes <= 0:
+            return 0.0
+        return self.peer_latency + nbytes / self.peer_bandwidth
+
+    def read_time(self, mask: int, dst_mem: int, nbytes: int) -> float:
+        """Predicted time to bring one datum of ``nbytes`` to ``dst_mem``."""
+        r = self.route(mask, dst_mem)
+        if r == ROUTE_HOST:
+            return self.time(nbytes)
+        if r == ROUTE_STAGED:
+            return 2 * self.time(nbytes)
+        if r == ROUTE_PEER:
+            return self.peer_time(nbytes)
+        return 0.0
 
     def mem_plan(self, mems: tuple) -> tuple:
         """Decompose a resource→memory list into (unique mems, column-of,
@@ -197,8 +265,7 @@ class TransferModel:
         total = 0.0
         for d in task.reads:
             if not residency.is_resident(d.name, resource.mem):
-                hops = residency.transfer_hops(d.name, resource.mem)
-                total += hops * self.time(d.size_bytes)
+                total += self.read_time(residency.mask(d.name), resource.mem, d.size_bytes)
         return total
 
     # ------------------------------------------------------------------
@@ -215,8 +282,9 @@ class TransferModel:
         activations (the common case — ``activate`` usually wakes 1-3
         tasks) take a scalar path over the per-task read lists and the
         residency bitmasks, wide ones take the batched numpy path. Both
-        compute ``hops * (latency + size/bandwidth)`` summed in access
-        order, so every entry is bit-equal to the scalar reference.
+        price each read by its :meth:`route` (``t``, ``2 * t`` or the
+        fabric's own time) and sum in access order, so every entry is
+        bit-equal to the scalar reference.
         """
         # resources sharing a memory space (all CPUs see host memory) share
         # a column: compute per unique memory, then expand
@@ -230,33 +298,34 @@ class TransferModel:
             ).tolist()
         else:
             masks = residency._mask
-            # per-task (read name, per-hop time) pairs are graph-static:
-            # precompute once per (model, graph) and only refresh the
-            # residency masks per activation
-            key = ("read_times", self.latency, self.bandwidth)
+            # per-task (read name, per-hop time, fabric-hop time) triples
+            # are graph-static: precompute once per (model, graph) and only
+            # refresh the residency masks per activation
+            key = ("read_times", self.latency, self.bandwidth,
+                   self.peer_latency, self.peer_bandwidth)
             prep = arr.cache.get(key)
             if prep is None:
-                latency = self.latency
-                bandwidth = self.bandwidth
                 prep = [
-                    [
-                        (name, 0.0 if size <= 0 else latency + size / bandwidth)
-                        for _, name, size in reads
-                    ]
+                    [(name, self.time(size), self.peer_time(size)
+                      if self.peer_mems else 0.0) for _, name, size in reads]
                     for reads in arr.task_reads
                 ]
                 arr.cache[key] = prep
+            reach = self._peer_reach
             rows = []
             for tid in tids:
-                reads = [(masks.get(name, 0), t) for name, t in prep[tid]]
+                reads = [(masks.get(name, 0), t, tp) for name, t, tp in prep[tid]]
                 row = []
                 for mem in uniq:
                     bit = 1 << (mem + 1)
+                    peer = reach.get(mem, 0)
                     total = 0.0
-                    for m, t in reads:
+                    for m, t, tp in reads:
                         if m & bit or m == 0:
                             continue
-                        if mem == HOST_MEM or m & 1:
+                        if m & peer:
+                            total += tp
+                        elif mem == HOST_MEM or m & 1:
                             total += t
                         else:
                             total += 2 * t
@@ -289,6 +358,10 @@ class TransferModel:
         masks = residency.mask_of_ids(ids)
         # per-read transfer time (latency + size/bw; 0 for empty reads)
         per_read = np.where(sizes <= 0, 0.0, self.latency + sizes / self.bandwidth)
+        if self.peer_mems:
+            per_peer = np.where(
+                sizes <= 0, 0.0, self.peer_latency + sizes / self.peer_bandwidth
+            )
         on_host = (masks & 1) != 0
         nowhere = masks == 0
         out = np.empty((n, m), dtype=np.float64)
@@ -308,6 +381,11 @@ class TransferModel:
                     resident | nowhere, 0.0, np.where(on_host, 1.0, 2.0)
                 )
             contrib = hops * per_read
+            peer = self._peer_reach.get(mem, 0)
+            if peer:
+                contrib = np.where(
+                    ~(resident | nowhere) & ((masks & peer) != 0), per_peer, contrib
+                )
             col = np.add.reduceat(np.append(contrib, 0.0), indptr[:-1])[:n]
             if fix_empty:
                 col = np.where(empty_seg, 0.0, col)
@@ -411,14 +489,11 @@ class Residency:
         return self._mask.get(name, 0) != 0
 
     def transfer_hops(self, name: str, dst_mem: int) -> int:
-        """1 hop if a copy is on host or dst is host; 2 hops for GPU->GPU
+        """Link hops of the copy's :func:`route` on a machine without a
+        fabric: 1 if a copy is on host or dst is host; 2 for GPU->GPU
         (device -> host -> device, the paper-era PCIe path)."""
-        m = self._mask.get(name, 0)
-        if m == 0 or m & _mem_bit(dst_mem):
-            return 0
-        if dst_mem == HOST_MEM or m & 1:
-            return 1
-        return 2
+        _mem_bit(dst_mem)
+        return ROUTE_HOPS[route(self._mask.get(name, 0), dst_mem)]
 
     def add_copy(self, name: str, mem: int) -> None:
         if not -1 <= mem <= _MAX_MEM:

@@ -104,6 +104,7 @@ class Simulator(Engine):
             strategy=self.strategy.name,
             total_flops=self._primary.graph.total_flops(),
             n_events=m.n_events,
+            routes=m.routes(),
             faults=(
                 m.fault_summary()
                 if (self._faults_on or self._flake_on)

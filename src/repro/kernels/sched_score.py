@@ -9,7 +9,9 @@ of the reads that are *not* resident at u —
 where ``mask`` holds compact residency codes (bit 0 = a host copy exists,
 bit u+1 = a valid copy at unique memory u) and ``hops`` is the paper-era
 PCIe path length: 0 if resident (or the data exists nowhere yet), 1 for
-host→device / anything→host, 2 for device→host→device.
+host→device / anything→host, 2 for device→host→device. The surrogate
+episodes (the Pallas kernel) model only that path; the jax scheduling
+backend's fold also prices a copy over a machine's peer fabric.
 
 Layout mirrors ``tile_gemm``: the grid tiles the task axis, each program
 reduces its (bt × r_pad) read block into a (bt × n_u) output block. The
@@ -28,13 +30,15 @@ from __future__ import annotations
 
 import functools
 import operator
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _hop_fold(masks, per_read, resident_of, host_col, n_u, add=operator.add):
+def _hop_fold(masks, per_read, resident_of, host_col, n_u, add=operator.add,
+              peer=None):
     """Shared in-order read fold: the single home of the hop formula.
 
     ``resident_of(r)`` returns the (n_pad, n_u) residency booleans of read
@@ -43,7 +47,10 @@ def _hop_fold(masks, per_read, resident_of, host_col, n_u, add=operator.add):
     so the bit-for-bit-critical arithmetic lives exactly once. A read
     costs 0, 1 or 2 one-hop times, and ``p + p`` is ``2 p`` exactly, so
     the fold needs nothing but ``add`` (which may be an integer-exact f64
-    add over bit patterns, see ``repro.core.f64``).
+    add over bit patterns, see ``repro.core.f64``). ``peer``, on a machine
+    with a fabric, is ``(peer_of, per_read_peer)``: where ``peer_of(r)``
+    holds, a fabric peer of column u has read r and the copy costs one
+    fabric hop, ``per_read_peer`` (``TransferModel.route`` prefers it).
     """
     on_host = (masks & 1) != 0
     nowhere = masks == 0
@@ -53,7 +60,11 @@ def _hop_fold(masks, per_read, resident_of, host_col, n_u, add=operator.add):
         skip = resident_of(r) | nowhere[:, r][:, None]
         one_hop = host_col[None, :] | on_host[:, r][:, None]
         p = per_read[:, r][:, None]
-        return add(acc, jnp.where(skip, 0, jnp.where(one_hop, p, add(p, p))))
+        cost = jnp.where(one_hop, p, add(p, p))
+        if peer is not None:
+            peer_of, q = peer
+            cost = jnp.where(peer_of(r), q[:, r][:, None], cost)
+        return add(acc, jnp.where(skip, 0, cost))
 
     return jax.lax.fori_loop(
         0, masks.shape[1], body, jnp.zeros((n_pad, n_u), dtype=per_read.dtype)
@@ -80,13 +91,21 @@ def transfer_matrix_from_full(
     mem_shift: jax.Array,  # (n_u,) int64, mem+1 shift per unique memory
     host_col: jax.Array,  # (n_u,) bool, True where unique mem u is the host
     add=operator.add,
+    peer_bits: Optional[jax.Array] = None,  # (n_u,) int64 fabric reach of u
+    per_read_peer: Optional[jax.Array] = None,  # (n_pad, r_pad) fabric-hop times
 ) -> jax.Array:
     """Same fold straight off the full int64 residency masks — the
-    transfer fold of the jax scheduling backend (no compact remap)."""
+    transfer fold of the jax scheduling backend (no compact remap). On a
+    machine with a fabric, ``peer_bits[u]`` are the residency bits one
+    fabric hop from unique memory u (0 for the host)."""
+    peer = None
+    if peer_bits is not None:
+        peer = (lambda r: (masks[:, r][:, None] & peer_bits[None, :]) != 0,
+                per_read_peer)
     return _hop_fold(
         masks, per_read,
         lambda r: ((masks[:, r][:, None] >> mem_shift[None, :]) & 1) != 0,
-        host_col, mem_shift.shape[0], add,
+        host_col, mem_shift.shape[0], add, peer,
     )
 
 

@@ -176,9 +176,7 @@ class Engine:
         self.rng = np.random.default_rng(seed)
         self.noise = noise
         self.model = HistoryPerfModel()
-        self.transfer_model = transfer_model or TransferModel(
-            bandwidth=machine.link.bandwidth, latency=machine.link.latency
-        )
+        self.transfer_model = transfer_model or TransferModel.of(machine)
 
         self.now = 0.0
         self.events = EventQueue()
@@ -1095,6 +1093,7 @@ class Engine:
             strategy=self.strategy.name,
             total_flops=ctx.graph.total_flops(),
             n_events=self.metrics.n_events,
+            routes=self.metrics.routes(),
             faults=(
                 self.metrics.fault_summary()
                 if (self._faults_on or self._flake_on)

@@ -9,7 +9,7 @@ multi-tenant streams.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from repro.core.machine import MachineModel
@@ -41,6 +41,8 @@ class SimResult:
     submit_at: float = 0.0
     admit_at: float = 0.0
     admitted: bool = True
+    # demand-copy hops and bytes by route (Metrics.routes)
+    routes: Dict[str, int] = field(default_factory=dict)
 
     @property
     def gflops(self) -> float:
@@ -64,6 +66,8 @@ class Metrics:
         "n_notices", "n_proactive", "proactive_bytes",
         "n_retries", "n_timeouts", "retry_delay_s",
         "n_arrivals", "n_admitted", "n_rejected", "n_deferred",
+        "hops_host", "hops_peer", "hops_staged",
+        "bytes_host", "bytes_peer", "bytes_staged",
     )
 
     def __init__(self, machine: MachineModel) -> None:
@@ -97,6 +101,15 @@ class Metrics:
         self.n_admitted = 0  # ... admitted past admission control
         self.n_rejected = 0  # ... turned away (working set vs capacity)
         self.n_deferred = 0  # defer re-posts (one arrival may defer many times)
+        # demand-copy hops by route (repro.runtime.transfers): host link,
+        # peer fabric, and the two host-link legs of a staged copy
+        self.hops_host = self.hops_peer = self.hops_staged = 0
+        self.bytes_host = self.bytes_peer = self.bytes_staged = 0
+
+    def routes(self) -> Dict[str, int]:
+        return {k: getattr(self, k) for k in (
+            "hops_host", "hops_peer", "hops_staged",
+            "bytes_host", "bytes_peer", "bytes_staged")}
 
     def fault_summary(self) -> Dict[str, float]:
         """The fault counters as a plain dict (``SimResult.faults``)."""

@@ -8,8 +8,12 @@ Lifted from the monolithic simulator's ``request_transfer`` / ``_one_hop``:
   * the in-flight index is kept per graph context and per data name
     (``ctx.inflight[name] -> {dst_mem: done_t}``), so duplicate requests
     dedup in O(1) and a write invalidates stale entries in O(copies);
-  * GPU→GPU moves route through the host (two hops, the paper-era PCIe
-    path), reusing an already-in-flight host hop when one exists.
+  * each copy takes the route ``TransferModel.route`` picks: one host-link
+    hop (host→device, device→host), one hop over the machine's peer
+    fabric (its destination's fabric port is the contention group), or,
+    where no fabric reaches, device→host→device (two hops, the paper-era
+    PCIe path, reusing an already-in-flight host hop when one exists).
+    ``Metrics`` counts the hops and bytes of each route.
 
 Capacity-bounded memories (``repro.runtime.memory``) hook in at request
 time: space at the destination is reserved *before* the hop is scheduled,
@@ -36,6 +40,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from repro.core.machine import HOST_MEM, LinkModel, MachineModel
+from repro.core.perfmodel import ROUTE_HOST, ROUTE_PEER
 
 from .events import EventQueue
 from .metrics import Metrics
@@ -51,6 +56,7 @@ class TransferEngine:
     __slots__ = (
         "machine", "model", "events", "metrics", "memory",
         "mem_link", "link_free", "_plain_link", "_link_lat", "_link_bw",
+        "fabric_ports", "_fabric",
         "cancel_stale", "faults", "audit",
         "flake_rate", "retry_max", "backoff_s", "_flake_rng", "_flake_on",
     )
@@ -86,14 +92,20 @@ class TransferEngine:
         self._plain_link = type(machine.link) is LinkModel
         self._link_lat = machine.link.latency
         self._link_bw = machine.link.bandwidth
+        self.fabric_ports = machine.fabric_ports
+        self._fabric = None if machine.fabric is None else machine.fabric.link
 
     # ------------------------------------------------------------------
     def one_hop(
-        self, nbytes: int, group: Optional[int], t: float, kind: str = "copy"
+        self, nbytes: int, group: Optional[int], t: float, kind: str = "copy",
+        peer: bool = False,
     ) -> float:
-        """Serialize the transfer on its link group (FIFO = shared bandwidth)."""
+        """Serialize the transfer on its link group (FIFO = shared bandwidth);
+        ``peer`` times it on the fabric's link instead of the host's."""
         start = max(t, self.link_free.get(group, 0.0)) if group is not None else t
-        if self._plain_link:
+        if peer:
+            dur = self._fabric.time(nbytes)
+        elif self._plain_link:
             dur = 0.0 if nbytes <= 0 else self._link_lat + nbytes / self._link_bw
         else:
             dur = self.machine.link.time(nbytes)
@@ -134,6 +146,7 @@ class TransferEngine:
         group: Optional[int],
         t: float,
         dst_mem: int,
+        peer: bool = False,
     ) -> float:
         """One demand hop under the flake model: retry with capped
         exponential backoff, re-source on timeout.
@@ -145,7 +158,7 @@ class TransferEngine:
         only the final landing is posted as an event — which keeps the
         event-loop structure (and the zero-flake path) untouched.
         """
-        done = self.one_hop(nbytes, group, t)
+        done = self.one_hop(nbytes, group, t, peer=peer)
         attempt = 0
         rng = self._flake_rng
         rate = self.flake_rate
@@ -160,7 +173,7 @@ class TransferEngine:
                     self.audit.log_timeout(
                         ctx.gid, name, dst_mem, done, attempt + 1, nbytes
                     )
-                return self.one_hop(nbytes, group, done, kind="resource")
+                return self.one_hop(nbytes, group, done, kind="resource", peer=peer)
             attempt += 1
             delay = min(
                 self.backoff_s * (2.0 ** (attempt - 1)),
@@ -172,7 +185,7 @@ class TransferEngine:
                 self.audit.log_retry(
                     ctx.gid, name, dst_mem, done, attempt, delay, nbytes
                 )
-            done = self.one_hop(nbytes, group, done + delay, kind="retry")
+            done = self.one_hop(nbytes, group, done + delay, kind="retry", peer=peer)
         return done
 
     # ------------------------------------------------------------------
@@ -223,8 +236,24 @@ class TransferEngine:
         mem_link = self.mem_link
         post = self.events.post
         flake = self._flake_on
-        if (mask & 1) and dst_mem != HOST_MEM:
+        metrics = self.metrics
+        audit = self.audit
+        route = self.model.route(mask, dst_mem)
+        if route == ROUTE_PEER:
+            # a fabric peer holds a copy: one direct hop into dst's port
+            peers = mask & self.model.peer_reach(dst_mem)
+            src = (peers & -peers).bit_length() - 2
+            port = self.fabric_ports[dst_mem]
+            done = (
+                self._flaky_hop(ctx, name, size, port, now, dst_mem, peer=True)
+                if flake
+                else self.one_hop(size, port, now, peer=True)
+            )
+            metrics.hops_peer += 1
+            metrics.bytes_peer += size
+        elif route == ROUTE_HOST and dst_mem != HOST_MEM:
             # a host copy exists: single host->device hop
+            src = HOST_MEM
             done = (
                 self._flaky_hop(
                     ctx, name, size, mem_link.get(dst_mem), now, dst_mem
@@ -232,7 +261,9 @@ class TransferEngine:
                 if flake
                 else self.one_hop(size, mem_link.get(dst_mem), now)
             )
-        elif dst_mem == HOST_MEM:
+            metrics.hops_host += 1
+            metrics.bytes_host += size
+        elif route == ROUTE_HOST:
             src = (mask & -mask).bit_length() - 2  # lowest-numbered location
             done = (
                 self._flaky_hop(
@@ -241,6 +272,8 @@ class TransferEngine:
                 if flake
                 else self.one_hop(size, mem_link.get(src), now)
             )
+            metrics.hops_host += 1
+            metrics.bytes_host += size
         else:
             # GPU -> host -> GPU (two hops, paper-era PCIe path)
             src = (mask & -mask).bit_length() - 2
@@ -258,8 +291,11 @@ class TransferEngine:
                     flights = inflight[name] = {}
                 flights[HOST_MEM] = mid
                 post(mid, "xfer", (ctx, name, HOST_MEM, ver, 0))
-                if self.audit is not None:
-                    self.audit.note_request(ctx.gid, name, HOST_MEM, mid, now)
+                metrics.hops_staged += 1
+                metrics.bytes_staged += size
+                if audit is not None:
+                    audit.note_request(ctx.gid, name, HOST_MEM, mid, now, src)
+            src = HOST_MEM
             done = (
                 self._flaky_hop(
                     ctx, name, size, mem_link.get(dst_mem), mid, dst_mem
@@ -267,12 +303,14 @@ class TransferEngine:
                 if flake
                 else self.one_hop(size, mem_link.get(dst_mem), mid)
             )
+            metrics.hops_staged += 1
+            metrics.bytes_staged += size
         if flights is None:
             flights = inflight[name] = {}
         flights[dst_mem] = done
         post(done, "xfer", (ctx, name, dst_mem, ver, epoch))
-        if self.audit is not None:
-            self.audit.note_request(ctx.gid, name, dst_mem, done, now)
+        if audit is not None:
+            audit.note_request(ctx.gid, name, dst_mem, done, now, src)
         return done
 
     # ------------------------------------------------------------------

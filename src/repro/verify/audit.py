@@ -68,7 +68,8 @@ class LandRecord:
     ``reason``: "ok" (copy became resident), "dead" (target memory died
     or its epoch advanced mid-flight), "stale" (cancel-stale mode
     dropped an outdated version).  ``t_req`` is the time the transfer
-    was requested, matched from the request site.
+    was requested and ``src`` the memory it was copied from, both matched
+    from the request site (None where the engine logged no request).
     """
 
     seq: int
@@ -79,6 +80,7 @@ class LandRecord:
     landed: bool
     reason: str
     t_req: Optional[float] = None
+    src: Optional[int] = None
 
 
 @dataclass
@@ -239,8 +241,9 @@ class AuditLog:
         self.rejects: List[RejectRecord] = []
         self.result: Dict[str, Any] = {}
         self._seq = 0
-        # (gid, name, dst_mem, done_t) -> request time, popped on landing
-        self._pending_req: Dict[Tuple[int, str, int, float], float] = {}
+        # (gid, name, dst_mem, done_t) -> (request time, source memory),
+        # popped on landing
+        self._pending_req: Dict[Tuple[int, str, int, float], Tuple[float, int]] = {}
 
     # ------------------------------------------------------------------
     # producer API (called from the engines)
@@ -250,6 +253,9 @@ class AuditLog:
         return self._seq
 
     def log_machine(self, machine: Any, **info: Any) -> None:
+        fabric = getattr(machine, "fabric", None)
+        if fabric is not None:
+            info["fabric"] = [int(m) for m in fabric.mems]
         resources = [
             {
                 "rid": int(r.rid),
@@ -305,14 +311,17 @@ class AuditLog:
         )
 
     def note_request(
-        self, gid: int, name: str, dst_mem: int, done: float, t_req: float
+        self, gid: int, name: str, dst_mem: int, done: float, t_req: float,
+        src: int,
     ) -> None:
-        self._pending_req[(int(gid), name, int(dst_mem), float(done))] = float(t_req)
+        self._pending_req[(int(gid), name, int(dst_mem), float(done))] = (
+            float(t_req), int(src))
 
     def log_landing(
         self, gid: int, name: str, mem: int, t: float, landed: bool, reason: str
     ) -> None:
-        t_req = self._pending_req.pop((int(gid), name, int(mem), float(t)), None)
+        t_req, src = self._pending_req.pop(
+            (int(gid), name, int(mem), float(t)), (None, None))
         self.landings.append(
             LandRecord(
                 self._next_seq(),
@@ -323,6 +332,7 @@ class AuditLog:
                 bool(landed),
                 reason,
                 t_req,
+                src,
             )
         )
 

@@ -41,6 +41,9 @@ Invariants (exact engine):
   or after the retry/timeout time — no transfer retries forever.
 - ``MAKESPAN``       each graph's recorded finish time equals the max
   recorded execution end for that graph.
+- ``ROUTE``          a copy from one device memory straight into another
+  needs a peer fabric that the machine declares between the two; without
+  one, device copies are staged through host memory.
 - ``ARRIVAL``        no execution of a graph's task starts before the
   graph's submit time (and, in serving mode, before its admit time); a
   graph admission control rejected must show no executions at all, and
@@ -296,6 +299,22 @@ def _verify_exact(log: AuditLog) -> List[Finding]:
 
     exec_of = _exec_index(log, out)
     _check_bytes(log, out)
+
+    # routes: direct device->device copies only over a declared fabric ----
+    fabric = set(machine.get("fabric", ()))
+    for land in log.landings:
+        if land.src is None or land.src == host or land.mem == host:
+            continue
+        if land.src not in fabric or land.mem not in fabric:
+            out.append(
+                Finding(
+                    "ROUTE",
+                    "error",
+                    f"g{land.gid}/{land.name} copied from memory {land.src} "
+                    f"straight into memory {land.mem} at t={land.t:.6g}, but "
+                    "the machine declares no fabric between them",
+                )
+            )
 
     # arrival / admission ------------------------------------------------
     arrive_at = {r.gid: r.t for r in log.arrivals}

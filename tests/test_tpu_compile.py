@@ -110,11 +110,43 @@ def backend_calls():
     return calls
 
 
-@pytest.mark.parametrize("kind", ["matrix", "search", "heft"])
-def test_backend_functions_compile(one_chip, backend_calls, kind):
+@pytest.fixture(scope="module")
+def peer_calls():
+    """One 31-wide activation of DADA+cp on the DGX A100 deployment (128
+    resources, NVSwitch peers): the score program with the fabric's fold
+    and the λ search at 128 columns, recorded as for ``backend_calls``."""
+    from repro.configs.dgx_a100 import dgx_a100
+
+    graph = cholesky_graph(NT, 1024, itemsize=8, with_fns=False)
+    wave = _wide_wave(graph)[:NT - 1]
+    be = JaxScoringBackend(SchedConfig(backend="jax", lambda_depth=5, jax_min=8))
+    be.f64 = f64.for_platform("tpu")
+    calls = {"matrix": [], "search": []}
+    be._build_matrix_fn = _recording(be._build_matrix_fn, calls["matrix"])
+    be._build_search_fn = _recording(be._build_search_fn, calls["search"])
+    strat = DADA(alpha=0.5, use_cp=True, backend="jax")
+    strat._backend, strat._backend_resolved = be, True
+    sim = Simulator(graph, dgx_a100(), strat, seed=0)
+    for k, name in enumerate(sim.arrays.data_names):
+        if k % 3 == 0:
+            sim.residency.write(name, k % 8)
+    sim.push = lambda task, rid: None
+    strat.place(sim, wave, None)
+    assert all(calls.values())
+    assert calls["matrix"][0][0][-1], "the score program took no fabric"
+    return calls
+
+
+@pytest.mark.parametrize("kind", ["matrix", "search", "heft", "peer_matrix", "peer_search"])
+def test_backend_functions_compile(one_chip, request, kind):
     """The score-matrix, λ-search (depth 5, the TPU default) and HEFT EFT
-    programs in the chip's integer-exact f64, at a phase-(a) width."""
-    for key, fn, args in backend_calls[kind]:
+    programs in the chip's integer-exact f64, at a phase-(a) width; the
+    score and search programs of the DGX A100 deployment at 128 columns."""
+    if kind.startswith("peer_"):
+        calls = request.getfixturevalue("peer_calls")[kind[5:]]
+    else:
+        calls = request.getfixturevalue("backend_calls")[kind]
+    for key, fn, args in calls:
         with jax.enable_x64(True):
             compiled = _compile(fn, args, one_chip)
         assert compiled.as_text()
