@@ -22,13 +22,16 @@ accelerates the two placement hot spots on wide activations:
     degenerates to plain bisection — speculative probes cost real time on
     a single core); on gpu/tpu it is 5, where the 31-probe vmap rides the
     accelerator for free;
-  * **one transfer each way** — each DADA program takes its per-call
-    inputs as one packed int64 buffer and the score program returns what
-    the host reads as one (:class:`Packed`): a TPU pays a fixed latency
-    per transfer, whatever its size.
+  * **one program per DADA activation** — ``dada_score_and_search``
+    scores the matrices, orders DADA's affinity preferences (best resource
+    per row, the (−score, tid) sort, the per-resource chains), sums the λ
+    upper bound and runs the λ search in one dispatch, taking one packed
+    int64 buffer and returning one (:class:`Packed`): a TPU pays a fixed
+    latency per transfer and per dispatch, whatever its size.
 
 Bit-for-bit contract: the backend only ever computes *score values* (which
-are IEEE-f64 op-for-op identical to the numpy path) and *feasibility
+are IEEE-f64 op-for-op identical to the numpy path), DADA's *affinity
+order* (by the host's tolerance rule and sort key) and *feasibility
 verdicts*; the placement for the accepted λ is always rebuilt by the
 strategy's own Python ``try_build``, so decisions — including tie-breaks —
 cannot drift. ``tests/test_backend.py`` enforces both levels. A TPU has no
@@ -44,10 +47,11 @@ op sequence), which admits structural speedups that keep bit-equal
 results:
 
   * the **affinity phase decomposes into per-resource chains**: each
-    by-score entry only reads/writes its own resource's load, so the
-    n-entry sequential loop becomes a (max-chain-length × resources) scan
-    — entries of different resources advance in parallel lanes — and the
-    per-task assignment flags come back through one gather;
+    by-score entry only reads/writes its own resource's load, and a
+    resource takes a prefix of its chain, so the chains' loads are folded
+    once per search and each probe only compares and gathers
+    (:func:`affinity_prefix`); the per-task assignment flags come back
+    through one gather;
   * the flexible phase runs on **split CPU/GPU load lanes** (the paper's
     Algorithm 2 only ever takes a min over one class at a time), with
     first-occurrence ``argmin`` preserving the scalar tie-break;
@@ -307,7 +311,8 @@ def _score_layout(key) -> Tuple[Packed, Packed]:
         ins += [("read_masks", (n_pad, r_pad), "i64"), ("per_read", (n_pad, r_pad), "f64")]
         if peer:
             ins.append(("per_read_peer", (n_pad, r_pad), "f64"))
-        outs += [("X", (n_pad, n_res), "f64") if x_rows else ("X_max", (n_pad,), "f64")]
+        if x_rows:
+            outs.append(("X", (n_pad, n_res), "f64"))
     if want_bias:
         ins.append(("x_bias", (n_pad, n_res), "f64"))
     if want_s:
@@ -324,24 +329,69 @@ def _score_layout(key) -> Tuple[Packed, Packed]:
 _SEARCH_SCALARS = (
     ("no_cpus", "bool"), ("no_gpus", "bool"), ("alpha", "f64"), ("two_alpha", "f64"),
     ("area", "f64"), ("off_total", "f64"), ("max_off", "f64"), ("n_res_f", "f64"),
-    ("eps_rel", "f64"), ("max_iters", "i32"), ("upper0", "f64"),
+    ("eps_rel", "f64"), ("max_iters", "i32"),
 )
+
+
+def _search_fields(n_pad: int, n_res: int, n_cpu: int, n_gpu: int) -> list:
+    """The λ-independent inputs of the λ search that the host packs, less
+    the per-class durations and the affinity chains."""
+    return ([("loads0", (n_res,), "f64"), ("valid", (n_pad,), "bool"),
+             ("flex_ord", (n_pad,), "i32"), ("cpu_idx", (n_cpu,), "i32"),
+             ("gpu_idx", (n_gpu,), "i32")]
+            + [(name, (), kind) for name, kind in _SEARCH_SCALARS])
+
+
+def _search_vals(n: int, n_pad: int, resources, s: Dict[str, object]) -> dict:
+    """The values of ``_search_fields`` from the search's inputs ``s`` (the
+    ``search`` of :meth:`JaxScoringBackend.score_matrices`)."""
+    accel = [r.is_accelerator for r in resources]
+    # padded flex_order entries point at row 0; the search masks them with
+    # the position-validity of `valid` (True exactly for k < n)
+    ford = np.zeros(n_pad, dtype=np.int64)
+    ford[:n] = s["flex_order"]
+    alpha = s["alpha"]
+    return dict(
+        loads0=s["offsets"], valid=np.arange(n_pad) < n, flex_ord=ford,
+        cpu_idx=[j for j, a in enumerate(accel) if not a],
+        gpu_idx=[j for j, a in enumerate(accel) if a],
+        no_cpus=s["no_cpus"], no_gpus=s["no_gpus"], alpha=alpha,
+        two_alpha=2.0 + alpha, area=s["area"], off_total=s["off_total"],
+        max_off=s["max_off"], n_res_f=float(len(resources)),
+        eps_rel=s["eps_rel"], max_iters=s["max_iters"],
+    )
 
 
 @functools.lru_cache(maxsize=None)
 def _search_layout(key) -> Packed:
-    """The λ search's packed inputs, from its key."""
-    n_pad, chain_pad, n_res, n_cpu, n_gpu = key[:5]
-    fields = [("loads0", (n_res,), "f64"), ("p_cpu", (n_pad,), "f64"),
-              ("p_gpu", (n_pad,), "f64"), ("valid", (n_pad,), "bool"),
-              ("flex_ord", (n_pad,), "i32")]
-    if chain_pad:
-        fields += [("chain_cost", (chain_pad, n_res), "f64"),
-                   ("chain_valid", (chain_pad, n_res), "bool"),
-                   ("task_slot", (n_pad,), "i32")]
-    fields += [("cpu_idx", (n_cpu,), "i32"), ("gpu_idx", (n_gpu,), "i32")]
-    fields += [(name, (), kind) for name, kind in _SEARCH_SCALARS]
+    """The λ search's packed inputs, from its key: the affinity chains are
+    padded to the rows, and their length is a value, not a shape."""
+    n_pad, n_res, n_cpu, n_gpu = key[:4]
+    fields = [("p_cpu", (n_pad,), "f64"), ("p_gpu", (n_pad,), "f64"),
+              ("chain_cost", (n_pad, n_res), "f64"),
+              ("chain_valid", (n_pad, n_res), "bool"),
+              ("task_slot", (n_pad,), "i32"), ("chain_len", (), "i32")]
+    fields += _search_fields(n_pad, n_res, n_cpu, n_gpu) + [("upper0", (), "f64")]
     return Packed(fields)
+
+
+@functools.lru_cache(maxsize=None)
+def _fused_layout(key) -> Tuple[Packed, Packed]:
+    """The one-dispatch DADA program's packed inputs and outputs, from its
+    key: the score program's key, then the search's machine flags."""
+    n_pad, n_res, want_s = key[0], key[4], key[7]
+    n_cpu, n_gpu = key[12:14]
+    ins, _ = _score_layout(key[:12])
+    fields = [f[:3] for f in ins.fields] + _search_fields(n_pad, n_res, n_cpu, n_gpu)
+    # the host's part of the λ upper bound: sum(max(p_cpu, p_gpu)) + max_off
+    fields.append(("upper_host", (), "f64"))
+    outs = [("C", (n_pad, n_res), "f64")]
+    if want_s:
+        fields.append(("tids", (n_pad,), "i64"))
+        outs += [("ord_row", (n_pad,), "i32"), ("ord_rid", (n_pad,), "i32"),
+                 ("n_pref", (), "i32")]
+    outs += [("lam", (), "f64"), ("upper0", (), "f64")]
+    return Packed(fields), Packed(outs)
 
 
 def call_program(prog: str, fn, args, reads, counts: Optional[dict] = None):
@@ -366,6 +416,307 @@ def call_program(prog: str, fn, args, reads, counts: Optional[dict] = None):
         counts["uploads"] += sum(up is not None for up, _ in args)
         counts["readbacks"] += len(reads)
     return out, host
+
+
+# ---------------------------------------------------------------------------
+# DADA's order and λ search as traced functions: each program that runs them
+# (``dada_lambda_search``, ``dada_score_and_search``) traces these, in the
+# f64 arithmetic ``F`` (``repro.core.f64``)
+
+
+def chain_loads(F, loads0, chain_cost, length):
+    """Each resource's load before each entry of its affinity chain: row
+    ``k`` is ``loads0`` plus the chain's first ``k`` costs, added in chain
+    order; row ``k + 1`` is the load after entry ``k`` if it is taken.
+    ``length`` is the longest chain; rows past it stay 0, and no verdict
+    reads them. Nothing here depends on λ, so a search folds it once."""
+    import jax
+    import jax.numpy as jnp
+
+    cum = jnp.zeros((chain_cost.shape[0] + 1,) + loads0.shape, loads0.dtype)
+
+    def body(k, cum):
+        return cum.at[k + 1].set(F.add(cum[k], chain_cost[k]))
+
+    return jax.lax.fori_loop(0, length, body, cum.at[0].set(loads0))
+
+
+def affinity_prefix(F, cum, chain_valid, budget, cap):
+    """DADA's local affinity phase at one guess λ, from the chains' loads
+    ``cum`` (:func:`chain_loads`): the loads after the phase, whether a
+    taken entry overflows ``cap``, and which entries are taken.
+
+    An entry is taken iff its resource's load before it is ≤ ``budget``;
+    a skipped entry leaves the load as it was, so every later entry of its
+    chain is skipped too, and a resource's takes are a prefix of its chain.
+    Each probe then only compares and gathers: the verdict is the
+    sequential chain scan's, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    ok = chain_valid & F.le(cum[:-1], budget)
+    takes = jax.lax.cummin(ok.astype(jnp.int32), axis=0) == 1
+    bad = jnp.any(takes & F.lt(cap, cum[1:]))
+    n_taken = jnp.sum(takes, axis=0, dtype=jnp.int32)
+    loads = jnp.take_along_axis(cum, n_taken[None, :], axis=0)[0]
+    return loads, bad, takes
+
+
+def affinity_order(F, S, C, tids, valid, cols):
+    """DADA's affinity preferences, ordered as ``dada.place`` orders them
+    on the host: each row's best resource by the rid-ascending
+    ``s > best + _TINY`` scan over ``cols`` (ascending; a column left out
+    must hold no positive score), the rows that have one sorted by
+    (−score, tid), and each resource's chain of them in that order.
+
+    Returns ``(ord_row, ord_rid, n_pref, chains)``: the row and resource of
+    each entry in order (the first ``n_pref`` have a preference), and the
+    chains as :func:`lambda_search` takes them."""
+    import jax
+    import jax.numpy as jnp
+
+    lax = jax.lax
+    n_pad, n_res = C.shape
+    TINY = F.const(_TINY)
+
+    def scan_col(carry, x):
+        best, best_rid = carry
+        col, rid = x
+        upd = F.lt(F.add(best, TINY), col)
+        return (jnp.where(upd, col, best), jnp.where(upd, rid, best_rid)), None
+
+    (best, best_rid), _ = lax.scan(
+        scan_col, (jnp.zeros_like(S[:, 0]), jnp.full((n_pad,), -1, jnp.int32)),
+        (S[:, cols].T, cols),
+    )
+    sel = valid & (best_rid >= 0)
+    rows = jnp.arange(n_pad, dtype=jnp.int32)
+    # tids are unique, so the order of the entries with a preference is
+    # total; those without one sort last, in any order
+    _, _, _, ord_row, ord_rid = lax.sort(
+        (jnp.where(sel, 0, 1), -F.key(best), tids, rows, best_rid), num_keys=3
+    )
+    n_pref = jnp.sum(sel, dtype=jnp.int32)
+    entry = rows < n_pref
+    rid = jnp.maximum(ord_rid, 0)
+    # an entry's position in its resource's chain: the earlier entries of
+    # that resource; entries without a preference get n_pad, which every
+    # scatter below drops
+    same = (rid[:, None] == jnp.arange(n_res)[None, :]) & entry[:, None]
+    pos = jnp.take_along_axis(jnp.cumsum(same, axis=0, dtype=jnp.int32),
+                              rid[:, None], axis=1)[:, 0] - 1
+    pos = jnp.where(entry, pos, n_pad)
+    chain_cost = jnp.zeros_like(C).at[pos, rid].set(C[ord_row, rid], mode="drop")
+    chain_valid = jnp.zeros(C.shape, bool).at[pos, rid].set(True, mode="drop")
+    # each row's cell of the chains, or the cell past them (never taken)
+    task_slot = jnp.zeros((n_pad,), jnp.int32).at[ord_row].set(
+        jnp.where(entry, pos * n_res + rid, n_pad * n_res))
+    length = jnp.max(jnp.where(entry, pos + 1, 0))
+    return ord_row, ord_rid, n_pref, (chain_cost, chain_valid, task_slot, length)
+
+
+def lambda_search(F, depth, have_both, area_bound, C, v, chains=None):
+    """DADA's binary search on λ as one traced loop: the final ``upper``,
+    bit for bit the value the Python loop in ``dada.place`` settles on.
+
+    ``C`` is the padded cost matrix; ``v`` holds the search's inputs by
+    their packed names (``_search_fields``, ``p_cpu``, ``p_gpu`` and
+    ``upper0``); ``chains`` is ``(chain_cost, chain_valid, task_slot,
+    length)`` (:func:`affinity_order`), or None without an affinity phase.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    lax = jax.lax
+    add, sub, mul, lt, le = F.add, F.sub, F.mul, F.lt, F.le
+    n_pad, n_res = C.shape
+    K = 2 ** depth - 1
+    (loads0, p_cpu, p_gpu, valid, flex_ord, cpu_idx, gpu_idx, no_cpus, no_gpus,
+     alpha, two_alpha, area, off_total, max_off, n_res_f, eps_rel, max_iters,
+     upper0) = map(v.get, (
+         "loads0", "p_cpu", "p_gpu", "valid", "flex_ord", "cpu_idx", "gpu_idx",
+         "no_cpus", "no_gpus", "alpha", "two_alpha", "area", "off_total",
+         "max_off", "n_res_f", "eps_rel", "max_iters", "upper0"))
+    TINY, INF, HALF = F.const(_TINY), F.const(float("inf")), F.const(0.5)
+    # probe-invariant values, computed once per search
+    if chains is not None:
+        chain_cost, chain_valid, task_slot, length = chains
+        cum = chain_loads(F, loads0, chain_cost, length)
+    if have_both:
+        C_g = C[:, gpu_idx]
+        C_c = C[:, cpu_idx]
+        Cf_g = C_g[flex_ord]
+        Cf_c = C_c[flex_ord]
+        gpu_mask = jnp.zeros((n_res,), bool).at[gpu_idx].set(True)
+        cpu_mask = ~gpu_mask
+
+    def verdict(lam):
+        """Feasibility of guess λ — the exact boolean dada's
+        ``try_build(lam) is not None`` yields (early-exit order differs,
+        the verdict cannot: overflow flags are sticky and loads accumulate
+        through the same op sequence)."""
+        cap = add(mul(two_alpha, lam), TINY)
+        bad = lt(cap, max_off)
+        if area_bound:
+            bad = bad | lt(add(sub(mul(lam, n_res_f), off_total), TINY), area)
+        loads = loads0
+
+        if chains is not None:
+            budget = add(mul(alpha, lam), TINY)
+            loads, over, takes = affinity_prefix(F, cum, chain_valid, budget, cap)
+            bad = bad | over
+            flat = jnp.append(takes.reshape(-1), False)
+            assigned = flat[task_slot]
+        else:
+            assigned = jnp.zeros((n_pad,), dtype=bool)
+
+        rem = valid & ~assigned
+        big_cpu = no_cpus | lt(lam, p_cpu)
+        big_gpu = no_gpus | lt(lam, p_gpu)
+        bad = bad | jnp.any(rem & big_cpu & big_gpu)
+
+        def balance(args):
+            loads, bad = args
+            if have_both:
+                flex = rem & le(p_cpu, lam) & le(p_gpu, lam)
+                ded = rem & ~flex
+                ded_gpu = lt(lam, p_cpu)
+                lanes = jnp.arange(n_res)
+
+                def dstep(carry, x):
+                    loads, bad = carry
+                    on, to_gpu, crow = x
+                    pool = jnp.where(to_gpu, gpu_mask, cpu_mask)
+                    vm = jnp.where(pool, add(loads, crow), INF)
+                    # first-occurrence argmin keeps the scalar tie-break
+                    j = jnp.argmin(F.key(vm))
+                    bv = vm[j]
+                    bad = bad | (on & lt(cap, bv))
+                    loads = jnp.where((lanes == j) & on, bv, loads)
+                    return (loads, bad), None
+
+                def ded_pass(args):
+                    (loads, bad), _ = lax.scan(
+                        dstep, args, (ded, ded_gpu, C), unroll=F.unroll
+                    )
+                    return loads, bad
+
+                # the dedicated pass is usually empty for feasible λ
+                # guesses — skip its n-step scan when it is
+                loads, bad = lax.cond(
+                    jnp.any(ded), ded_pass, lambda a: a, (loads, bad)
+                )
+
+                # flexible phase on split class lanes: Algorithm 2 only
+                # ever takes the min over one class at a time
+                loads_g = loads[gpu_idx]
+                loads_c = loads[cpu_idx]
+                gpu_budget = add(lam, TINY)
+                # `valid` is a position mask (True exactly for k < n), so
+                # it also masks padded flex positions
+                flex_o = flex[flex_ord] & valid
+
+                def fstep(carry, x):
+                    loads_g, loads_c, bad = carry
+                    on, crow_g, crow_c = x
+                    g = jnp.argmin(F.key(loads_g))
+                    gl = loads_g[g]
+                    use_gpu = on & le(gl, gpu_budget)
+                    vg = add(gl, crow_g[g])
+                    bad = bad | (use_gpu & lt(cap, vg))
+                    loads_g = loads_g.at[g].set(jnp.where(use_gpu, vg, gl))
+                    vm = add(loads_c, crow_c)
+                    j = jnp.argmin(F.key(vm))
+                    bv = vm[j]
+                    use_eft = on & ~use_gpu
+                    bad = bad | (use_eft & lt(cap, bv))
+                    loads_c = loads_c.at[j].set(jnp.where(use_eft, bv, loads_c[j]))
+                    return (loads_g, loads_c, bad), None
+
+                (loads_g, loads_c, bad), _ = lax.scan(
+                    fstep, (loads_g, loads_c, bad),
+                    (flex_o, Cf_g, Cf_c), unroll=F.unroll,
+                )
+                # `loads` is returned un-merged: only `bad` is read after
+                # the balance phase
+            else:
+                # single-class machine: the EFT pool is every resource,
+                # processed in index order
+                def sstep(carry, x):
+                    loads, bad = carry
+                    on, crow = x
+                    vm = add(loads, crow)
+                    j = jnp.argmin(F.key(vm))
+                    bv = vm[j]
+                    bad = bad | (on & lt(cap, bv))
+                    loads = loads.at[j].set(jnp.where(on, bv, loads[j]))
+                    return (loads, bad), None
+
+                (loads, bad), _ = lax.scan(
+                    sstep, (loads, bad), (rem, C), unroll=F.unroll
+                )
+            return loads, bad
+
+        # a probe that already failed skips the balance scans
+        loads, bad = lax.cond(bad, lambda a: a, balance, (loads, bad))
+        return bad
+
+    feasible_grid = jax.vmap(lambda lam: ~verdict(lam))
+
+    def searching(lower, upper, it):
+        return lt(mul(eps_rel, upper), sub(upper, lower)) & (it < max_iters)
+
+    def cond(state):
+        return searching(*state)
+
+    def body(state):
+        lower, upper, it = state
+        # speculative midpoint tree (heap layout): node k covers an
+        # interval; its midpoint is the probe the bisection would make on
+        # reaching it. Depth-d tree = the next d probes for every possible
+        # verdict path — all evaluated in one vmapped sweep of the λ grid.
+        lo = [None] * K
+        hi = [None] * K
+        mid = [None] * K
+        lo[0], hi[0] = lower, upper
+        for k in range(K):
+            # (lo + hi) / 2: halving is exact, so * 0.5 is the same
+            mid[k] = mul(add(lo[k], hi[k]), HALF)
+            if 2 * k + 2 < K:
+                lo[2 * k + 1], hi[2 * k + 1] = lo[k], mid[k]
+                lo[2 * k + 2], hi[2 * k + 2] = mid[k], hi[k]
+        mids = jnp.stack(mid)
+        if K == 1:
+            # no vmap at depth 1: gathers/updates inside the verdict stay
+            # scalar-indexed (cheap on CPU) instead of turning into batched
+            # scatters
+            feas = jnp.reshape(~verdict(mids[0]), (1,))
+        else:
+            feas = feasible_grid(mids)
+        # walk ≤ depth bisection steps, re-checking the stopping rule
+        # before each (exactly like the Python while loop)
+        idx = jnp.int32(0)
+        for _ in range(depth):
+            go = searching(lower, upper, it)
+            safe = jnp.minimum(idx, K - 1)
+            f = feas[safe]
+            lam = mids[safe]
+            lower = jnp.where(go & ~f, lam, lower)
+            upper = jnp.where(go & f, lam, upper)
+            it = it + go.astype(jnp.int32)
+            idx = jnp.where(go, 2 * idx + jnp.where(f, 1, 2), idx)
+        return lower, upper, it
+
+    _, upper, _ = lax.while_loop(cond, body, (F.const(0.0), upper0, jnp.int32(0)))
+    return upper
+
+
+def _row_fold(F, x):
+    """``((0 + x[0]) + x[1]) + …``: the sum in row order, as the host adds."""
+    import jax
+
+    acc, _ = jax.lax.scan(lambda acc, v: (F.add(acc, v), None), F.const(0.0), x)
+    return acc
 
 
 class JaxScoringBackend:
@@ -416,10 +767,11 @@ class JaxScoringBackend:
         # DADA program call; the per-machine arrays, uploaded once, are not
         # counted); task x resource score cells computed on the device
         # (``cells_device``) and, by the strategies, on the host
-        # (``cells_host``)
+        # (``cells_host``); activations whose order and λ search ran in the
+        # one program that scored them (``fused``)
         self.counts = {"device": 0, "outside": 0, "rejected": 0,
                        "uploads": 0, "readbacks": 0,
-                       "cells_device": 0, "cells_host": 0}
+                       "cells_device": 0, "cells_host": 0, "fused": 0}
         self._matrix_fns: Dict[tuple, object] = {}
         self._search_fns: Dict[tuple, object] = {}
         self._heft_fns: Dict[tuple, object] = {}
@@ -504,6 +856,7 @@ class JaxScoringBackend:
         affinity: Optional[str] = None,
         x_rows: bool = False,
         x_bias: Optional[np.ndarray] = None,
+        search: Optional[dict] = None,
     ) -> Optional[dict]:
         """Fused (ready × resources) scoring matrices.
 
@@ -514,17 +867,28 @@ class JaxScoringBackend:
         numpy path's ``x + bias`` fold.
 
         Returns ``{"C": list rows|None, "C_np": array|None, "C_dev":
-        device array|None, "X_np": array|None, "X_rowmax": list|None,
-        "S_np": array|None}``: cost ``C`` (duration + predicted transfer)
-        when per-class durations are supplied, transfer times ``X`` when
-        ``use_cp`` (full rows only with ``x_rows=True`` — HEFT needs them;
-        DADA only needs the per-row maxima for its λ upper bound, reduced
-        on-device), affinity scores ``S`` when ``affinity`` names a
-        resident-weighted score. Every entry is bit-equal to the numpy
-        path (same IEEE op order); the device-resident ``C_dev`` (padded
-        to the same bucket the λ search uses) avoids a host round-trip
-        between the two calls. ``None`` means unsupported (caller takes
-        the numpy path).
+        device array|None, "X_np": array|None, "S_np": array|None}``: cost
+        ``C`` (duration + predicted transfer) when per-class durations are
+        supplied, transfer times ``X`` when ``use_cp`` and ``x_rows``
+        (HEFT), affinity scores ``S`` when ``affinity`` names a
+        resident-weighted score. Every entry is
+        bit-equal to the numpy path (same IEEE op order); ``C_dev`` is the
+        padded cost matrix, left on the device. ``None`` means unsupported
+        (caller takes the numpy path).
+
+        ``search`` (DADA, with the per-class durations): the λ-independent
+        inputs of DADA's λ search, by name: ``offsets``, ``flex_order``,
+        ``have_both``, ``no_cpus``, ``no_gpus``, ``alpha``, ``area_bound``,
+        ``area``, ``off_total``, ``max_off``, ``eps_rel``, ``max_iters`` and
+        ``upper_host``, the host's part of the upper bound
+        (``sum(max(p_cpu, p_gpu)) + max_off``). One program, ``dada_score_and_search``, then also orders the
+        affinity preferences and runs the whole λ search, in one dispatch
+        and one read-back; the result holds ``C``, ``C_np`` and ``C_dev``,
+        the affinity order (``order_rows``, ``order_rids``: the row and the
+        resource of each preference, best first), the search's final λ
+        (``lam``) and its upper bound at the start (``upper0``). An
+        affinity with no device form (``missing_bytes``) is then outside
+        the envelope: the order needs its scores on the device.
         """
         from .affinity import affinity_csr_source
 
@@ -544,7 +908,8 @@ class JaxScoringBackend:
             peer = want_x and mach["peer_bits"] is not None
             aff_src = affinity_csr_source(affinity, arr) if affinity else None
             want_s = aff_src is not None
-            if not (want_x or want_s or p_cpu is not None):
+            if not (want_x or want_s or p_cpu is not None) or (
+                    search is not None and affinity and not want_s):
                 self.counts["outside"] += 1
                 return None
             want_bias = want_x and x_bias is not None
@@ -592,11 +957,23 @@ class JaxScoringBackend:
 
             key = (n_pad, r_pad, w_pad, len(uniq), len(resources),
                    want_x, bool(x_rows), want_s, want_c, accel_only, want_bias, peer)
+            if search is None:
+                ins, outs = _score_layout(key)
+                build = self._build_matrix_fn
+            else:
+                assert want_c and not x_rows
+                vals.update(_search_vals(n, n_pad, resources, search))
+                vals["upper_host"] = search["upper_host"]
+                if want_s:
+                    vals["tids"] = np.zeros(n_pad, dtype=np.int64)
+                    vals["tids"][:n] = tids_arr
+                key += (len(vals["cpu_idx"]), len(vals["gpu_idx"]),
+                        bool(search["have_both"]), bool(search["area_bound"]), self.depth)
+                ins, outs = _fused_layout(key)
+                build = self._build_fused_fn
             fn = self._matrix_fns.get(key)
             if fn is None:
-                fn = self._build_matrix_fn(key)
-                self._matrix_fns[key] = fn
-            ins, outs = _score_layout(key)
+                fn = self._matrix_fns[key] = build(key)
             args = [
                 (self.jax.device_put, ins.pack(vals)),
                 (None, mach["mem_shift"]), (None, mach["host_col"]),
@@ -606,33 +983,37 @@ class JaxScoringBackend:
                 args.append((None, mach["peer_bits"]))
 
         def dec(x):
-            return {k: v[:n] for k, v in outs.split(x).items()}
+            return {k: v[:n] if v.ndim else v for k, v in outs.split(x).items()}
 
         raw, (host,) = call_program("score", fn, args, [(1, dec)], self.counts)
         self.counts["device"] += 1
         self.counts["cells_device"] += n * len(resources)
         out = dict(C=None, C_np=host.get("C"), C_dev=None, X_np=host.get("X"),
-                   X_rowmax=None, S_np=host.get("S"))
+                   S_np=host.get("S"))
         if want_c:
             out["C_dev"] = raw[0]
             out["C"] = out["C_np"].tolist()
-        if "X_max" in host:
-            out["X_rowmax"] = host["X_max"].tolist()
+        if search is not None:
+            self.counts["fused"] += 1
+            m = int(host["n_pref"]) if want_s else 0
+            none = np.zeros(0, np.int32)
+            out.update(order_rows=host.get("ord_row", none)[:m],
+                       order_rids=host.get("ord_rid", none)[:m],
+                       lam=float(host["lam"]), upper0=float(host["upper0"]))
         return out
 
-    def _build_matrix_fn(self, key):
+    def _score_body(self, key):
+        """The score program's computation, as a function of its unpacked
+        inputs and the machine's arrays: ``(C, X, X_max, S)``, each None
+        where the key does not ask for it."""
         (n_pad, r_pad, w_pad, n_u, n_res,
          want_x, x_rows, want_s, want_c, accel_only, want_bias, peer) = key
         jax, jnp = self.jax, self.jnp
         F = self.f64
-        ins, outs = _score_layout(key)
 
-        def dada_score_matrices(packed, mem_shift, host_col, col_of, accel_res,
-                                peer_bits=None):
-            """``(C, bits)``: the cost matrix, kept on the device for the λ
-            search, and one buffer of the outputs the host reads."""
+        def scores(v, mem_shift, host_col, col_of, accel_res, peer_bits):
             (read_masks, per_read, per_read_peer, x_bias, write_masks,
-             write_weights, p_cpu, p_gpu) = map(ins.unpack(packed, F).get, (
+             write_weights, p_cpu, p_gpu) = map(v.get, (
                  "read_masks", "per_read", "per_read_peer", "x_bias",
                  "write_masks", "write_weights", "p_cpu", "p_gpu"))
             X_res = None
@@ -680,9 +1061,61 @@ class JaxScoringBackend:
                 C = F.add(base, X_res) if want_x else jnp.broadcast_to(
                     base, (n_pad, n_res)
                 )
-            return C, outs.join(dict(C=C, X=X_res, X_max=X_max, S=S_res), F)
+            return C, X_res, X_max, S_res
 
-        return jax.jit(dada_score_matrices)
+        return scores
+
+    def _build_matrix_fn(self, key):
+        F = self.f64
+        ins, outs = _score_layout(key)
+        scores = self._score_body(key)
+
+        def dada_score_matrices(packed, mem_shift, host_col, col_of, accel_res,
+                                peer_bits=None):
+            """``(C, bits)``: the cost matrix, left on the device, and one
+            buffer of the outputs the host reads."""
+            C, X, _, S = scores(ins.unpack(packed, F), mem_shift, host_col,
+                                col_of, accel_res, peer_bits)
+            return C, outs.join(dict(C=C, X=X, S=S), F)
+
+        return self.jax.jit(dada_score_matrices)
+
+    def _build_fused_fn(self, key):
+        n_res, want_x, want_s, accel_only = key[4], key[5], key[7], key[9]
+        have_both, area_bound, depth = key[14:]
+        jnp = self.jnp
+        F = self.f64
+        ins, outs = _fused_layout(key)
+        scores = self._score_body(key[:12])
+
+        def score_and_search(packed, mem_shift, host_col, col_of, accel_res,
+                             peer_bits=None):
+            """``(C, bits)``: the cost matrix, left on the device, and one
+            buffer of what the host reads: ``C``, the affinity order, the
+            final λ and the search's upper bound at the start."""
+            v = ins.unpack(packed, F)
+            C, _, X_max, S = scores(v, mem_shift, host_col, col_of, accel_res,
+                                    peer_bits)
+            valid = v["valid"]
+            # the upper bound in the host's fold order: its own part, then
+            # the row maxima of X in row order, then _TINY
+            upper = v["upper_host"]
+            if want_x:
+                upper = F.add(upper, _row_fold(F, jnp.where(valid, X_max, F.const(0.0))))
+            v["upper0"] = F.add(upper, F.const(_TINY))
+            out = dict(C=C, upper0=v["upper0"])
+            chains = None
+            if want_s:
+                # with accel_only, the other columns of S are 0: never a best
+                cols = v["gpu_idx"] if accel_only else jnp.arange(n_res, dtype=jnp.int32)
+                out["ord_row"], out["ord_rid"], out["n_pref"], chains = affinity_order(
+                    F, S, C, v["tids"], valid, cols)
+            out["lam"] = lambda_search(F, depth, have_both, area_bound, C, v, chains)
+            return C, outs.join(out, F)
+
+        # the program's name in a device trace (jit_dada_score_and_search)
+        score_and_search.__name__ = "dada_score_and_search"
+        return self.jax.jit(score_and_search)
 
     # ------------------------------------------------------------------
     # DADA λ-probe search
@@ -713,7 +1146,10 @@ class JaxScoringBackend:
         max_iters: int,
         upper0: float,
     ) -> float:
-        """Run DADA's binary search on λ entirely on the backend.
+        """Run DADA's binary search on λ entirely on the backend, as a
+        program of its own over the order the host made (``by_score``,
+        ``flex_order``). DADA itself runs the search inside
+        ``dada_score_and_search`` (:meth:`score_matrices` with ``search``).
 
         Returns the final ``upper`` — identical (bit-for-bit) to the value
         the Python loop in ``dada.place`` would settle on, because every
@@ -726,32 +1162,26 @@ class JaxScoringBackend:
             n_pad = _bucket(n)
             assert C_dev.shape == (n_pad, n_res), (C_dev.shape, n_pad, n_res)
 
-            accel = [r.is_accelerator for r in resources]
-            vals = dict(
-                loads0=offsets, alpha=alpha, two_alpha=2.0 + alpha, area=area,
-                off_total=off_total, max_off=max_off, n_res_f=float(n_res),
-                eps_rel=eps_rel, max_iters=max_iters, upper0=upper0,
-                no_cpus=no_cpus, no_gpus=no_gpus,
-                cpu_idx=[j for j, a in enumerate(accel) if not a],
-                gpu_idx=[j for j, a in enumerate(accel) if a],
-                p_cpu=_pad_rows(p_cpu, n_pad), p_gpu=_pad_rows(p_gpu, n_pad),
-                valid=np.arange(n_pad) < n,
-            )
-            # padded flex_order entries point at row 0; the search masks them
-            # with the position-validity of `valid` (True exactly for k < n)
-            vals["flex_ord"] = ford = np.zeros(n_pad, dtype=np.int64)
-            ford[:n] = flex_order
+            vals = _search_vals(n, n_pad, resources, dict(
+                offsets=offsets, flex_order=flex_order, no_cpus=no_cpus,
+                no_gpus=no_gpus, alpha=alpha, area=area, off_total=off_total,
+                max_off=max_off, eps_rel=eps_rel, max_iters=max_iters))
+            vals.update(p_cpu=_pad_rows(p_cpu, n_pad), p_gpu=_pad_rows(p_gpu, n_pad),
+                        upper0=upper0)
 
             # Affinity phase → per-resource chains: entry k of by_score only
             # reads/writes loads[rid_k], so entries of different resources are
             # independent; within one resource the by-score order is preserved
-            # by the stable sort. The scan then runs max-chain-length steps
-            # with one lane per resource instead of len(by_score) steps, and
-            # each task reads its own take-flag back through one gather
-            # (task_slot points at the task's (chain position, rid) cell; the
-            # appended always-False cell absorbs tasks without a preference).
+            # by the stable sort. The search folds the chains' loads once and
+            # each probe takes a prefix of every chain (affinity_prefix); each
+            # task reads its own take-flag back through one gather (task_slot
+            # points at the task's (chain position, rid) cell; the cell past
+            # the chains, never taken, absorbs tasks without a preference).
+            chain_cost = np.zeros((n_pad, n_res), dtype=np.float64)
+            chain_valid = np.zeros((n_pad, n_res), dtype=bool)
+            task_slot = np.full(n_pad, n_pad * n_res, dtype=np.int64)
             m = len(by_score)
-            chain_pad = 0
+            chain_len = 0
             if m:
                 rids = np.fromiter((e[2] for e in by_score), np.int64, m)
                 costs = np.fromiter((e[3] for e in by_score), np.float64, m)
@@ -762,17 +1192,14 @@ class JaxScoringBackend:
                 srid = rids[perm]
                 first = np.searchsorted(srid, srid, side="left")
                 pos = np.arange(m, dtype=np.int64) - first
-                chain_pad = _bucket(int(pos.max()) + 1, lo=1)
-                chain_cost = np.zeros((chain_pad, n_res), dtype=np.float64)
-                chain_valid = np.zeros((chain_pad, n_res), dtype=bool)
+                chain_len = int(pos.max()) + 1
                 chain_cost[pos, srid] = costs[perm]
                 chain_valid[pos, srid] = True
-                task_slot = np.full(n_pad, chain_pad * n_res, dtype=np.int64)
                 task_slot[tis[perm]] = pos * n_res + srid
-                vals.update(chain_cost=chain_cost, chain_valid=chain_valid,
-                            task_slot=task_slot)
+            vals.update(chain_cost=chain_cost, chain_valid=chain_valid,
+                        task_slot=task_slot, chain_len=chain_len)
 
-            key = (n_pad, chain_pad, n_res, len(vals["cpu_idx"]), len(vals["gpu_idx"]),
+            key = (n_pad, n_res, len(vals["cpu_idx"]), len(vals["gpu_idx"]),
                    bool(have_both), bool(area_bound), self.depth)
             fn = self._search_fns.get(key)
             if fn is None:
@@ -785,222 +1212,19 @@ class JaxScoringBackend:
         return upper
 
     def _build_search_fn(self, key):
-        (n_pad, chain_pad, n_res, n_cpu, n_gpu,
-         have_both, area_bound, depth) = key
-        jax, jnp = self.jax, self.jnp
-        lax = jax.lax
+        have_both, area_bound, depth = key[4:]
         F = self.f64
-        add, sub, mul, lt, le = F.add, F.sub, F.mul, F.lt, F.le
-        K = 2 ** depth - 1
         layout = _search_layout(key)
 
         def search(packed, C):
-            (loads0, p_cpu, p_gpu, valid, flex_ord, chain_cost, chain_valid,
-             task_slot, cpu_idx, gpu_idx, no_cpus, no_gpus, alpha, two_alpha,
-             area, off_total, max_off, n_res_f, eps_rel, max_iters,
-             upper0) = map(layout.unpack(packed, F).get, (
-                 "loads0", "p_cpu", "p_gpu", "valid", "flex_ord", "chain_cost",
-                 "chain_valid", "task_slot", "cpu_idx", "gpu_idx", "no_cpus",
-                 "no_gpus", "alpha", "two_alpha", "area", "off_total", "max_off",
-                 "n_res_f", "eps_rel", "max_iters", "upper0"))
-            TINY, INF, HALF = F.const(_TINY), F.const(float("inf")), F.const(0.5)
-            # probe-invariant gathers, done once per search
-            if have_both:
-                C_g = C[:, gpu_idx]
-                C_c = C[:, cpu_idx]
-                Cf_g = C_g[flex_ord]
-                Cf_c = C_c[flex_ord]
-                gpu_mask = jnp.zeros((n_res,), bool).at[gpu_idx].set(True)
-                cpu_mask = ~gpu_mask
-
-            def verdict(lam):
-                """Feasibility of guess λ — the exact boolean dada's
-                ``try_build(lam) is not None`` yields (early-exit order
-                differs, the verdict cannot: overflow flags are sticky and
-                loads accumulate through the same op sequence)."""
-                cap = add(mul(two_alpha, lam), TINY)
-                bad = lt(cap, max_off)
-                if area_bound:
-                    bad = bad | lt(add(sub(mul(lam, n_res_f), off_total), TINY), area)
-                loads = loads0
-
-                if chain_pad:
-                    budget = add(mul(alpha, lam), TINY)
-
-                    def astep(carry, x):
-                        loads, bad = carry
-                        costs, av = x
-                        take = av & le(loads, budget)
-                        v = add(loads, costs)
-                        bad = bad | jnp.any(take & lt(cap, v))
-                        loads = jnp.where(take, v, loads)
-                        return (loads, bad), take
-
-                    (loads, bad), takes = lax.scan(
-                        astep, (loads, bad), (chain_cost, chain_valid),
-                        unroll=min(F.unroll, chain_pad),
-                    )
-                    flat = jnp.append(takes.reshape(-1), False)
-                    assigned = flat[task_slot]
-                else:
-                    assigned = jnp.zeros((n_pad,), dtype=bool)
-
-                rem = valid & ~assigned
-                big_cpu = no_cpus | lt(lam, p_cpu)
-                big_gpu = no_gpus | lt(lam, p_gpu)
-                bad = bad | jnp.any(rem & big_cpu & big_gpu)
-
-                def balance(args):
-                    loads, bad = args
-                    if have_both:
-                        flex = rem & le(p_cpu, lam) & le(p_gpu, lam)
-                        ded = rem & ~flex
-                        ded_gpu = lt(lam, p_cpu)
-                        lanes = jnp.arange(n_res)
-
-                        def dstep(carry, x):
-                            loads, bad = carry
-                            on, to_gpu, crow = x
-                            pool = jnp.where(to_gpu, gpu_mask, cpu_mask)
-                            vm = jnp.where(pool, add(loads, crow), INF)
-                            # first-occurrence argmin keeps the scalar
-                            # tie-break
-                            j = jnp.argmin(F.key(vm))
-                            bv = vm[j]
-                            bad = bad | (on & lt(cap, bv))
-                            loads = jnp.where((lanes == j) & on, bv, loads)
-                            return (loads, bad), None
-
-                        def ded_pass(args):
-                            (loads, bad), _ = lax.scan(
-                                dstep, args, (ded, ded_gpu, C), unroll=F.unroll
-                            )
-                            return loads, bad
-
-                        # the dedicated pass is usually empty for feasible
-                        # λ guesses — skip its n-step scan when it is
-                        loads, bad = lax.cond(
-                            jnp.any(ded), ded_pass, lambda a: a, (loads, bad)
-                        )
-
-                        # flexible phase on split class lanes: Algorithm 2
-                        # only ever takes the min over one class at a time
-                        loads_g = loads[gpu_idx]
-                        loads_c = loads[cpu_idx]
-                        gpu_budget = add(lam, TINY)
-                        # `valid` is a position mask (True exactly for
-                        # k < n), so it also masks padded flex positions
-                        flex_o = flex[flex_ord] & valid
-
-                        def fstep(carry, x):
-                            loads_g, loads_c, bad = carry
-                            on, crow_g, crow_c = x
-                            g = jnp.argmin(F.key(loads_g))
-                            gl = loads_g[g]
-                            use_gpu = on & le(gl, gpu_budget)
-                            vg = add(gl, crow_g[g])
-                            bad = bad | (use_gpu & lt(cap, vg))
-                            loads_g = loads_g.at[g].set(
-                                jnp.where(use_gpu, vg, gl)
-                            )
-                            vm = add(loads_c, crow_c)
-                            j = jnp.argmin(F.key(vm))
-                            bv = vm[j]
-                            use_eft = on & ~use_gpu
-                            bad = bad | (use_eft & lt(cap, bv))
-                            loads_c = loads_c.at[j].set(
-                                jnp.where(use_eft, bv, loads_c[j])
-                            )
-                            return (loads_g, loads_c, bad), None
-
-                        (loads_g, loads_c, bad), _ = lax.scan(
-                            fstep, (loads_g, loads_c, bad),
-                            (flex_o, Cf_g, Cf_c), unroll=F.unroll,
-                        )
-                        # `loads` is returned un-merged: only `bad` is read
-                        # after the balance phase
-                    else:
-                        # single-class machine: the EFT pool is every
-                        # resource, processed in index order
-                        def sstep(carry, x):
-                            loads, bad = carry
-                            on, crow = x
-                            vm = add(loads, crow)
-                            j = jnp.argmin(F.key(vm))
-                            bv = vm[j]
-                            bad = bad | (on & lt(cap, bv))
-                            loads = loads.at[j].set(
-                                jnp.where(on, bv, loads[j])
-                            )
-                            return (loads, bad), None
-
-                        (loads, bad), _ = lax.scan(
-                            sstep, (loads, bad), (rem, C), unroll=F.unroll
-                        )
-                    return loads, bad
-
-                # a probe that already failed skips the balance scans
-                loads, bad = lax.cond(
-                    bad, lambda a: a, balance, (loads, bad)
-                )
-                return bad
-
-            feasible_grid = jax.vmap(lambda lam: ~verdict(lam))
-
-            def searching(lower, upper, it):
-                return lt(mul(eps_rel, upper), sub(upper, lower)) & (it < max_iters)
-
-            def cond(state):
-                return searching(*state)
-
-            def body(state):
-                lower, upper, it = state
-                # speculative midpoint tree (heap layout): node k covers an
-                # interval; its midpoint is the probe the bisection would
-                # make on reaching it. Depth-d tree = the next d probes for
-                # every possible verdict path — all evaluated in one
-                # vmapped sweep of the λ grid.
-                lo = [None] * K
-                hi = [None] * K
-                mid = [None] * K
-                lo[0], hi[0] = lower, upper
-                for k in range(K):
-                    # (lo + hi) / 2: halving is exact, so * 0.5 is the same
-                    mid[k] = mul(add(lo[k], hi[k]), HALF)
-                    if 2 * k + 2 < K:
-                        lo[2 * k + 1], hi[2 * k + 1] = lo[k], mid[k]
-                        lo[2 * k + 2], hi[2 * k + 2] = mid[k], hi[k]
-                mids = jnp.stack(mid)
-                if K == 1:
-                    # no vmap at depth 1: gathers/updates inside the
-                    # verdict stay scalar-indexed (cheap on CPU) instead
-                    # of turning into batched scatters
-                    feas = jnp.reshape(~verdict(mids[0]), (1,))
-                else:
-                    feas = feasible_grid(mids)
-                # walk ≤ depth bisection steps, re-checking the stopping
-                # rule before each (exactly like the Python while loop)
-                idx = jnp.int32(0)
-                for _ in range(depth):
-                    go = searching(lower, upper, it)
-                    safe = jnp.minimum(idx, K - 1)
-                    f = feas[safe]
-                    lam = mids[safe]
-                    lower = jnp.where(go & ~f, lam, lower)
-                    upper = jnp.where(go & f, lam, upper)
-                    it = it + go.astype(jnp.int32)
-                    idx = jnp.where(go, 2 * idx + jnp.where(f, 1, 2), idx)
-                return lower, upper, it
-
-            _, upper, _ = lax.while_loop(
-                cond, body, (F.const(0.0), upper0, jnp.int32(0))
-            )
-            return upper
+            v = layout.unpack(packed, F)
+            chains = (v["chain_cost"], v["chain_valid"], v["task_slot"], v["chain_len"])
+            return lambda_search(F, depth, have_both, area_bound, C, v, chains)
 
         # the program's name in a device trace (jit_dada_lambda_search); the
         # def is named apart from the method, which the lint would take for it
         search.__name__ = "dada_lambda_search"
-        return jax.jit(search)
+        return self.jax.jit(search)
 
     # ------------------------------------------------------------------
     # HEFT earliest-finish-time selection

@@ -160,62 +160,7 @@ class DADA(ScoringBackendMixin, Strategy):
                         if p > 0.0:
                             noticed_pen[j] = p
 
-        # accelerated fused scoring (wide activations, jax backend): C, X
-        # and the affinity matrix come out of one jitted dispatch, bit-equal
-        # to the numpy formulas below (skipped under active faults or
-        # pending notices — the backend kernels do not model liveness)
-        be = self._scoring_backend()
-        fused = None
-        if be is not None and n >= be.min_wide and not dead and not noticed_pen:
-            fused = be.score_matrices(
-                sim, tids, resources,
-                p_cpu=p_cpu, p_gpu=p_gpu,
-                use_cp=self.use_cp,
-                affinity=self.affinity_name if self.alpha > 0.0 else None,
-                x_bias=P,
-            )
-        if be is not None and fused is None:
-            be.counts["cells_host"] += n * n_res
-
         with obs.span("dada.order"):
-            use_backend_search = fused is not None
-
-            if fused is not None:
-                X = None  # worst-case transfer bound: fused["X_rowmax"] below
-                C_rows = fused["C"]
-            elif self.use_cp:
-                X = fold_pressure(
-                    sim.transfer_model.task_input_transfer_rows(
-                        sim.arrays, tids, [r.mem for r in resources], sim.residency
-                    ),
-                    P,
-                )
-            else:
-                X = None
-
-            # cost matrix C[i][rid] = duration-on-class + predicted transfer
-            if fused is None:
-                gpu_pos = [j for j, r in enumerate(resources) if r.is_accelerator]
-                if X is None:
-                    C_rows = []
-                    for pc, pg in zip(p_cpu, p_gpu):
-                        row = [pc] * n_res
-                        for j in gpu_pos:
-                            row[j] = pg
-                        C_rows.append(row)
-                else:
-                    C_rows = []
-                    for pc, pg, xrow in zip(p_cpu, p_gpu, X):
-                        row = [pc + x for x in xrow]
-                        for j in gpu_pos:
-                            row[j] = pg + xrow[j]
-                        C_rows.append(row)
-            if noticed_pen:
-                # condemned columns pay the remaining notice window (the fused
-                # path is disabled above, so C_rows is always the list form)
-                for row in C_rows:
-                    for j, p in noticed_pen.items():
-                        row[j] += p
             offsets = [
                 lt - sim.now if lt - sim.now > 0.0 else 0.0
                 for lt in (sim.load_ts[r.rid] for r in resources)
@@ -227,41 +172,105 @@ class DADA(ScoringBackendMixin, Strategy):
                     if r.rid in dead:
                         offsets[j] = 0.0
 
-            # affinity preferences per task, with the placement cost prefetched
-            pref: List[Tuple[float, int, int, float]] = []  # (score, tid, rid, cost)
-            S_np = fused["S_np"] if fused is not None else None
-            if self.alpha > 0.0 and S_np is not None:
-                # vectorized best-resource selection: one pass per resource
-                # column reproduces the scalar rid-ascending tolerance scan
-                # row-by-row, and the (-score, tid) lexsort matches sorted()
-                # because tids are unique
-                best = np.zeros(n, dtype=np.float64)
-                best_rid = np.full(n, -1, dtype=np.int64)
-                for rid in range(n_res):
-                    col = S_np[:, rid]
-                    upd = col > best + _TINY
-                    if upd.any():
-                        best[upd] = col[upd]
-                        best_rid[upd] = rid
-                sel = np.nonzero(best_rid >= 0)[0]
-                if len(sel):
-                    scores = best[sel]
-                    prids = best_rid[sel]
-                    ptids = np.asarray(tids, dtype=np.int64)[sel]
-                    pcosts = fused["C_np"][sel, prids]
-                    order_p = np.lexsort((ptids, -scores))
-                    by_score = list(
-                        zip(
-                            scores[order_p].tolist(),
-                            ptids[order_p].tolist(),
-                            prids[order_p].tolist(),
-                            pcosts[order_p].tolist(),
-                        )
-                    )
-                else:
-                    by_score = []
+            # speedup sort keys for the flexible phase (λ-independent)
+            skey = [-(pc / max(pg, _TINY)) for pc, pg in zip(p_cpu, p_gpu)]
+
+            cpu_rids = [r.rid for r in cpus if r.rid not in dead]
+            gpu_rids = [r.rid for r in gpus if r.rid not in dead]
+            any_rids = cpu_rids or gpu_rids
+            if not any_rids:
+                raise RuntimeError("DADA: every resource is detached")
+            have_both = bool(cpu_rids and gpu_rids)
+            no_cpus = not cpu_rids
+            no_gpus = not gpu_rids
+
+            area = off_total = 0.0
+            if self.area_bound:
+                area = sum(min(pc, pg) for pc, pg in zip(p_cpu, p_gpu))
+                off_total = sum(offsets)
+
+            all_idx = list(range(n))
+            # global flex order (λ-independent): per-probe flex sets are subsets
+            # of ready, so filtering this order equals sorting each subset.
+            # (skey, tid) keys are unique per task (tids are unique), so the
+            # wide-activation lexsort yields the identical permutation.
+            if n >= _WIDE:
+                flex_order = np.lexsort(
+                    (np.asarray(tids, dtype=np.int64), np.asarray(skey))
+                ).tolist()
             else:
-                if self.alpha > 0.0:
+                flex_order = sorted(all_idx, key=lambda i: (skey[i], tids[i]))
+            alpha = self.alpha
+            two_alpha = 2.0 + alpha
+            area_bound = self.area_bound
+            max_off = max(offsets, default=0.0)
+            n_res_alive = n_res - len(dead)
+            # the λ search's upper bound, less the transfer terms (X's row
+            # maxima) and _TINY, added in this order wherever it is summed
+            upper_host = sum(max(pc, pg) for pc, pg in zip(p_cpu, p_gpu)) + max_off
+
+            # wide activations, jax backend: one program scores C (and X's
+            # row maxima and the affinity scores), orders the affinity
+            # preferences and runs the λ search, bit-equal to the host path
+            # below (skipped under active faults or pending notices — the
+            # backend kernels do not model liveness)
+            be = self._scoring_backend()
+            fused = None
+            if be is not None and n >= be.min_wide and not dead and not noticed_pen:
+                fused = be.score_matrices(
+                    sim, tids, resources,
+                    p_cpu=p_cpu, p_gpu=p_gpu,
+                    use_cp=self.use_cp,
+                    affinity=self.affinity_name if alpha > 0.0 else None,
+                    x_bias=P,
+                    search=dict(
+                        offsets=offsets, flex_order=flex_order, have_both=have_both,
+                        no_cpus=no_cpus, no_gpus=no_gpus, alpha=alpha,
+                        area_bound=area_bound, area=area, off_total=off_total,
+                        max_off=max_off, eps_rel=self.eps_rel,
+                        max_iters=self.max_iters, upper_host=upper_host,
+                    ),
+                )
+            if be is not None and fused is None:
+                be.counts["cells_host"] += n * n_res
+
+            if fused is not None:
+                C_rows = fused["C"]
+            else:
+                X = None
+                if self.use_cp:
+                    X = fold_pressure(
+                        sim.transfer_model.task_input_transfer_rows(
+                            sim.arrays, tids, [r.mem for r in resources],
+                            sim.residency,
+                        ),
+                        P,
+                    )
+                # cost matrix C[i][rid] = duration-on-class + predicted transfer
+                gpu_pos = [j for j, r in enumerate(resources) if r.is_accelerator]
+                C_rows = []
+                if X is None:
+                    for pc, pg in zip(p_cpu, p_gpu):
+                        row = [pc] * n_res
+                        for j in gpu_pos:
+                            row[j] = pg
+                        C_rows.append(row)
+                else:
+                    for pc, pg, xrow in zip(p_cpu, p_gpu, X):
+                        row = [pc + x for x in xrow]
+                        for j in gpu_pos:
+                            row[j] = pg + xrow[j]
+                        C_rows.append(row)
+                if noticed_pen:
+                    # condemned columns pay the remaining notice window
+                    for row in C_rows:
+                        for j, p in noticed_pen.items():
+                            row[j] += p
+
+                # affinity preferences per task, with the placement cost
+                # prefetched: (tid, rid, cost) by (-score, tid)
+                pref: List[Tuple[float, int, int, float]] = []
+                if alpha > 0.0:
                     S_rows = affinity_rows(
                         self.affinity_name, sim.arrays, tids, ready, resources,
                         sim.residency,
@@ -284,40 +293,21 @@ class DADA(ScoringBackendMixin, Strategy):
                             pref.append(
                                 (best_score, tids[i], best_rid, C_rows[i][best_rid])
                             )
-                by_score = sorted(pref, key=lambda x: (-x[0], x[1]))
+                by_score = [
+                    (tid, rid, c)
+                    for _, tid, rid, c in sorted(pref, key=lambda x: (-x[0], x[1]))
+                ]
 
-            # speedup sort keys for the flexible phase (λ-independent)
-            skey = [-(pc / max(pg, _TINY)) for pc, pg in zip(p_cpu, p_gpu)]
-
-            cpu_rids = [r.rid for r in cpus if r.rid not in dead]
-            gpu_rids = [r.rid for r in gpus if r.rid not in dead]
-            any_rids = cpu_rids or gpu_rids
-            if not any_rids:
-                raise RuntimeError("DADA: every resource is detached")
-            have_both = bool(cpu_rids and gpu_rids)
-            no_cpus = not cpu_rids
-            no_gpus = not gpu_rids
-
-            if self.area_bound:
-                area = sum(min(pc, pg) for pc, pg in zip(p_cpu, p_gpu))
-                off_total = sum(offsets)
-
-            all_idx = list(range(n))
-            # global flex order (λ-independent): per-probe flex sets are subsets
-            # of ready, so filtering this order equals sorting each subset.
-            # (skey, tid) keys are unique per task (tids are unique), so the
-            # wide-activation lexsort yields the identical permutation.
-            if n >= _WIDE:
-                flex_order = np.lexsort(
-                    (np.asarray(tids, dtype=np.int64), np.asarray(skey))
-                ).tolist()
-            else:
-                flex_order = sorted(all_idx, key=lambda i: (skey[i], tids[i]))
-            alpha = self.alpha
-            two_alpha = 2.0 + alpha
-            area_bound = self.area_bound
-            max_off = max(offsets, default=0.0)
-            n_res_alive = n_res - len(dead)
+                # upper bound of the binary search on λ
+                worst_xfer = 0.0
+                if X is not None:
+                    for xrow in X:
+                        worst_xfer += max(xrow)
+                upper = upper_host + worst_xfer + _TINY
+                if noticed_pen:
+                    # the notice penalties inflate C, so the feasibility anchor
+                    # must cover them too (λ=upper stays provably feasible)
+                    upper += n * max(noticed_pen.values())
 
             # ------------------------------------------------------------------
             def try_build(lam: float) -> Optional[Tuple[Dict[int, int], List[float]]]:
@@ -338,7 +328,7 @@ class DADA(ScoringBackendMixin, Strategy):
                 # ---- local affinity phase (line 5-7) -------------------------
                 if by_score:
                     budget = alpha * lam + _TINY
-                    for sc, tid, rid, c in by_score:
+                    for tid, rid, c in by_score:
                         if loads[rid] <= budget:
                             assign[tid] = rid
                             v = loads[rid] + c
@@ -434,66 +424,28 @@ class DADA(ScoringBackendMixin, Strategy):
                 # acceptance (line 10) already enforced incrementally above
                 return assign, loads
 
-            # ------------------------------------------------------------------
-            # binary search on λ (classical dual-approximation driver)
-            worst_xfer = 0.0
-            if fused is not None and fused["X_rowmax"] is not None:
-                # device-reduced per-row maxima equal max(xrow) (max is
-                # order-independent); the host fold order is unchanged
-                for v in fused["X_rowmax"]:
-                    worst_xfer += v
-            elif X is not None:
-                for xrow in X:
-                    worst_xfer += max(xrow)
-            upper = (
-                sum(max(pc, pg) for pc, pg in zip(p_cpu, p_gpu))
-                + max_off
-                + worst_xfer
-                + _TINY
-            )
-            if noticed_pen:
-                # the notice penalties inflate C, so the feasibility anchor
-                # must cover them too (λ=upper stays provably feasible)
-                upper += n * max(noticed_pen.values())
-            lower = 0.0
-        if use_backend_search:
-            # the whole λ binary search runs as one backend dispatch; the
-            # returned λ is bit-identical to the Python loop's final
-            # upper, and the placement is rebuilt by try_build so decisions
-            # (including tie-breaks) cannot drift
-            lam_final = be.dada_lambda_search(
-                n=n,
-                n_res=n_res,
-                offsets=offsets,
-                C_dev=fused["C_dev"],
-                p_cpu=p_cpu,
-                p_gpu=p_gpu,
-                by_score=by_score,
-                tid_index={tid: i for i, tid in enumerate(tids)},
-                flex_order=flex_order,
-                resources=resources,
-                have_both=have_both,
-                no_cpus=no_cpus,
-                no_gpus=no_gpus,
-                alpha=alpha,
-                area_bound=area_bound,
-                area=(area if area_bound else 0.0),
-                off_total=(off_total if area_bound else 0.0),
-                max_off=max_off,
-                eps_rel=self.eps_rel,
-                max_iters=self.max_iters,
-                upper0=upper,
-            )
         # the placement at the final λ; where the search runs on the host
         # (narrow activations, or a rejected device λ) dada.search_host
         # nests inside this span
         with obs.span("dada.rebuild"):
+            # binary search on λ (the classical dual-approximation loop)
+            lower = 0.0
             kept: Optional[Tuple[Dict[int, int], List[float]]] = None
             searched = False
-            if use_backend_search:
-                built = try_build(lam_final)
+            if fused is not None:
+                # the device's λ is bit-identical to the Python loop's final
+                # upper, and the placement is rebuilt by try_build, over the
+                # device's affinity order, so decisions (tie-breaks
+                # included) cannot drift
+                rows, rids = fused["order_rows"], fused["order_rids"]
+                by_score = list(zip(
+                    np.asarray(tids, dtype=np.int64)[rows].tolist(), rids.tolist(),
+                    fused["C_np"][rows, rids].tolist(),
+                ))
+                upper = fused["upper0"]
+                built = try_build(fused["lam"])
                 if built is not None:
-                    upper = lam_final
+                    upper = fused["lam"]
                     kept = built
                     searched = True
                 else:

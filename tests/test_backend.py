@@ -152,6 +152,211 @@ def test_jax_matches_numpy_deep_lambda_tree(force_jax, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the one-dispatch program: scores, affinity order and λ search
+
+
+def _decisions(strategy, graph, machine, seed):
+    """A schedule's fingerprint, and the accepted λ and the loads of every
+    activation in order."""
+    trail = []
+    place = strategy.place
+
+    def recording(sim, ready, src):
+        place(sim, ready, src)
+        trail.append((strategy.last_lambda, tuple(sorted(strategy.last_loads.items()))))
+
+    strategy.place = recording
+    res = run_simulation(graph, machine, strategy, seed=seed)
+    return _fingerprint(res), trail
+
+
+def _machine(name):
+    if name == "dgx_a100":
+        from repro.configs.dgx_a100 import dgx_a100
+
+        return dgx_a100()
+    return paper_machine(8)
+
+
+# (machine, NT, alpha, +CP, area_bound): NT=32 gives the 8-31 wide
+# activations of the schedule cells, NT=20 those of 8-19
+ONE_DISPATCH_CASES = [
+    ("paper_machine", 32, 0.5, True, False),
+    ("dgx_a100", 32, 0.5, True, False),
+    ("paper_machine", 20, 0.0, False, False),
+    ("paper_machine", 20, 1.0, True, False),
+    ("paper_machine", 20, 0.5, False, False),
+    ("paper_machine", 20, 0.5, True, True),
+    ("dgx_a100", 20, 0.0, True, False),
+    ("dgx_a100", 20, 1.0, False, True),
+]
+
+
+@pytest.mark.parametrize("machine,nt,alpha,use_cp,area_bound", ONE_DISPATCH_CASES)
+def test_one_dispatch_matches_numpy(machine, nt, alpha, use_cp, area_bound):
+    """Every activation from 8 tasks wide runs the one program; the
+    placements, and each activation's accepted λ and loads, equal the
+    numpy path's bit for bit, with one upload and one read-back each."""
+    from repro.sched.config import SchedConfig
+
+    cfg = SchedConfig(backend="jax", jax_min=8)
+    mach = _machine(machine)
+    tile = 1024 if machine == "dgx_a100" else 512
+    be = get_backend("jax", cfg)
+    kw = dict(alpha=alpha, use_cp=use_cp, area_bound=area_bound, config=cfg)
+    before = dict(be.counts)
+    got = _decisions(DADA(backend="jax", **kw), cholesky_graph(nt, tile, with_fns=False),
+                     mach, seed=4)
+    n = {k: be.counts[k] - before[k] for k in be.counts}
+    want = _decisions(DADA(backend="numpy", **kw), cholesky_graph(nt, tile, with_fns=False),
+                      mach, seed=4)
+    assert got == want
+    assert n["device"] > 0 and n["outside"] == n["rejected"] == 0
+    assert n["fused"] == n["uploads"] == n["readbacks"] == n["device"]
+
+
+def _wide_wave(graph):
+    """The widest same-depth task set: the trailing-update wave."""
+    depth = [0] * len(graph)
+    for t in graph.tasks:
+        depth[t.tid] = max((depth[p] + 1 for p in graph.pred[t.tid]), default=0)
+    widest = max(set(depth), key=depth.count)
+    return [t for t in graph.tasks if depth[t.tid] == widest]
+
+
+def test_one_dispatch_matches_numpy_on_a_wide_wave():
+    """The ≥256-wide wave of Cholesky NT=32 on the 32-resource machine
+    (``chip_smoke.py`` phase (a)), with residency seeded so that transfers
+    and affinities are not trivial: one activation, placed alike."""
+    from repro.sched.config import SchedConfig
+
+    graph = cholesky_graph(32, 512, with_fns=False)
+    wave = _wide_wave(graph)
+    assert len(wave) >= 256
+    machine = scaled_machine()
+    placed = []
+    for backend in ("jax", "numpy"):
+        strat = DADA(alpha=0.5, use_cp=True, backend=backend,
+                     config=SchedConfig(backend=backend, jax_min=8))
+        sim = Simulator(graph, machine, strat, seed=0)
+        for k, name in enumerate(sim.arrays.data_names):
+            if k % 3 == 0:
+                sim.residency.write(name, k % 24)
+        pushes = []
+        sim.push = lambda task, rid: pushes.append((task.tid, rid))
+        strat.place(sim, wave, None)
+        placed.append((pushes, strat.last_lambda, strat.last_loads, list(sim.load_ts)))
+    assert placed[0] == placed[1]
+
+
+def _sequential_affinity(loads0, by_score, budget, cap):
+    """``try_build``'s affinity phase as written: entries in by-score order."""
+    loads = list(loads0)
+    taken, bad = [], False
+    for _, rid, c in by_score:
+        take = loads[rid] <= budget
+        taken.append(take)
+        if take:
+            v = loads[rid] + c
+            bad |= v > cap
+            loads[rid] = v
+    return loads, taken, bad
+
+
+def _host_chains(by_score, n_res):
+    """The chains as ``dada_lambda_search`` packs them."""
+    rids = np.asarray([e[1] for e in by_score], np.int64)
+    perm = np.argsort(rids, kind="stable")
+    srid = rids[perm]
+    pos = np.arange(len(rids)) - np.searchsorted(srid, srid, side="left")
+    chain_pad = int(pos.max()) + 1
+    cost = np.zeros((chain_pad, n_res))
+    valid = np.zeros((chain_pad, n_res), bool)
+    cost[pos, srid] = np.asarray([e[2] for e in by_score])[perm]
+    valid[pos, srid] = True
+    slot = np.empty(len(rids), np.int64)
+    slot[perm] = pos * n_res + srid
+    return cost, valid, slot
+
+
+@pytest.mark.parametrize("arith", ["native", "soft"])
+@pytest.mark.parametrize("case", range(4))
+def test_prefix_affinity_verdict_equals_sequential_scan(arith, case):
+    """The λ search's affinity phase in prefix form (the chains' loads
+    folded once, then one compare and gather per probe) against the
+    sequential scan over the by-score list: the same loads bit for bit,
+    the same takes and the same overflow verdict. Costs are quarters, so
+    loads land exactly on the budget; some are 0."""
+    from repro.core import f64
+    from repro.core.backend import affinity_prefix, chain_loads
+
+    F = f64.NATIVE if arith == "native" else f64.SOFT
+    rng = np.random.default_rng(case)
+    n_res, m = 6, 24
+    budget = 1.0
+    cap = (2.0, 1.25, 1.5, 3.0)[case]
+    loads0 = rng.choice([0.0, 0.25, 0.5, 1.0, 1.25], size=n_res)
+    loads0[0] = budget
+    by_score = [(tid, int(rng.integers(0, n_res)), float(rng.choice([0.0, 0.25, 0.5, 0.75])))
+                for tid in rng.permutation(m)]
+    want_loads, want_taken, want_bad = _sequential_affinity(loads0, by_score, budget, cap)
+    cost, valid, slot = _host_chains(by_score, n_res)
+    with jax.enable_x64(True):
+        cum = chain_loads(F, jax.numpy.asarray(F.encode(loads0)),
+                          jax.numpy.asarray(F.encode(cost)), cost.shape[0])
+        loads, bad, takes = affinity_prefix(F, cum, jax.numpy.asarray(valid),
+                                            F.const(budget), F.const(cap))
+        loads, bad, takes = F.decode(loads), bool(bad), np.asarray(takes)
+    assert loads.tolist() == want_loads
+    assert takes.reshape(-1)[slot].tolist() == want_taken
+    assert bad == want_bad
+    assert any(want_taken) and not all(want_taken)
+
+
+@pytest.mark.parametrize("arith", ["native", "soft"])
+def test_device_affinity_order_equals_host_rule(arith):
+    """The device's best resource per row (rid-ascending, ``s > best +
+    1e-12``, ties within the tolerance included) and its (−score, tid)
+    order equal DADA's host rule; padded rows and all-zero rows have no
+    preference."""
+    from repro.core import f64
+    from repro.core.backend import affinity_order
+
+    F = f64.NATIVE if arith == "native" else f64.SOFT
+    rng = np.random.default_rng(3)
+    n, n_pad, n_res = 13, 16, 5
+    base = rng.choice([0.0, 1.0, 2.0], size=(n_pad, n_res))
+    # steps below and above the tolerance decide between near-equal scores
+    S = base + rng.choice([0.0, 0.6e-12, 1.2e-12, 2.5e-12], size=(n_pad, n_res))
+    S[0] = [1.0, 1.0 + 0.6e-12, 0.0, 0.0, 0.0]  # within the tolerance: rid 0
+    S[1] = [0.0, 2.0, 2.0 + 0.5e-12, 2.0 + 1.1e-12, 0.0]  # past it: rid 3
+    S[2] = 0.0
+    S[3] = [1.0, 1.0, 1.0, 0.0, 0.0]  # ties with row 0 too: tid decides
+    S[n:] = rng.random((n_pad - n, n_res))  # padding: never a preference
+    C = rng.random((n_pad, n_res))
+    tids = rng.permutation(100)[:n_pad]
+    pref = []
+    for i in range(n):
+        best, best_rid = 0.0, -1
+        for rid in range(n_res):
+            if S[i, rid] > best + 1e-12:
+                best, best_rid = S[i, rid], rid
+        if best_rid >= 0:
+            pref.append((-best, int(tids[i]), i, best_rid))
+    pref.sort()
+    with jax.enable_x64(True):
+        jnp = jax.numpy
+        ord_row, ord_rid, n_pref, _ = affinity_order(
+            F, jnp.asarray(F.encode(S)), jnp.asarray(F.encode(C)),
+            jnp.asarray(tids, dtype=jnp.int64), jnp.arange(n_pad) < n,
+            jnp.arange(n_res, dtype=jnp.int32))
+        m = int(n_pref)
+    assert m == len(pref) < n
+    assert np.asarray(ord_row)[:m].tolist() == [e[2] for e in pref]
+    assert np.asarray(ord_rid)[:m].tolist() == [e[3] for e in pref]
+
+
+# ---------------------------------------------------------------------------
 # score-matrix bit-equality
 
 
@@ -319,17 +524,18 @@ def test_backend_does_not_leak_x64(force_jax):
 
 def test_padded_shapes_bound_retraces(force_jax):
     """Activation widths within one power-of-two bucket share a compiled
-    search: the jit caches must stay bounded across activations."""
+    program: the jit caches must stay bounded across activations."""
     be = get_backend("jax")
-    n_search_before = len(be._search_fns)
+    n_before = len(be._matrix_fns)
     machine = paper_machine(3)
     run_simulation(
         cholesky_graph(6, 256, with_fns=False), machine,
         DADA(alpha=0.5, use_cp=True, backend="jax"), seed=0,
     )
-    # ready widths 1..15 at NT=6 → buckets {8, 16} × (chain, flags) variants
-    grown = len(be._search_fns) - n_search_before
-    assert grown <= 8, f"unbounded retraces: {grown} new search signatures"
+    # ready widths 1..15 at NT=6 → buckets {8, 16} × (read, write) CSR
+    # width variants; the affinity chains are no longer part of the key
+    grown = len(be._matrix_fns) - n_before
+    assert grown <= 8, f"unbounded retraces: {grown} new program signatures"
 
 
 # ---------------------------------------------------------------------------
@@ -341,11 +547,10 @@ PHASES = ("pack", "upload", "dispatch", "readback")
 
 def test_spans_leave_placements_bit_equal_and_count_transfers(force_jax):
     """With the recorder on, a device-scored DADA+CP schedule places as it
-    does with it off; every device-scored activation has one span of each
-    phase of both programs, and makes 2 uploads and 2 read-backs: each
-    program puts its inputs on the device as one packed buffer, the score
-    matrices come back as one packed buffer (C, the row maxima of X and the
-    affinity scores), and λ as one value."""
+    does with it off; every device-scored activation runs one program, with
+    one span of each of its phases, and makes 1 upload and 1 read-back: its
+    inputs go to the device as one packed buffer, and C, the affinity order
+    and λ come back as one."""
     from repro.core import obs
 
     graph = cholesky_graph(5, 256, with_fns=False)
@@ -369,8 +574,7 @@ def test_spans_leave_placements_bit_equal_and_count_transfers(force_jax):
     assert _fingerprint(on) == _fingerprint(off)
     assert n_on == n_off
     assert n_on["device"] > 0 and n_on["outside"] == n_on["rejected"] == 0
-    assert n_on["uploads"] == 2 * n_on["device"]
-    assert n_on["readbacks"] == 2 * n_on["device"]
+    assert n_on["uploads"] == n_on["readbacks"] == n_on["fused"] == n_on["device"]
 
     roots = {s.id for s in spans if s.name == "dada.place"}
     assert all(s.root in roots for s in spans)
@@ -380,9 +584,9 @@ def test_spans_leave_placements_bit_equal_and_count_transfers(force_jax):
     scored = [names for names in per_root.values() if "score.dispatch" in names]
     assert len(scored) == n_on["device"]
     for names in scored:
-        for prog in ("score", "search"):
-            for phase in PHASES:
-                assert names.count(f"{prog}.{phase}") == 1, (prog, phase, names)
+        for phase in PHASES:
+            assert names.count(f"score.{phase}") == 1, (phase, names)
+        assert not any(name.startswith("search.") for name in names), names
         assert "dada.search_host" not in names
         for phase in ("predict", "order", "rebuild"):
             assert names.count(f"dada.{phase}") == 1
@@ -402,7 +606,7 @@ def test_programs_have_stable_names(force_jax):
     )
     names = {f.__name__ for fns in (be._matrix_fns, be._search_fns, be._heft_fns)
              for f in fns.values()}
-    assert names == {"dada_score_matrices", "dada_lambda_search", "heft_select"}
+    assert names == {"dada_score_matrices", "dada_score_and_search", "heft_select"}
     (n_pad, n_res), fn = next(iter(be._heft_fns.items()))
     f64 = be.f64.encode(np.zeros(0)).dtype
     rows = jax.ShapeDtypeStruct((n_pad, n_res), f64)

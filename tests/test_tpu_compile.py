@@ -22,7 +22,7 @@ from repro.configs.paper_machine import paper_machine, scaled_machine
 from repro.core import DADA, HEFT, Simulator
 from repro.core import episode as ep
 from repro.core import f64
-from repro.core.backend import JaxScoringBackend
+from repro.core.backend import DEFAULT_JAX_MIN, JaxScoringBackend
 from repro.linalg import cholesky
 from repro.linalg.cholesky import cholesky_graph
 from repro.sched.config import SchedConfig
@@ -85,63 +85,108 @@ def _wide_wave(graph):
     return [t for t in graph.tasks if depth[t.tid] == widest]
 
 
+def _recorded_backend(calls, jax_min=DEFAULT_JAX_MIN):
+    """A depth-5 backend in the chip's integer-exact f64 whose jit factories
+    record into ``calls`` (the fused programs apart from the score-matrix
+    programs, which share one cache)."""
+    be = JaxScoringBackend(SchedConfig(backend="jax", lambda_depth=5, jax_min=jax_min))
+    be.f64 = f64.for_platform("tpu")  # the chip has no IEEE f64
+    for kind, attr in (("matrix", "_build_matrix_fn"), ("fused", "_build_fused_fn"),
+                       ("search", "_build_search_fn"), ("heft", "_build_heft_fn")):
+        if kind in calls:
+            setattr(be, attr, _recording(getattr(be, attr), calls[kind]))
+    return be
+
+
+def _place_wave(be, strat, graph, machine, wave, n_mems):
+    """One activation of ``wave``, with residency seeded over ``n_mems``
+    memories; returns the cost matrix the DADA program left on the device."""
+    strat._backend, strat._backend_resolved = be, True
+    sim = Simulator(graph, machine, strat, seed=0)
+    for k, name in enumerate(sim.arrays.data_names):
+        if k % 3 == 0:
+            sim.residency.write(name, k % n_mems)
+    sim.push = lambda task, rid: None
+    kept = []
+    score = be.score_matrices
+
+    def keeping(*args, **kw):
+        out = score(*args, **kw)
+        kept.append(out["C_dev"])
+        return out
+
+    be.score_matrices = keeping
+    try:
+        strat.place(sim, wave, None)
+    finally:
+        del be.score_matrices
+    return kept[0] if kept else None
+
+
+def _standalone_search(be, resources, C_dev):
+    """The standalone λ search, as the benchmark's set-up warms it: one
+    affinity chain as long as the rows."""
+    n_pad, n_res = C_dev.shape
+    gpu = next(j for j, r in enumerate(resources) if r.is_accelerator)
+    be.dada_lambda_search(
+        n=n_pad, n_res=n_res, offsets=[0.0] * n_res, C_dev=C_dev,
+        p_cpu=[1.0] * n_pad, p_gpu=[1.0] * n_pad,
+        by_score=[(1.0, i, gpu, 1.0) for i in range(n_pad)],
+        tid_index={i: i for i in range(n_pad)}, flex_order=list(range(n_pad)),
+        resources=resources, have_both=True, no_cpus=False, no_gpus=False,
+        alpha=0.5, area_bound=False, area=0.0, off_total=0.0, max_off=0.0,
+        eps_rel=0.01, max_iters=30, upper0=2.0 * n_pad)
+
+
 @pytest.fixture(scope="module")
 def backend_calls():
     """One wide activation of DADA+cp and of HEFT on the CPU, with the
-    backend's jitted functions and their real arguments recorded."""
+    backend's jitted functions and their real arguments recorded, and the
+    standalone λ search over the DADA program's cost matrix."""
     graph = cholesky_graph(NT, TILE, with_fns=False)
     machine = scaled_machine()
     wave = _wide_wave(graph)
-    be = JaxScoringBackend(SchedConfig(backend="jax", lambda_depth=5))
-    be.f64 = f64.for_platform("tpu")  # the chip has no IEEE f64
-    calls = {"matrix": [], "search": [], "heft": []}
-    be._build_matrix_fn = _recording(be._build_matrix_fn, calls["matrix"])
-    be._build_search_fn = _recording(be._build_search_fn, calls["search"])
-    be._build_heft_fn = _recording(be._build_heft_fn, calls["heft"])
-    for strat in (DADA(alpha=0.5, use_cp=True, backend="jax"), HEFT(backend="jax")):
-        strat._backend, strat._backend_resolved = be, True
-        sim = Simulator(graph, machine, strat, seed=0)
-        for k, name in enumerate(sim.arrays.data_names):
-            if k % 3 == 0:
-                sim.residency.write(name, k % 24)
-        sim.push = lambda task, rid: None
-        strat.place(sim, wave, None)
+    calls = {"matrix": [], "fused": [], "search": [], "heft": []}
+    be = _recorded_backend(calls)
+    C_dev = _place_wave(be, DADA(alpha=0.5, use_cp=True, backend="jax"), graph,
+                        machine, wave, 24)
+    _place_wave(be, HEFT(backend="jax"), graph, machine, wave, 24)
+    _standalone_search(be, machine.resources, C_dev)
     assert len(wave) >= 256 and all(calls.values()), (len(wave), calls.keys())
     return calls
 
 
 @pytest.fixture(scope="module")
 def peer_calls():
-    """One 31-wide activation of DADA+cp on the DGX A100 deployment (128
-    resources, NVSwitch peers): the score program with the fabric's fold
-    and the λ search at 128 columns, recorded as for ``backend_calls``."""
+    """One 31-wide activation of DADA+cp and of HEFT on the DGX A100
+    deployment (128 resources, NVSwitch peers): the score programs with
+    the fabric's fold at 128 columns, and the standalone λ search, recorded
+    as for ``backend_calls``."""
     from repro.configs.dgx_a100 import dgx_a100
 
     graph = cholesky_graph(NT, 1024, itemsize=8, with_fns=False)
     wave = _wide_wave(graph)[:NT - 1]
-    be = JaxScoringBackend(SchedConfig(backend="jax", lambda_depth=5, jax_min=8))
-    be.f64 = f64.for_platform("tpu")
-    calls = {"matrix": [], "search": []}
-    be._build_matrix_fn = _recording(be._build_matrix_fn, calls["matrix"])
-    be._build_search_fn = _recording(be._build_search_fn, calls["search"])
-    strat = DADA(alpha=0.5, use_cp=True, backend="jax")
-    strat._backend, strat._backend_resolved = be, True
-    sim = Simulator(graph, dgx_a100(), strat, seed=0)
-    for k, name in enumerate(sim.arrays.data_names):
-        if k % 3 == 0:
-            sim.residency.write(name, k % 8)
-    sim.push = lambda task, rid: None
-    strat.place(sim, wave, None)
+    machine = dgx_a100()
+    calls = {"matrix": [], "fused": [], "search": []}
+    be = _recorded_backend(calls, jax_min=8)
+    C_dev = _place_wave(be, DADA(alpha=0.5, use_cp=True, backend="jax"), graph,
+                        machine, wave, 8)
+    _place_wave(be, HEFT(backend="jax"), graph, machine, wave, 8)
+    _standalone_search(be, machine.resources, C_dev)
     assert all(calls.values())
+    assert calls["fused"][0][0][11], "the DADA program took no fabric"
     assert calls["matrix"][0][0][-1], "the score program took no fabric"
     return calls
 
 
-@pytest.mark.parametrize("kind", ["matrix", "search", "heft", "peer_matrix", "peer_search"])
+@pytest.mark.parametrize("kind", ["matrix", "search", "heft", "peer_matrix", "peer_search",
+                                  "fused", "peer_fused"])
 def test_backend_functions_compile(one_chip, request, kind):
-    """The score-matrix, λ-search (depth 5, the TPU default) and HEFT EFT
+    """The score-matrix, one-dispatch DADA (scores, affinity order and λ
+    search), standalone λ-search (depth 5, the TPU default) and HEFT EFT
     programs in the chip's integer-exact f64, at a phase-(a) width; the
-    score and search programs of the DGX A100 deployment at 128 columns."""
+    score, one-dispatch and search programs of the DGX A100 deployment at
+    128 columns."""
     if kind.startswith("peer_"):
         calls = request.getfixturevalue("peer_calls")[kind[5:]]
     else:
