@@ -5,12 +5,13 @@ with different seeds; we report mean and 95% CI of GFLOPS and total
 transferred GB. Matrix 8192x8192, tile 512 (16x16 tiles), inner block 128,
 fp64 item size — the paper's exact problem shape.
 
-Environment knobs:
-  REPRO_BENCH_RUNS   repetitions per configuration (default 30, paper-level)
-  REPRO_BENCH_GPUS   comma list of GPU counts       (default 1..8)
-  REPRO_BENCH_FAST   =1 shrinks to 3 runs x {2,4,8} GPUs for smoke use
-  REPRO_BENCH_JOBS   process-pool width for the seeded repetitions
-                     (default: CPU count; 1 forces the serial path)
+Environment knobs, parsed once into :class:`BenchConfig`:
+  REPRO_BENCH_RUNS        repetitions per configuration (default 30, paper-level)
+  REPRO_BENCH_GPUS        comma list of GPU counts       (default 1..8)
+  REPRO_BENCH_FAST        =1 shrinks to 3 runs x {2,4,8} GPUs for smoke use
+  REPRO_BENCH_JOBS        process-pool width for the seeded repetitions
+                          (default: CPU count; 1 forces the serial path)
+  REPRO_BENCH_ALLOW_FAIL  =1 exits 0 when a claim fails
 
 Factories are ``functools.partial`` over module-level callables (not
 lambdas) so ``run_many`` can ship them to its process pool.
@@ -18,16 +19,21 @@ lambdas) so ``run_many`` can ship them to its process pool.
 from __future__ import annotations
 
 import csv
+import os
+from dataclasses import dataclass
 from functools import partial
 from pathlib import Path
-from typing import Callable, Dict, List
+from typing import Callable, Dict, List, Mapping, Optional, Tuple
 
 from repro.configs.paper_machine import paper_machine
 from repro.core import Summary, default_jobs, get_pool, pool_allowed, run_many
 from repro.linalg.cholesky import cholesky_graph
 from repro.sched import resolve
+from repro.sched.config import _parse_flag, _parse_int
 from repro.linalg.lu import lu_graph
 from repro.linalg.qr import qr_graph
+
+BENCH_PREFIX = "REPRO_BENCH_"
 
 MATRIX = 8192
 TILE = 512
@@ -61,10 +67,10 @@ def machine_for(n_gpus: int, n_cpus: int = None):
 def update_bench_json(section: str, payload) -> Path:
     """Merge one section into ``results/BENCH_sched.json``.
 
-    The file tracks the scheduler-performance trajectory across PRs
-    (events/sec per strategy and backend, wall times, λ-probe latencies);
-    each producing script owns one top-level key so ``sched_overhead.py``
-    and ``paper_validation.py`` can update it independently.
+    The file records each script's last run (claims, figure rows,
+    serving rows); each producing script owns one top-level key so
+    ``paper_validation.py``, ``scenario_matrix.py`` and
+    ``serving_load.py`` can update it independently.
     """
     import json
 
@@ -88,18 +94,65 @@ def update_bench_json(section: str, payload) -> Path:
     return BENCH_JSON
 
 
-def bench_settings():
-    """(runs, gpu_counts) from the validated ``SchedConfig`` (one parse
-    for every ``REPRO_BENCH_*`` knob; malformed values fail loudly there)."""
-    from repro.sched import current_config
+def _parse_int_list(var: str, value: str) -> Tuple[int, ...]:
+    # empty entries allowed: REPRO_BENCH_GPUS="" is an empty sweep
+    return tuple(_parse_int(var, p.strip(), lo=0) for p in value.split(",") if p.strip())
 
-    cfg = current_config()
-    runs = cfg.bench_runs if cfg.bench_runs is not None else (3 if cfg.bench_fast else 30)
-    if cfg.bench_gpus is not None:
-        gpus = list(cfg.bench_gpus)
+
+@dataclass(frozen=True)
+class BenchConfig:
+    """The benchmark scripts' ``REPRO_BENCH_*`` knobs, parsed and validated
+    once. ``None`` means "unset" where a script's default depends on other
+    knobs (runs defaults to 3 under ``fast``, else 30 for the figures)."""
+
+    fast: bool = False
+    runs: Optional[int] = None
+    gpus: Optional[Tuple[int, ...]] = None
+    jobs: Optional[int] = None
+    allow_fail: bool = False
+
+    @classmethod
+    def from_env(cls, env: Optional[Mapping[str, str]] = None) -> "BenchConfig":
+        """Parse ``REPRO_BENCH_*``; a malformed value or an unknown name
+        raises ``ValueError`` naming the variable."""
+        env = os.environ if env is None else env
+        kw = {}
+        unknown = []
+        for var, raw in env.items():
+            if not var.startswith(BENCH_PREFIX):
+                continue
+            spec = _BENCH_ENV.get(var)
+            if spec is None:
+                unknown.append(var)
+                continue
+            field_name, parse = spec
+            kw[field_name] = parse(var, raw)
+        if unknown:
+            raise ValueError(
+                "unknown benchmark configuration variable(s): "
+                f"{', '.join(sorted(unknown))} (known: {', '.join(sorted(_BENCH_ENV))})"
+            )
+        return cls(**kw)
+
+
+_BENCH_ENV = {
+    "REPRO_BENCH_FAST": ("fast", _parse_flag),
+    "REPRO_BENCH_RUNS": ("runs", lambda var, v: _parse_int(var, v, lo=1)),
+    "REPRO_BENCH_GPUS": ("gpus", _parse_int_list),
+    "REPRO_BENCH_JOBS": ("jobs", lambda var, v: _parse_int(var, v, lo=1)),
+    "REPRO_BENCH_ALLOW_FAIL": ("allow_fail", _parse_flag),
+}
+
+
+def bench_settings() -> Tuple[int, List[int], Optional[int]]:
+    """(runs, gpu_counts, jobs) of the figure sweeps, from the environment."""
+    cfg = BenchConfig.from_env()
+    runs = cfg.runs if cfg.runs is not None else (3 if cfg.fast else 30)
+    if cfg.gpus is not None:
+        gpus = list(cfg.gpus)
     else:
-        gpus = [2, 4, 8] if cfg.bench_fast else [1, 2, 3, 4, 5, 6, 7, 8]
-    return runs, gpus
+        gpus = [2, 4, 8] if cfg.fast else [1, 2, 3, 4, 5, 6, 7, 8]
+    return runs, gpus, cfg.jobs
 
 
 # one code path for every consumer: specs resolved through the policy
@@ -187,14 +240,17 @@ def sweep(
     strategies: Dict[str, Callable],
     n_runs: int,
     gpu_counts: List[int],
+    jobs: Optional[int] = None,
 ) -> List[dict]:
     """Run strategies x gpu-counts; persist CSV; return row dicts.
 
     Configurations fan out over the shared process pool (one pool task per
     strategy × GPU-count, each running its seeded repetitions serially —
     coarser tasks than per-seed fan-out, so 2 workers stay busy end to
-    end). Each configuration is independently seeded, so results are
-    bit-identical to the serial loop and are gathered in sweep order.
+    end). ``jobs`` (``REPRO_BENCH_JOBS``) sets the pool's width; unset, it
+    is the CPU count. Each configuration is independently seeded, so
+    results are bit-identical to the serial loop and are gathered in sweep
+    order.
 
     An empty sweep (no strategies or no GPU counts, e.g. an empty
     ``REPRO_BENCH_GPUS``) returns ``[]`` with a warning instead of
@@ -224,7 +280,7 @@ def sweep(
     summaries: List[Summary] = (
         _sweep_batched(configs, graph_factory, n_runs) if batched else []
     )
-    n_jobs = default_jobs(len(configs))
+    n_jobs = jobs if jobs is not None else default_jobs(len(configs))
     futs = None
     if (
         not batched and n_jobs > 1 and len(configs) > 1
@@ -234,7 +290,7 @@ def sweep(
             import pickle
 
             pickle.dumps([sfac for _, _, sfac in configs] + [graph_factory])
-            pool = get_pool(n_jobs)
+            pool = get_pool(jobs)
             futs = [
                 pool.submit(
                     _sweep_config, graph_factory, paper_machine(n_gpus), sfac, n_runs

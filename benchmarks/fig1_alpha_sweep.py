@@ -13,7 +13,7 @@ ALPHAS = [0.0, 0.25, 0.5, 0.75, 1.0]
 
 
 def main() -> list:
-    runs, gpus = bench_settings()
+    runs, gpus, jobs = bench_settings()
     strategies = {}
     for a in ALPHAS:
         strategies[f"dada({a:g})"] = partial(resolve, f"dada?alpha={a:g}")
@@ -21,7 +21,7 @@ def main() -> list:
         strategies[f"dada({a:g})+cp"] = partial(
             resolve, f"dada?alpha={a:g}&use_cp=1"
         )
-    rows = sweep("fig1_alpha_sweep", "cholesky", strategies, runs, gpus)
+    rows = sweep("fig1_alpha_sweep", "cholesky", strategies, runs, gpus, jobs)
     emit_csv_lines(rows)
     return rows
 

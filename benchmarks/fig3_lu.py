@@ -8,8 +8,8 @@ from .common import STRATEGIES, bench_settings, emit_csv_lines, sweep
 
 
 def main() -> list:
-    runs, gpus = bench_settings()
-    rows = sweep("fig3_lu", "lu", STRATEGIES, runs, gpus)
+    runs, gpus, jobs = bench_settings()
+    rows = sweep("fig3_lu", "lu", STRATEGIES, runs, gpus, jobs)
     emit_csv_lines(rows)
     return rows
 

@@ -6,8 +6,8 @@ from .common import STRATEGIES, bench_settings, emit_csv_lines, sweep
 
 
 def main() -> list:
-    runs, gpus = bench_settings()
-    rows = sweep("fig4_qr", "qr", STRATEGIES, runs, gpus)
+    runs, gpus, jobs = bench_settings()
+    rows = sweep("fig4_qr", "qr", STRATEGIES, runs, gpus, jobs)
     emit_csv_lines(rows)
     return rows
 

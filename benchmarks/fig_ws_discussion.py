@@ -18,7 +18,7 @@ from .common import bench_settings
 
 
 def main() -> list:
-    runs, _ = bench_settings()
+    runs, _, jobs = bench_settings()
     machine = paper_machine(4)
     rows = []
     for n in (2048, 4096, 8192, 16384):
@@ -30,7 +30,7 @@ def main() -> list:
         ]:
             s = run_many(
                 partial(cholesky_graph, nt, 512, with_fns=False),
-                machine, fac, n_runs=max(3, runs // 3),
+                machine, fac, n_runs=max(3, runs // 3), n_jobs=jobs,
             )
             rows.append(dict(
                 n=n, strategy=label, gflops=round(s.gflops_mean, 1),

@@ -13,7 +13,7 @@ from __future__ import annotations
 import sys
 from functools import partial
 from pathlib import Path
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 if __package__ in (None, ""):  # `python benchmarks/paper_validation.py`
     _repo = Path(__file__).resolve().parents[1]
@@ -44,13 +44,16 @@ def _verify_sim(sim) -> int | None:
     return len(errors(verify_audit(sim.audit)))
 
 
-def validate(fig1: List[dict], fig2: List[dict], fig3: List[dict], fig4: List[dict], n_runs: int = 10) -> List[dict]:
+def validate(
+    fig1: List[dict], fig2: List[dict], fig3: List[dict], fig4: List[dict],
+    n_runs: int = 10, jobs: Optional[int] = None,
+) -> List[dict]:
     checks: List[dict] = []
     if not (fig1 and fig2 and fig3 and fig4):
         # empty sweeps (e.g. REPRO_BENCH_GPUS=""): nothing to validate
         # against; C6 below runs its own simulations, so keep only that
         print("  (figure sweeps empty — skipping row-based claims C1-C5)")
-        return _validate_c6(checks, n_runs)
+        return _validate_c6(checks, n_runs, jobs)
     gpus = sorted({r["n_gpus"] for r in fig1})
     lo, hi = gpus[0], gpus[-1]
 
@@ -119,10 +122,10 @@ def validate(fig1: List[dict], fig2: List[dict], fig3: List[dict], fig4: List[di
         )
     )
 
-    return _validate_c6(checks, n_runs)
+    return _validate_c6(checks, n_runs, jobs)
 
 
-def _validate_c6(checks: List[dict], n_runs: int) -> List[dict]:
+def _validate_c6(checks: List[dict], n_runs: int, jobs: Optional[int]) -> List[dict]:
     # C6 — work stealing is cache-unfriendly on small matrices -------------
     machine = paper_machine(4)
     small = partial(cholesky_graph, 8, 512, with_fns=False)  # 4096^2
@@ -130,10 +133,11 @@ def _validate_c6(checks: List[dict], n_runs: int) -> List[dict]:
 
     with ThreadPoolExecutor(max_workers=2) as tp:
         ws_f = tp.submit(
-            run_many, small, machine, partial(resolve, "ws"), n_runs
+            run_many, small, machine, partial(resolve, "ws"), n_runs, n_jobs=jobs
         )
         da_f = tp.submit(
-            run_many, small, machine, partial(resolve, "dada?alpha=0.5"), n_runs
+            run_many, small, machine, partial(resolve, "dada?alpha=0.5"), n_runs,
+            n_jobs=jobs,
         )
         ws, da = ws_f.result(), da_f.result()
     checks.append(
@@ -375,23 +379,25 @@ def main() -> bool:
 
     from repro.core import get_pool
 
+    from benchmarks.common import BenchConfig
+
+    bench = BenchConfig.from_env()
     t0 = time.perf_counter()
     # create the shared process pool from the main thread, before any sweep
     # threads exist (fork-after-threads can deadlock forked children)
-    get_pool()
+    get_pool(bench.jobs)
     mods = [
         importlib.import_module(f"benchmarks.{m}")
         for m in ("fig1_alpha_sweep", "fig2_cholesky", "fig3_lu", "fig4_qr")
     ]
     with ThreadPoolExecutor(max_workers=len(mods)) as tp:
         figs = [f.result() for f in [tp.submit(m.main) for m in mods]]
-    checks = validate(*figs)
+    checks = validate(*figs, jobs=bench.jobs)
     ok = print_checks(checks)
     wall = time.perf_counter() - t0
     print(f"\ntotal wall-clock: {wall:.2f}s")
 
-    # record the run in the machine-readable perf trajectory (satellite of
-    # the scheduler-throughput tracking; see benchmarks/README.md)
+    # record the run in results/BENCH_sched.json (see benchmarks/README.md)
     from repro.sched import current_config
 
     from benchmarks.common import update_bench_json
@@ -405,7 +411,7 @@ def main() -> bool:
         dict(
             wall_s=round(wall, 2),
             backend=cfg.backend,
-            fast=cfg.bench_fast,
+            fast=bench.fast,
             exact=cfg.exact,
             claims=[
                 dict(claim=c["claim"], passed=bool(c["passed"]),
@@ -427,7 +433,7 @@ if __name__ == "__main__":
         print("WARNING: some paper claims did not reproduce — see above", file=sys.stderr)
         # gate CI on claim regressions; REPRO_BENCH_ALLOW_FAIL=1 opts out
         # (e.g. deliberately tiny smoke configurations on noisy runners)
-        from repro.sched import current_config
+        from benchmarks.common import BenchConfig
 
-        if not current_config().bench_allow_fail:
+        if not BenchConfig.from_env().allow_fail:
             sys.exit(1)

@@ -11,6 +11,7 @@ import sys
 
 def main() -> None:
     from . import fig1_alpha_sweep, fig2_cholesky, fig3_lu, fig4_qr, fig_ws_discussion
+    from .common import BenchConfig
     from .paper_validation import print_checks, validate
 
     print("name,us_per_call,derived")
@@ -20,7 +21,7 @@ def main() -> None:
     f4 = fig4_qr.main()
     print("== §4.3 work-stealing discussion ==")
     fig_ws_discussion.main()
-    ok = print_checks(validate(f1, f2, f3, f4))
+    ok = print_checks(validate(f1, f2, f3, f4, jobs=BenchConfig.from_env().jobs))
     if not ok:
         print("WARNING: some paper claims did not reproduce — see above", file=sys.stderr)
 

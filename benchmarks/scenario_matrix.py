@@ -57,9 +57,9 @@ from repro.configs.paper_machine import paper_machine
 from repro.core import Simulator
 from repro.linalg.lu import lu_graph
 from repro.runtime import recovery_report
-from repro.sched import current_config, resolve
+from repro.sched import resolve
 
-from benchmarks.common import RESULTS_DIR, update_bench_json
+from benchmarks.common import RESULTS_DIR, BenchConfig, update_bench_json
 
 # matrix axes: a light and a heavy churn regime (events per unit sim
 # time over a ~0.1 s trace: a few vs dozens of detach cycles), both
@@ -77,11 +77,11 @@ N_BOOT = 2000
 
 def _settings() -> Tuple[int, int, Tuple[float, ...]]:
     """(n_seeds, nt, churn_levels) honouring the bench knobs."""
-    cfg = current_config()
-    if cfg.bench_fast:
-        runs = cfg.bench_runs if cfg.bench_runs is not None else 3
+    cfg = BenchConfig.from_env()
+    if cfg.fast:
+        runs = cfg.runs if cfg.runs is not None else 3
         return runs, 6, (250.0,)
-    runs = cfg.bench_runs if cfg.bench_runs is not None else 20
+    runs = cfg.runs if cfg.runs is not None else 20
     return runs, 12, (40.0, 150.0)
 
 
@@ -271,7 +271,7 @@ def main() -> int:
         ok = ok and c["passed"]
         print(f"  [{status}] {c['claim']}\n         measured: {c['measured']}")
     print(f"\ntotal wall-clock {time.perf_counter() - t0:.1f}s -> {out_csv}")
-    if not ok and not current_config().bench_allow_fail:
+    if not ok and not BenchConfig.from_env().allow_fail:
         return 1
     return 0
 

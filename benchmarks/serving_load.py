@@ -2,25 +2,33 @@
 
 Sweeps tenant count × arrival process × strategy over the mixed graph
 catalog (``repro.runtime.load.default_catalog``), driving every
-configuration through ``run_serving`` with incremental rescoring — the
-serving hot path this benchmark regression-gates.  Each row reports
+configuration through ``run_serving`` with incremental rescoring.  Each
+row reports
 
-  * engine throughput (events/sec, wall seconds, rows built), and
+  * rows built, and events/sec and wall seconds (host time: printed as
+    information, never gated), and
   * tenant-visible tails — p50/p99 makespan and slowdown vs the
     empty-machine baseline, queueing delay, Jain fairness — plus the
     admission counters,
 
-into the ``serving`` section of ``results/BENCH_sched.json`` (consumed by
-``check_sched_regression.py``).
+into the ``serving`` section of ``results/BENCH_sched.json``.
 
-The **speedup probe** is the headline: at 256 tenants the
-same arrival stream is replayed twice in this one process — once with
-``rescore="full"`` (rebuild every row, every round: the naive O(R·M)
-baseline) and once with ``rescore="incremental"`` (dirty rows only) —
-both capped at the same event count, so the events/sec ratio isolates
-the scoring work the incremental cache elides.  The two modes place
-bit-for-bit identically (tests/test_load_property.py pins this), so the
-ratio is pure overhead, not a schedule change.
+The **speedup probe** replays one arrival stream at 256 tenants twice in
+this process — once with ``rescore="full"`` (rebuild every row, every
+round: the naive O(R·M) baseline) and once with
+``rescore="incremental"`` (dirty rows only) — both capped at the same
+event count.  The two modes place bit-for-bit identically
+(tests/test_load_property.py pins this), so the ratio of rows built is
+the scoring work the incremental cache elides.
+
+Two gates judge decisions, not host time, and the script exits 1 when
+either fails:
+
+  * each row's p99 slowdown — simulated time, so deterministic — may not
+    grow past ``P99_SLOWDOWN_TOL`` over its committed value in
+    ``results/serving_p99_baseline.json``;
+  * the probe's full/incremental ``rows_built`` ratio must clear
+    ``SPEEDUP_FLOOR`` (a cache that rebuilds every row reads ≈1).
 
 Knobs: REPRO_BENCH_FAST=1 drops the 1024-tenant column.  The arrival
 rate is fixed at 2000 arrivals/sec — deep open-loop backlog at every
@@ -29,6 +37,7 @@ gets measured.
 """
 from __future__ import annotations
 
+import json
 import sys
 import time
 from pathlib import Path
@@ -39,8 +48,7 @@ if __package__ in (None, ""):
         if p not in sys.path:
             sys.path.insert(0, p)
 
-from benchmarks.common import update_bench_json
-from benchmarks.sched_overhead import calibration_score
+from benchmarks.common import RESULTS_DIR, BenchConfig, update_bench_json
 
 TENANTS_FULL = (16, 64, 256, 1024)
 TENANTS_FAST = (16, 64, 256)
@@ -62,6 +70,14 @@ PROBE_EVENTS = 4000
 # the ready pool fits the cache's sweet spot — at 1024 tenants the pool
 # itself (heap churn, dirty fan-out) eats into the win (≈2× vs ≈9×)
 PROBE_TENANTS = 256
+# the committed p99 slowdown of every (tenants, arrival, strategy) row
+P99_BASELINE = RESULTS_DIR / "serving_p99_baseline.json"
+# a row fails when its p99 slowdown exceeds the committed one by this much
+P99_SLOWDOWN_TOL = 0.25
+# floor on the probe's full/incremental rows_built ratio: deterministic,
+# so host noise cannot fail it, while a cache that rebuilds every row
+# (ratio ≈ 1) always does
+SPEEDUP_FLOOR = 1.5
 
 
 def serving_rows(tenant_counts, rate: float) -> list:
@@ -123,8 +139,8 @@ def serving_rows(tenant_counts, rate: float) -> list:
 
 
 def speedup_probe(tenants: int, rate: float) -> dict:
-    """Full-rescore vs incremental events/sec on the same arrival stream,
-    same process, same event cap — the incremental-rescoring headline."""
+    """Full-rescore vs incremental on the same arrival stream, same
+    process, same event cap: rows built (gated) and events/sec."""
     from repro.configs.paper_machine import paper_machine
     from repro.runtime.load import make_arrivals, run_serving
 
@@ -149,41 +165,74 @@ def speedup_probe(tenants: int, rate: float) -> dict:
     full_ev = probe["full"]["events_per_s"]
     incr_ev = probe["incremental"]["events_per_s"]
     speedup = round(incr_ev / full_ev, 2) if full_ev > 0 else 0.0
+    full_rows = probe["full"]["rows_built"]
+    incr_rows = probe["incremental"]["rows_built"]
+    rows_ratio = full_rows / max(incr_rows, 1)
     result = dict(
         tenants=tenants, arrival="poisson", strategy="heft",
         max_events=PROBE_EVENTS, rate=rate,
         full=probe["full"], incremental=probe["incremental"],
-        speedup=speedup,
+        speedup=speedup, rows_ratio=round(rows_ratio, 2),
     )
     print(
         f"serving/speedup/tenants{tenants},"
         f"{probe['incremental']['wall_s'] * 1e6:.1f},"
+        f"rows_built={full_rows}/{incr_rows};rows_ratio={rows_ratio:.2f}x;"
         f"incremental={incr_ev};full={full_ev};speedup={speedup}x"
     )
     return result
 
 
-def main() -> dict:
-    from repro.sched import current_config
+def gate(rows: list, probe: dict, baseline: list) -> bool:
+    """The decision gates: each row's p99 slowdown against ``baseline``
+    (rows absent from either side are skipped) and the probe's
+    rows-built floor. True when both hold."""
+    committed = {
+        (b["tenants"], b["arrival"], b["strategy"]): b["p99_slowdown"]
+        for b in baseline
+    }
+    ok = True
+    for row in rows:
+        key = (row["tenants"], row["arrival"], row["strategy"])
+        base = committed.get(key)
+        if base is None:
+            continue
+        if row["p99_slowdown"] > base * (1.0 + P99_SLOWDOWN_TOL):
+            ok = False
+            print(
+                f"  [FAIL] serving p99 slowdown {'/'.join(map(str, key))}: "
+                f"{row['p99_slowdown']:.2f} vs committed {base:.2f} "
+                f"(limit +{P99_SLOWDOWN_TOL:.0%})"
+            )
+    ratio = probe["rows_ratio"]
+    mark = "ok  " if ratio >= SPEEDUP_FLOOR else "FAIL"
+    print(
+        f"  [{mark}] incremental rescoring builds {ratio:.2f}x fewer rows "
+        f"than full at {probe['tenants']} tenants (floor {SPEEDUP_FLOOR:.1f}x)"
+    )
+    return ok and ratio >= SPEEDUP_FLOOR
 
-    cfg = current_config()
-    fast = cfg.bench_fast
+
+def main() -> int:
+    fast = BenchConfig.from_env().fast
     tenant_counts = list(TENANTS_FAST if fast else TENANTS_FULL)
     rate = DEFAULT_RATE
 
     print("name,us_per_call,derived")
     rows = serving_rows(tenant_counts, rate)
     probe = speedup_probe(PROBE_TENANTS, rate)
-    payload = dict(
+    update_bench_json("serving", dict(
         config=dict(tenants=tenant_counts, arrivals=list(ARRIVALS),
                     strategies=list(STRATEGY_LABELS.values()), rate=rate),
-        calibration_score=round(calibration_score(), 2),
         rows=rows,
         speedup=probe,
-    )
-    update_bench_json("serving", payload)
-    return payload
+    ))
+    if not gate(rows, probe, json.loads(P99_BASELINE.read_text())):
+        print("serving gate FAILED")
+        return 1
+    print("serving gate passed")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

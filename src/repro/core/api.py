@@ -171,16 +171,9 @@ def _run_chunk(
     return out
 
 
-def default_jobs(n_runs: int, config=None) -> int:
-    """Worker count for run_many: REPRO_BENCH_JOBS (via SchedConfig),
-    else min(cpus, runs). A malformed value raises at config parse time
-    (``SchedConfig.from_env``) instead of silently using the CPU count."""
-    if config is None:
-        from repro.sched.config import current_config
-
-        config = current_config()
-    if config.bench_jobs is not None:
-        return max(1, config.bench_jobs)
+def default_jobs(n_runs: int) -> int:
+    """Worker count for ``n_runs`` runs when the caller names none:
+    min(cpus, runs)."""
     return max(1, min(os.cpu_count() or 1, n_runs))
 
 
@@ -190,26 +183,26 @@ _POOL_LOCK = threading.Lock()
 
 
 def get_pool(n_jobs: Optional[int] = None):
-    """Public handle on the shared simulation process pool.
+    """Public handle on the shared simulation process pool. The call that
+    creates it sets its width: ``n_jobs``, or the CPU count when ``None``.
 
     Creating it early — before spawning any threads that will submit to
     it — also sidesteps the fork-after-threads hazard (forking workers
     while sibling threads hold allocator/stdio locks can deadlock the
     children on some platforms).
     """
-    if n_jobs is None:
-        n_jobs = default_jobs(os.cpu_count() or 1)
     return _get_pool(n_jobs)
 
 
-def _get_pool(n_jobs: int):
+def _get_pool(n_jobs: Optional[int]):
     """Lazily build (and reuse) one process pool; fork context when available
     so repeated run_many calls don't pay per-call interpreter startup.
 
     Thread-safe: concurrent sweeps share the same executor. The pool is
-    sized once, at first use, from REPRO_BENCH_JOBS (or the CPU count) —
-    it is never resized or shut down afterwards, because cancelling would
-    kill in-flight futures belonging to other threads."""
+    sized once, at first use: ``n_jobs`` workers where the caller names a
+    width, else the CPU count. It is never resized or shut down
+    afterwards, because cancelling would kill in-flight futures belonging
+    to other threads."""
     global _POOL, _POOL_JOBS
     with _POOL_LOCK:
         if _POOL is not None:
@@ -220,9 +213,9 @@ def _get_pool(n_jobs: int):
         ctx = None
         if "fork" in mp.get_all_start_methods():
             ctx = mp.get_context("fork")
-        # stable width independent of any one call's n_jobs, so the first
-        # caller doesn't pin concurrent sweeps to an undersized pool
-        workers = max(n_jobs, default_jobs(os.cpu_count() or 1))
+        # a caller that names no width gets the CPU count, so a small
+        # first call doesn't pin concurrent sweeps to an undersized pool
+        workers = n_jobs if n_jobs is not None else default_jobs(os.cpu_count() or 1)
         _POOL = cf.ProcessPoolExecutor(max_workers=workers, mp_context=ctx)
         _POOL_JOBS = workers
         return _POOL
@@ -261,14 +254,15 @@ def run_many(
     ``graph_factory`` and ``strategy_factory`` are callables so each run gets
     fresh graph/strategy state (the history model calibrates within a run).
 
-    Runs fan out over a process pool (``n_jobs`` workers; default from
-    ``REPRO_BENCH_JOBS`` or the CPU count). Each run is independently
+    Runs fan out over a process pool (``n_jobs`` workers; default
+    min(CPU count, ``n_runs``)). Each run is independently
     seeded, so the summary is bit-identical to the serial path regardless
     of worker count; results are gathered in seed order. Falls back to the
     serial loop when ``n_jobs == 1``, when the factories are not picklable
     (e.g. test-local closures), when the pool cannot be created, or when
     :func:`pool_allowed` keeps JAX work in this process.
     """
+    width = n_jobs  # the pool's width, where this call is the first
     if n_jobs is None:
         n_jobs = default_jobs(n_runs)
     seeds = [base_seed + i for i in range(n_runs)]
@@ -282,7 +276,7 @@ def run_many(
         chunks = [seeds[a:b] for a, b in zip(bounds, bounds[1:]) if b > a]
         try:
             pickle.dumps((graph_factory, machine, strategy_factory))
-            pool = _get_pool(n_jobs)
+            pool = _get_pool(width)
             futs = [
                 pool.submit(_run_chunk, graph_factory, machine, strategy_factory, c, noise)
                 for c in chunks

@@ -200,8 +200,8 @@ def jain_fairness(values: List[float]) -> float:
 
 def serving_report(tenants: List[Dict[str, float]]) -> Dict[str, float]:
     """Aggregate per-tenant serving rows (``repro.runtime.load.run_serving``)
-    into the p50/p99 + fairness summary benchmarks and BENCH_sched.json
-    consume.
+    into the p50/p99 + fairness summary that ``benchmarks/serving_load.py``
+    reports and gates on.
 
     Each row carries ``makespan``, ``slowdown`` (vs the tenant's
     empty-machine baseline) and ``queue_delay`` (first execution start

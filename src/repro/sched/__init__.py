@@ -11,8 +11,8 @@ extension surface here:
     ``resolve("dada?alpha=0.5&use_cp=1")`` replaces the old
     ``make_strategy`` if/elif ladder (which survives as a deprecated
     shim with bit-identical results);
-  * :class:`SchedConfig` — every ``REPRO_SCHED_*``/``REPRO_BENCH_*`` knob
-    parsed and validated in one place (:meth:`SchedConfig.from_env`),
+  * :class:`SchedConfig` — every ``REPRO_SCHED_*`` knob parsed and
+    validated in one place (:meth:`SchedConfig.from_env`),
     then threaded explicitly through the scheduling stack;
   * :func:`assign_from_scores` — the pure scores → assignment kernel,
     shared with ``repro.dist.sched_bridge``'s expert placement.

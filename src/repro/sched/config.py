@@ -1,14 +1,14 @@
 """Typed scheduling configuration — the single source of truth for every
-``REPRO_SCHED_*`` / ``REPRO_BENCH_*`` knob.
+``REPRO_SCHED_*`` knob.
 
-Before this module the knobs were parsed ad hoc at ~10 call sites
-(``backend.py`` read four env vars with silent fallbacks, the benchmark
-harness another six): a typo like ``REPRO_SCHED_LAMBDA_DEPTH=banana``
-silently became the platform default deep inside the jax backend.
-``SchedConfig.from_env()`` parses the whole environment once, validates
-every value, and rejects unknown ``REPRO_SCHED_*``/``REPRO_BENCH_*``
-variables with one clear error, so misconfiguration fails at the edge
-instead of deep in a hot path.
+Before this module the knobs were parsed ad hoc at several call sites
+(``backend.py`` read four env vars with silent fallbacks): a typo like
+``REPRO_SCHED_LAMBDA_DEPTH=banana`` silently became the platform default
+deep inside the jax backend. ``SchedConfig.from_env()`` parses the whole
+environment once, validates every value, and rejects unknown
+``REPRO_SCHED_*`` variables with one clear error, so misconfiguration
+fails at the edge instead of deep in a hot path. The benchmark scripts
+parse their own knobs (``benchmarks/common.py``).
 
 The frozen dataclass is then threaded explicitly through the scheduling
 stack (``repro.core.backend`` / ``dada`` / ``heft`` / ``Simulator``) —
@@ -25,7 +25,6 @@ from dataclasses import dataclass, fields
 from typing import Mapping, Optional, Tuple
 
 SCHED_PREFIX = "REPRO_SCHED_"
-BENCH_PREFIX = "REPRO_BENCH_"
 
 from repro.runtime.load import ADMISSION_MODES, ARRIVAL_PROCESSES
 from repro.runtime.memory import EVICTION_POLICIES
@@ -71,20 +70,6 @@ def _parse_flag(var: str, value: str) -> bool:
     raise _err(var, value, "expected 0 or 1")
 
 
-def _parse_int_list(var: str, value: str, lo: int = 0) -> Tuple[int, ...]:
-    out = []
-    for part in value.split(","):
-        part = part.strip()
-        if not part:
-            continue  # empty entries allowed: REPRO_BENCH_GPUS="" is an empty sweep
-        out.append(_parse_int(var, part, lo))
-    return tuple(out)
-
-
-def _parse_str_list(var: str, value: str) -> Tuple[str, ...]:
-    return tuple(p.strip() for p in value.split(",") if p.strip())
-
-
 def _parse_rate(var: str, value: str) -> float:
     rate = _parse_float(var, value)
     if rate < 0:
@@ -102,9 +87,7 @@ def _parse_trace_path(var: str, value: str) -> Optional[str]:
 
 @dataclass(frozen=True)
 class SchedConfig:
-    """Every scheduling/benchmark knob, parsed and validated once.
-
-    Scheduling (``REPRO_SCHED_*``):
+    """Every scheduling knob (``REPRO_SCHED_*``), parsed and validated once.
 
     - ``backend``: placement-scoring backend, ``numpy`` (default) or
       ``jax``; see ``repro.core.backend``.
@@ -146,8 +129,6 @@ class SchedConfig:
     - ``arrival``: open-loop arrival process for the serving load layer,
       ``poisson`` (default), ``bursty`` or ``diurnal``; consumed by
       ``repro.runtime.load.make_arrivals`` and the serving benchmark.
-    - ``tenants``: tenant count for serving runs (0 = the consumer's
-      default sweep; see ``benchmarks/serving_load.py``).
     - ``admission``: admission control at graph arrival, ``none``
       (default), ``reject`` (turn away tenants whose predicted working
       set exceeds free aggregate capacity) or ``defer`` (retry the
@@ -169,15 +150,8 @@ class SchedConfig:
     - ``batch``: per-dispatch batch-size cap for the surrogate engine
       (``api.run_batch`` splits larger sweeps into chunks of this many
       configurations).
-    - ``bench_backends``: backends the overhead benchmark measures.
-    - ``regression_tol`` / ``row_tol``: throughput-gate tolerances.
-
-    Benchmark harness (``REPRO_BENCH_*``): see ``benchmarks/common.py``;
-    ``None`` means "unset" where the consumer's default depends on other
-    knobs (e.g. runs defaults to 3 under ``bench_fast``, 30 otherwise).
     """
 
-    # --- scheduling ----------------------------------------------------
     backend: str = "numpy"
     jax_min: int = 32
     lambda_depth: Optional[int] = None
@@ -194,25 +168,11 @@ class SchedConfig:
     backoff_s: float = 1e-4
     exact: bool = True
     arrival: str = "poisson"
-    tenants: int = 0
     admission: str = "none"
     rescore: str = "off"
     admit_defer_s: float = 0.005
     audit: bool = False
     batch: int = 256
-    bench_backends: Optional[Tuple[str, ...]] = None
-    regression_tol: float = 0.25
-    row_tol: float = 0.0
-    # --- benchmark harness ---------------------------------------------
-    bench_fast: bool = False
-    bench_runs: Optional[int] = None
-    bench_gpus: Optional[Tuple[int, ...]] = None
-    bench_nt: Tuple[int, ...] = (16,)
-    bench_jobs: Optional[int] = None
-    bench_lambda: bool = True
-    bench_lambda_nt: int = 64
-    bench_lambda_reps: int = 3
-    bench_allow_fail: bool = False
 
     def __post_init__(self) -> None:
         if self.backend not in BACKENDS:
@@ -265,11 +225,6 @@ class SchedConfig:
                 "REPRO_SCHED_ARRIVAL", self.arrival,
                 f"choose from {ARRIVAL_PROCESSES}",
             )
-        if self.tenants < 0:
-            raise _err(
-                "REPRO_SCHED_TENANTS", str(self.tenants),
-                "expected an integer >= 0",
-            )
         if self.admission not in ADMISSION_MODES:
             raise _err(
                 "REPRO_SCHED_ADMISSION", self.admission,
@@ -305,15 +260,15 @@ class SchedConfig:
         """Parse (and validate) the environment into a ``SchedConfig``.
 
         Raises ``ValueError`` naming the offending variable for malformed
-        values *and* for unknown ``REPRO_SCHED_*``/``REPRO_BENCH_*``
-        variables — a typoed knob must not silently do nothing.
+        values *and* for unknown ``REPRO_SCHED_*`` variables — a typoed
+        knob must not silently do nothing.
         """
         if env is None:
             env = os.environ
         kw = {}
         unknown = []
         for var, raw in env.items():
-            if not (var.startswith(SCHED_PREFIX) or var.startswith(BENCH_PREFIX)):
+            if not var.startswith(SCHED_PREFIX):
                 continue
             spec = _ENV_SCHEMA.get(var)
             if spec is None:
@@ -338,9 +293,7 @@ class SchedConfig:
             if v == getattr(defaults, f.name):
                 continue
             var = _FIELD_TO_ENV[f.name]
-            if isinstance(v, tuple):
-                s = ",".join(str(x) for x in v)
-            elif isinstance(v, bool):
+            if isinstance(v, bool):
                 s = "1" if v else "0"
             else:
                 s = str(v)
@@ -368,28 +321,11 @@ _ENV_SCHEMA = {
     "REPRO_SCHED_BACKOFF_S": ("backoff_s", _parse_rate),
     "REPRO_SCHED_EXACT": ("exact", _parse_flag),
     "REPRO_SCHED_ARRIVAL": ("arrival", lambda var, v: v.lower()),
-    "REPRO_SCHED_TENANTS": (
-        "tenants", lambda var, v: _parse_int(var, v, lo=0)),
     "REPRO_SCHED_ADMISSION": ("admission", lambda var, v: v.lower()),
     "REPRO_SCHED_RESCORE": ("rescore", lambda var, v: v.lower()),
     "REPRO_SCHED_ADMIT_DEFER_S": ("admit_defer_s", _parse_rate),
     "REPRO_SCHED_AUDIT": ("audit", _parse_flag),
     "REPRO_SCHED_BATCH": ("batch", lambda var, v: _parse_int(var, v, lo=1)),
-    "REPRO_SCHED_BACKENDS": ("bench_backends", _parse_str_list),
-    "REPRO_SCHED_REGRESSION_TOL": ("regression_tol", _parse_float),
-    "REPRO_SCHED_ROW_TOL": (
-        "row_tol", lambda var, v: _parse_float(var, v) if v else 0.0),
-    "REPRO_BENCH_FAST": ("bench_fast", _parse_flag),
-    "REPRO_BENCH_RUNS": ("bench_runs", lambda var, v: _parse_int(var, v, lo=1)),
-    "REPRO_BENCH_GPUS": ("bench_gpus", _parse_int_list),
-    "REPRO_BENCH_NT": ("bench_nt", lambda var, v: _parse_int_list(var, v, lo=1)),
-    "REPRO_BENCH_JOBS": ("bench_jobs", lambda var, v: _parse_int(var, v, lo=1)),
-    "REPRO_BENCH_LAMBDA": ("bench_lambda", _parse_flag),
-    "REPRO_BENCH_LAMBDA_NT": (
-        "bench_lambda_nt", lambda var, v: _parse_int(var, v, lo=1)),
-    "REPRO_BENCH_LAMBDA_REPS": (
-        "bench_lambda_reps", lambda var, v: _parse_int(var, v, lo=1)),
-    "REPRO_BENCH_ALLOW_FAIL": ("bench_allow_fail", _parse_flag),
 }
 
 _FIELD_TO_ENV = {field: var for var, (field, _) in _ENV_SCHEMA.items()}
@@ -405,11 +341,7 @@ _CACHE: Optional[Tuple[Tuple[Tuple[str, str], ...], SchedConfig]] = None
 
 def _env_snapshot() -> Tuple[Tuple[str, str], ...]:
     return tuple(
-        sorted(
-            (k, v)
-            for k, v in os.environ.items()
-            if k.startswith(SCHED_PREFIX) or k.startswith(BENCH_PREFIX)
-        )
+        sorted((k, v) for k, v in os.environ.items() if k.startswith(SCHED_PREFIX))
     )
 
 

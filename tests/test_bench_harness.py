@@ -1,17 +1,19 @@
 """Benchmark-harness behavior: empty sweeps, parallel run_many equivalence,
-and the scheduler-overhead reporting contract."""
+the scripts' ``REPRO_BENCH_*`` configuration and the serving decision gates."""
+import sys
 from functools import partial
+from pathlib import Path
+
+import pytest
 
 from repro.configs.paper_machine import paper_machine
 from repro.core import DADA, run_many
 from repro.linalg.cholesky import cholesky_graph
 
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
 
 def test_sweep_empty_gpu_list_returns_no_rows(capsys):
-    import sys
-    from pathlib import Path
-
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
     from benchmarks.common import STRATEGIES, sweep
 
     rows = sweep("tmp_empty", "cholesky", STRATEGIES, 3, [])
@@ -43,99 +45,123 @@ def test_run_many_falls_back_on_unpicklable_factories():
     assert local["n"] >= 1  # ran in-process
 
 
-def test_sched_overhead_reports_events_per_sec(capsys, monkeypatch, tmp_path):
-    import json
-    import sys
-    from pathlib import Path
+def test_bench_config_parses_and_types():
+    from benchmarks.common import BenchConfig
 
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-    monkeypatch.setenv("REPRO_BENCH_GPUS", "2")
-    monkeypatch.setenv("REPRO_BENCH_RUNS", "1")
-    monkeypatch.setenv("REPRO_BENCH_LAMBDA", "0")  # skip the NT=64 micro
-    monkeypatch.setenv("REPRO_SCHED_BACKENDS", "numpy")
+    assert BenchConfig.from_env({}) == BenchConfig()
+    cfg = BenchConfig.from_env({
+        "REPRO_BENCH_FAST": "1",
+        "REPRO_BENCH_RUNS": "2",
+        "REPRO_BENCH_GPUS": "2, 8,",
+        "REPRO_BENCH_JOBS": "3",
+        "REPRO_BENCH_ALLOW_FAIL": "1",
+        "REPRO_SCHED_BACKEND": "jax",  # the library's, not the scripts'
+        "UNRELATED": "ignored",
+    })
+    assert cfg == BenchConfig(
+        fast=True, runs=2, gpus=(2, 8), jobs=3, allow_fail=True
+    )
+    # an empty GPU list is an empty sweep, not an error
+    assert BenchConfig.from_env({"REPRO_BENCH_GPUS": ""}).gpus == ()
+
+
+@pytest.mark.parametrize("var,bad", [
+    ("REPRO_BENCH_RUNS", "many"),
+    ("REPRO_BENCH_RUNS", "0"),
+    ("REPRO_BENCH_JOBS", "x"),
+    ("REPRO_BENCH_GPUS", "2,eight"),
+    ("REPRO_BENCH_FAST", "yes"),
+    ("REPRO_BENCH_ALLOW_FAIL", "2"),
+])
+def test_bench_config_rejects_malformed_values(var, bad):
+    from benchmarks.common import BenchConfig
+
+    with pytest.raises(ValueError, match=var):
+        BenchConfig.from_env({var: bad})
+
+
+def test_bench_config_rejects_unknown_vars():
+    from benchmarks.common import BenchConfig
+
+    # REPRO_BENCH_NT was read only by the retired host-timing benchmark
+    for var in ("REPRO_BENCH_NT", "REPRO_BENCH_RUSN"):
+        with pytest.raises(ValueError, match=var):
+            BenchConfig.from_env({var: "1"})
+
+
+@pytest.mark.parametrize("var", [
+    "REPRO_SCHED_BACKENDS", "REPRO_SCHED_REGRESSION_TOL",
+    "REPRO_SCHED_ROW_TOL", "REPRO_SCHED_TENANTS",
+])
+def test_sched_config_ignores_bench_vars_and_rejects_retired_names(var):
+    from repro.sched import SchedConfig
+
+    env = {"REPRO_BENCH_FAST": "1", "REPRO_BENCH_NT": "junk"}
+    assert SchedConfig.from_env(env) == SchedConfig()
+    with pytest.raises(ValueError, match=var):
+        SchedConfig.from_env({var: "1"})
+
+
+def test_serving_gate_on_committed_p99_rows_and_rows_built():
+    import json
+
+    import benchmarks.serving_load as sl
+
+    committed = json.loads(sl.P99_BASELINE.read_text())
+    assert len(committed) == 36
+    probe = {"tenants": sl.PROBE_TENANTS, "rows_ratio": 154087 / 1882}
+    rows = [dict(r) for r in committed]
+    assert sl.gate(rows, probe, committed)
+    # +26% on a single row's p99 slowdown fails the run
+    rows[5]["p99_slowdown"] *= 1.26
+    assert not sl.gate(rows, probe, committed)
+    # a dirty-row cache that rebuilds every row fails the floor
+    assert not sl.gate(committed, dict(probe, rows_ratio=1.0), committed)
+    # rows measured in only one of run and baseline are skipped
+    assert sl.gate(committed[:9], probe, committed[9:])
+
+
+def test_bench_jobs_sets_pool_and_run_width(monkeypatch, tmp_path):
+    """REPRO_BENCH_JOBS=2 gives the scripts a pool of width 2, and every
+    sweep and run_many they start fans out over 2 workers."""
+    from concurrent.futures import Future
+
     import benchmarks.common as common
-    import benchmarks.sched_overhead as so
+    import repro.core.api as api
 
-    out_json = tmp_path / "BENCH_sched.json"
-    monkeypatch.setattr(common, "BENCH_JSON", out_json)
-    rows = so.main()
-    out = capsys.readouterr().out
-    assert "events_per_s=" in out
-    assert all(r["events"] > 0 for r in rows)
-    assert {r["kernel"] for r in rows} == {
-        "cholesky", "lu", "qr", "cholesky-x4stream"
-    }
-    # backend-free ws is measured once under the stable "none" label
-    assert {r["backend"] for r in rows} == {"numpy", "none"}
-    assert all(
-        r["backend"] == "none" for r in rows if r["strategy"] == "ws"
-    )
-    # the eviction path has its own capacity-bounded rows (gated by key)
-    cap_rows = [r for r in rows if r["capacity"]]
-    assert {r["strategy"] for r in cap_rows} == set(
-        so.CAPACITY_ROW_STRATEGIES
-    )
-    assert all(r["capacity"] == so.CAPACITY_ROW_BYTES for r in cap_rows)
-    # the 4-tenant streaming row reports per-graph makespans
-    (stream,) = [r for r in rows if r["kernel"] == "cholesky-x4stream"]
-    assert len(stream["per_graph_makespans"]) == 4
-    assert all(m > 0 for m in stream["per_graph_makespans"])
-    # the fault path has its own churned rows: both recovery modes, keyed
-    # apart from the fault-free rows by the (churn, fault_mode) fields
-    churned = [r for r in rows if r["churn"]]
-    assert {(r["strategy"], r["fault_mode"]) for r in churned} == {
-        (s, m) for s in so.CHURN_STRATEGIES for m in ("drain", "kill")
-    }
-    assert all(r["churn"] == so.CHURN_RATE for r in churned)
-    assert all(r["fault_mode"] == "drain" and r["churn"] == 0.0
-               for r in rows if r not in churned)
-    # machine-readable perf trajectory (BENCH_sched.json satellite)
-    doc = json.loads(out_json.read_text())
-    sec = doc["sched_overhead"]
-    assert sec["calibration_score"] > 0
-    assert len(sec["whole_sim"]) == len(rows)
-    assert {"kernel", "strategy", "backend", "nt", "capacity",
-            "events_per_s", "wall_s"} <= set(sec["whole_sim"][0])
+    class RecordingPool:
+        widths = []
+        submits = []
 
+        def __init__(self, max_workers, mp_context=None):
+            self.widths.append(max_workers)
 
-def test_sched_regression_gate(monkeypatch, tmp_path, capsys):
-    """The CI gate fails on a >25% events/sec drop after machine-speed
-    calibration, and passes when throughput merely tracks machine speed."""
-    import json
-    import sys
-    from pathlib import Path
+        def submit(self, fn, *args):
+            self.submits.append(fn.__name__)
+            f = Future()
+            f.set_result(fn(*args))
+            return f
 
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-    import benchmarks.check_sched_regression as gate
+    monkeypatch.setenv("REPRO_SCHED_BACKEND", "numpy")
+    monkeypatch.setattr("concurrent.futures.ProcessPoolExecutor", RecordingPool)
+    monkeypatch.setattr(api, "_POOL", None)
+    monkeypatch.setattr(common, "RESULTS_DIR", tmp_path)
+    monkeypatch.setenv("REPRO_BENCH_JOBS", "2")
+    monkeypatch.setenv("REPRO_BENCH_RUNS", "1")
+    monkeypatch.setenv("REPRO_BENCH_GPUS", "2")
 
-    def write(path, cal, evs):
-        path.write_text(json.dumps({
-            "sched_overhead": {
-                "calibration_score": cal,
-                "whole_sim": [{
-                    "kernel": "cholesky", "strategy": "heft",
-                    "backend": "numpy", "nt": 16, "n_gpus": 8,
-                    "events_per_s": evs,
-                }],
-            }
-        }))
-
-    cur = tmp_path / "cur.json"
-    base = tmp_path / "base.json"
-    monkeypatch.setattr(gate, "CURRENT", cur)
-    monkeypatch.setattr(gate, "BASELINE", base)
-
-    # a slower machine (half calibration) with proportional events/sec: OK
-    write(base, 1000.0, 50000.0)
-    write(cur, 500.0, 25500.0)
-    assert gate.main() == 0
-    # a >25% real regression on the same machine: FAIL
-    write(cur, 1000.0, 36000.0)
-    assert gate.main() == 1
-    # missing baseline: skipped, not failed
-    base.unlink()
-    assert gate.main() == 0
-    capsys.readouterr()
+    runs, gpus, jobs = common.bench_settings()
+    assert (runs, gpus, jobs) == (1, [2], 2)
+    strategies = {k: common.STRATEGIES[k] for k in ("heft", "dada(a)")}
+    rows = common.sweep("tmp_jobs", "cholesky", strategies, runs, gpus, jobs)
+    assert len(rows) == 2
+    assert RecordingPool.widths == [2]
+    assert RecordingPool.submits == ["_sweep_config"] * 2
+    gfac = partial(cholesky_graph, 4, 256, with_fns=False)
+    run_many(gfac, paper_machine(2), partial(DADA, alpha=0.5), n_runs=4,
+             n_jobs=jobs)
+    assert RecordingPool.widths == [2]  # the one pool, reused
+    assert RecordingPool.submits[2:] == ["_run_chunk"] * 2
 
 
 def test_jax_work_never_forks(monkeypatch):

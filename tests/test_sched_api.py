@@ -183,16 +183,18 @@ def test_sched_config_parses_and_types(monkeypatch):
             "REPRO_SCHED_BACKEND": "jax",
             "REPRO_SCHED_JAX_MIN": "4",
             "REPRO_SCHED_LAMBDA_DEPTH": "3",
-            "REPRO_BENCH_NT": "16,32",
-            "REPRO_BENCH_FAST": "1",
+            "REPRO_SCHED_AUDIT": "1",
+            "REPRO_SCHED_CHURN": "2.5",
+            "REPRO_SCHED_RESCORE": "Incremental",
             "UNRELATED": "ignored",
         }
     )
     assert cfg.backend == "jax"
     assert cfg.jax_min == 4
     assert cfg.lambda_depth == 3
-    assert cfg.bench_nt == (16, 32)
-    assert cfg.bench_fast is True
+    assert cfg.audit is True
+    assert cfg.churn == 2.5
+    assert cfg.rescore == "incremental"
 
 
 def test_sched_config_rejects_malformed_values():
@@ -202,18 +204,20 @@ def test_sched_config_rejects_malformed_values():
         SchedConfig.from_env(env={"REPRO_SCHED_JAX_MIN": "junk"})
     with pytest.raises(ValueError, match="REPRO_SCHED_BACKEND"):
         SchedConfig.from_env(env={"REPRO_SCHED_BACKEND": "cuda"})
-    with pytest.raises(ValueError, match="REPRO_BENCH_RUNS"):
-        SchedConfig.from_env(env={"REPRO_BENCH_RUNS": "many"})
+    with pytest.raises(ValueError, match="REPRO_SCHED_BATCH"):
+        SchedConfig.from_env(env={"REPRO_SCHED_BATCH": "many"})
+    with pytest.raises(ValueError, match="REPRO_SCHED_AUDIT"):
+        SchedConfig.from_env(env={"REPRO_SCHED_AUDIT": "yes"})
 
 
 def test_sched_config_env_items_round_trip():
-    cfg = SchedConfig(backend="jax", jax_min=4, bench_nt=(16, 32), bench_fast=True)
+    cfg = SchedConfig(backend="jax", jax_min=4, audit=True, churn=2.5)
     env = dict(cfg.env_items())
     assert env == {
         "REPRO_SCHED_BACKEND": "jax",
         "REPRO_SCHED_JAX_MIN": "4",
-        "REPRO_BENCH_NT": "16,32",
-        "REPRO_BENCH_FAST": "1",
+        "REPRO_SCHED_AUDIT": "1",
+        "REPRO_SCHED_CHURN": "2.5",
     }
     assert SchedConfig.from_env(env=env) == cfg
 
