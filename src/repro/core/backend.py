@@ -55,8 +55,18 @@ results:
   * the flexible phase runs on **split CPU/GPU load lanes** (the paper's
     Algorithm 2 only ever takes a min over one class at a time), with
     first-occurrence ``argmin`` preserving the scalar tie-break;
-  * probes that are already infeasible (and the usually-empty dedicated
-    pass) **skip the remaining scans** via ``lax.cond``.
+  * the balance passes (dedicated rows, then flexible ones) **loop only
+    over the rows a live probe owns**: a probe that failed before its
+    balance phase owns none, and most rows went to the affinity phase.
+    Each pass is one loop, of data-dependent length, over the union of
+    the grid's live probes' rows in pass order; a probe applies a step only
+    on a row of its own, so it sees its own rows in its own order. A
+    ``lax.cond`` cannot skip this work: under ``vmap`` a batched
+    predicate turns it into a select that runs both branches, and the
+    dedicated pass has rows in most λ-loop iterations. ``counts`` keeps
+    the steps run and the ``2 · n_pad`` per iteration that passes over
+    every padded row would run (``balance_steps``,
+    ``balance_steps_padded``).
 
 The backend is selected per strategy instance (``DADA(backend="jax")``),
 falling back to the scheduling configuration (``repro.sched.SchedConfig``,
@@ -375,6 +385,13 @@ def _search_layout(key) -> Packed:
     return Packed(fields)
 
 
+# what a λ search returns (``lambda_search``), read back with it: the final
+# λ, and the loop steps its balance passes ran and the padded scans' steps
+_SEARCH_OUTS = Packed([("lam", (), "f64"), ("balance_steps", (), "i32"),
+                       ("balance_steps_padded", (), "i32")])
+_SEARCH_NAMES = tuple(f[0] for f in _SEARCH_OUTS.fields)
+
+
 @functools.lru_cache(maxsize=None)
 def _fused_layout(key) -> Tuple[Packed, Packed]:
     """The one-dispatch DADA program's packed inputs and outputs, from its
@@ -390,7 +407,7 @@ def _fused_layout(key) -> Tuple[Packed, Packed]:
         fields.append(("tids", (n_pad,), "i64"))
         outs += [("ord_row", (n_pad,), "i32"), ("ord_rid", (n_pad,), "i32"),
                  ("n_pref", (), "i32")]
-    outs += [("lam", (), "f64"), ("upper0", (), "f64")]
+    outs += [("upper0", (), "f64")] + [f[:3] for f in _SEARCH_OUTS.fields]
     return Packed(fields), Packed(outs)
 
 
@@ -515,6 +532,18 @@ def affinity_order(F, S, C, tids, valid, cols):
     return ord_row, ord_rid, n_pref, (chain_cost, chain_valid, task_slot, length)
 
 
+def _compact(mask):
+    """The indices at which ``mask`` holds, in order, at the front of an
+    array as long as ``mask`` (0 past them), and how many there are."""
+    import jax.numpy as jnp
+
+    n = mask.shape[0]
+    at = jnp.where(mask, jnp.cumsum(mask, dtype=jnp.int32) - 1, n)
+    idx = jnp.zeros((n,), jnp.int32).at[at].set(jnp.arange(n, dtype=jnp.int32),
+                                                 mode="drop")
+    return idx, jnp.sum(mask, dtype=jnp.int32)
+
+
 def lambda_search(F, depth, have_both, area_bound, C, v, chains=None):
     """DADA's binary search on λ as one traced loop: the final ``upper``,
     bit for bit the value the Python loop in ``dada.place`` settles on.
@@ -523,6 +552,12 @@ def lambda_search(F, depth, have_both, area_bound, C, v, chains=None):
     their packed names (``_search_fields``, ``p_cpu``, ``p_gpu`` and
     ``upper0``); ``chains`` is ``(chain_cost, chain_valid, task_slot,
     length)`` (:func:`affinity_order`), or None without an affinity phase.
+
+    Returns ``(upper, steps, padded)``: with the final λ, the loop steps
+    the balance passes ran over the whole search, and the steps passes over
+    every padded row would have run: per λ-loop iteration ``n_pad`` for the
+    dedicated and ``n_pad`` for the flexible pass, or ``n_pad`` for the one
+    pass of a single-class machine.
     """
     import jax
     import jax.numpy as jnp
@@ -531,6 +566,10 @@ def lambda_search(F, depth, have_both, area_bound, C, v, chains=None):
     add, sub, mul, lt, le = F.add, F.sub, F.mul, F.lt, F.le
     n_pad, n_res = C.shape
     K = 2 ** depth - 1
+    # the probes of one λ-loop iteration: vmapped over the grid, or, with
+    # one probe, unbatched, so that gathers and updates stay scalar-indexed
+    # (cheap on CPU) instead of turning into batched scatters
+    grid = jax.vmap if K > 1 else (lambda fn, in_axes=0: fn)
     (loads0, p_cpu, p_gpu, valid, flex_ord, cpu_idx, gpu_idx, no_cpus, no_gpus,
      alpha, two_alpha, area, off_total, max_off, n_res_f, eps_rel, max_iters,
      upper0) = map(v.get, (
@@ -543,18 +582,18 @@ def lambda_search(F, depth, have_both, area_bound, C, v, chains=None):
         chain_cost, chain_valid, task_slot, length = chains
         cum = chain_loads(F, loads0, chain_cost, length)
     if have_both:
-        C_g = C[:, gpu_idx]
-        C_c = C[:, cpu_idx]
-        Cf_g = C_g[flex_ord]
-        Cf_c = C_c[flex_ord]
+        Cf_g = C[:, gpu_idx][flex_ord]
+        Cf_c = C[:, cpu_idx][flex_ord]
         gpu_mask = jnp.zeros((n_res,), bool).at[gpu_idx].set(True)
         cpu_mask = ~gpu_mask
+        lanes = jnp.arange(n_res)
 
-    def verdict(lam):
-        """Feasibility of guess λ — the exact boolean dada's
-        ``try_build(lam) is not None`` yields (early-exit order differs,
-        the verdict cannot: overflow flags are sticky and loads accumulate
-        through the same op sequence)."""
+    def before_balance(lam):
+        """One probe up to its balance phase: the cap, whether the probe
+        has failed already, the loads after the affinity phase, and the
+        rows left to the balance phase. With both classes those are the
+        dedicated rows, whether each goes to the GPUs, and the flexible
+        positions of ``flex_ord``; on one class, every row left."""
         cap = add(mul(two_alpha, lam), TINY)
         bad = lt(cap, max_off)
         if area_bound:
@@ -574,107 +613,105 @@ def lambda_search(F, depth, have_both, area_bound, C, v, chains=None):
         big_cpu = no_cpus | lt(lam, p_cpu)
         big_gpu = no_gpus | lt(lam, p_gpu)
         bad = bad | jnp.any(rem & big_cpu & big_gpu)
+        if not have_both:
+            return cap, bad, loads, (rem,)
+        flex = rem & le(p_cpu, lam) & le(p_gpu, lam)
+        # `valid` is a position mask (True exactly for k < n), so it also
+        # masks padded flex positions
+        return cap, bad, loads, (rem & ~flex, lt(lam, p_cpu), flex[flex_ord] & valid)
 
-        def balance(args):
-            loads, bad = args
-            if have_both:
-                flex = rem & le(p_cpu, lam) & le(p_gpu, lam)
-                ded = rem & ~flex
-                ded_gpu = lt(lam, p_cpu)
-                lanes = jnp.arange(n_res)
+    # one balance step of one probe: `on` says whether the row is the
+    # probe's own; a step on a row it does not own changes nothing
+    def dstep(loads, bad, cap, on, to_gpu, crow):
+        pool = jnp.where(to_gpu, gpu_mask, cpu_mask)
+        vm = jnp.where(pool, add(loads, crow), INF)
+        # first-occurrence argmin keeps the scalar tie-break
+        j = jnp.argmin(F.key(vm))
+        bv = vm[j]
+        bad = bad | (on & lt(cap, bv))
+        loads = jnp.where((lanes == j) & on, bv, loads)
+        return loads, bad
 
-                def dstep(carry, x):
-                    loads, bad = carry
-                    on, to_gpu, crow = x
-                    pool = jnp.where(to_gpu, gpu_mask, cpu_mask)
-                    vm = jnp.where(pool, add(loads, crow), INF)
-                    # first-occurrence argmin keeps the scalar tie-break
-                    j = jnp.argmin(F.key(vm))
-                    bv = vm[j]
-                    bad = bad | (on & lt(cap, bv))
-                    loads = jnp.where((lanes == j) & on, bv, loads)
-                    return (loads, bad), None
+    # flexible phase on split class lanes: Algorithm 2 only ever takes the
+    # min over one class at a time
+    def fstep(loads_g, loads_c, bad, cap, gpu_budget, on, crow_g, crow_c):
+        g = jnp.argmin(F.key(loads_g))
+        gl = loads_g[g]
+        use_gpu = on & le(gl, gpu_budget)
+        vg = add(gl, crow_g[g])
+        bad = bad | (use_gpu & lt(cap, vg))
+        loads_g = loads_g.at[g].set(jnp.where(use_gpu, vg, gl))
+        vm = add(loads_c, crow_c)
+        j = jnp.argmin(F.key(vm))
+        bv = vm[j]
+        use_eft = on & ~use_gpu
+        bad = bad | (use_eft & lt(cap, bv))
+        loads_c = loads_c.at[j].set(jnp.where(use_eft, bv, loads_c[j]))
+        return loads_g, loads_c, bad
 
-                def ded_pass(args):
-                    (loads, bad), _ = lax.scan(
-                        dstep, args, (ded, ded_gpu, C), unroll=F.unroll
-                    )
-                    return loads, bad
+    # single-class machine: the EFT pool is every resource, in index order
+    def sstep(loads, bad, cap, on, crow):
+        vm = add(loads, crow)
+        j = jnp.argmin(F.key(vm))
+        bv = vm[j]
+        bad = bad | (on & lt(cap, bv))
+        loads = loads.at[j].set(jnp.where(on, bv, loads[j]))
+        return loads, bad
 
-                # the dedicated pass is usually empty for feasible λ
-                # guesses — skip its n-step scan when it is
-                loads, bad = lax.cond(
-                    jnp.any(ded), ded_pass, lambda a: a, (loads, bad)
-                )
+    def balance_pass(step, carry, consts, on, per_row, rows):
+        """``step`` over the rows any probe owns (``on``, probe × row), in
+        order, for every probe at once: one loop over a shared list as long
+        as the union of the probes' rows, each step reading one unbatched
+        row of ``rows``. Each probe applies the same operations to the same
+        rows in the same order as a pass over all rows would, since a step
+        it does not own leaves it as it was. Returns the carry and the
+        number of steps run."""
+        idx, count = _compact(jnp.any(on.reshape(-1, n_pad), axis=0))
+        batched = grid(step, in_axes=(0,) * (len(carry) + len(consts) + 1 + len(per_row))
+                       + (None,) * len(rows))
 
-                # flexible phase on split class lanes: Algorithm 2 only
-                # ever takes the min over one class at a time
-                loads_g = loads[gpu_idx]
-                loads_c = loads[cpu_idx]
-                gpu_budget = add(lam, TINY)
-                # `valid` is a position mask (True exactly for k < n), so
-                # it also masks padded flex positions
-                flex_o = flex[flex_ord] & valid
+        def body(i, carry):
+            k = idx[i]
+            return batched(*carry, *consts, on[..., k], *(x[..., k] for x in per_row),
+                           *(x[k] for x in rows))
 
-                def fstep(carry, x):
-                    loads_g, loads_c, bad = carry
-                    on, crow_g, crow_c = x
-                    g = jnp.argmin(F.key(loads_g))
-                    gl = loads_g[g]
-                    use_gpu = on & le(gl, gpu_budget)
-                    vg = add(gl, crow_g[g])
-                    bad = bad | (use_gpu & lt(cap, vg))
-                    loads_g = loads_g.at[g].set(jnp.where(use_gpu, vg, gl))
-                    vm = add(loads_c, crow_c)
-                    j = jnp.argmin(F.key(vm))
-                    bv = vm[j]
-                    use_eft = on & ~use_gpu
-                    bad = bad | (use_eft & lt(cap, bv))
-                    loads_c = loads_c.at[j].set(jnp.where(use_eft, bv, loads_c[j]))
-                    return (loads_g, loads_c, bad), None
+        return lax.fori_loop(0, count, body, tuple(carry)), count
 
-                (loads_g, loads_c, bad), _ = lax.scan(
-                    fstep, (loads_g, loads_c, bad),
-                    (flex_o, Cf_g, Cf_c), unroll=F.unroll,
-                )
-                # `loads` is returned un-merged: only `bad` is read after
-                # the balance phase
-            else:
-                # single-class machine: the EFT pool is every resource,
-                # processed in index order
-                def sstep(carry, x):
-                    loads, bad = carry
-                    on, crow = x
-                    vm = add(loads, crow)
-                    j = jnp.argmin(F.key(vm))
-                    bv = vm[j]
-                    bad = bad | (on & lt(cap, bv))
-                    loads = loads.at[j].set(jnp.where(on, bv, loads[j]))
-                    return (loads, bad), None
+    def verdicts(lam):
+        """Feasibility of the guesses ``lam`` (the grid, or one probe) —
+        the exact booleans dada's ``try_build(lam) is not None`` yields
+        (early-exit order differs, the verdicts cannot: overflow flags are
+        sticky and loads accumulate through the same op sequence) — and
+        the balance steps run. A probe that failed before its balance
+        phase owns no row of it."""
+        cap, bad, loads, left = grid(before_balance)(lam)
+        live = (~bad)[..., None]
+        if have_both:
+            ded, to_gpu, flex_o = left
+            (loads, bad), n_ded = balance_pass(dstep, (loads, bad), (cap,),
+                                               ded & live, (to_gpu,), (C,))
+            (_, _, bad), n_flex = balance_pass(
+                fstep, (loads[..., gpu_idx], loads[..., cpu_idx], bad),
+                (cap, add(lam, TINY)), flex_o & live, (), (Cf_g, Cf_c))
+            return ~bad, n_ded + n_flex
+        (rem,) = left
+        (_, bad), n = balance_pass(sstep, (loads, bad), (cap,), rem & live, (), (C,))
+        return ~bad, n
 
-                (loads, bad), _ = lax.scan(
-                    sstep, (loads, bad), (rem, C), unroll=F.unroll
-                )
-            return loads, bad
-
-        # a probe that already failed skips the balance scans
-        loads, bad = lax.cond(bad, lambda a: a, balance, (loads, bad))
-        return bad
-
-    feasible_grid = jax.vmap(lambda lam: ~verdict(lam))
+    padded_per_grid = (2 if have_both else 1) * n_pad
 
     def searching(lower, upper, it):
         return lt(mul(eps_rel, upper), sub(upper, lower)) & (it < max_iters)
 
     def cond(state):
-        return searching(*state)
+        return searching(*state[:3])
 
     def body(state):
-        lower, upper, it = state
+        lower, upper, it, steps, padded = state
         # speculative midpoint tree (heap layout): node k covers an
         # interval; its midpoint is the probe the bisection would make on
         # reaching it. Depth-d tree = the next d probes for every possible
-        # verdict path — all evaluated in one vmapped sweep of the λ grid.
+        # verdict path — all evaluated in one sweep of the λ grid.
         lo = [None] * K
         hi = [None] * K
         mid = [None] * K
@@ -686,13 +723,8 @@ def lambda_search(F, depth, have_both, area_bound, C, v, chains=None):
                 lo[2 * k + 1], hi[2 * k + 1] = lo[k], mid[k]
                 lo[2 * k + 2], hi[2 * k + 2] = mid[k], hi[k]
         mids = jnp.stack(mid)
-        if K == 1:
-            # no vmap at depth 1: gathers/updates inside the verdict stay
-            # scalar-indexed (cheap on CPU) instead of turning into batched
-            # scatters
-            feas = jnp.reshape(~verdict(mids[0]), (1,))
-        else:
-            feas = feasible_grid(mids)
+        feas, n = verdicts(mids if K > 1 else mids[0])
+        feas = jnp.reshape(feas, (K,))
         # walk ≤ depth bisection steps, re-checking the stopping rule
         # before each (exactly like the Python while loop)
         idx = jnp.int32(0)
@@ -705,10 +737,12 @@ def lambda_search(F, depth, have_both, area_bound, C, v, chains=None):
             upper = jnp.where(go & f, lam, upper)
             it = it + go.astype(jnp.int32)
             idx = jnp.where(go, 2 * idx + jnp.where(f, 1, 2), idx)
-        return lower, upper, it
+        return lower, upper, it, steps + n, padded + padded_per_grid
 
-    _, upper, _ = lax.while_loop(cond, body, (F.const(0.0), upper0, jnp.int32(0)))
-    return upper
+    zero = jnp.int32(0)
+    _, upper, _, steps, padded = lax.while_loop(
+        cond, body, (F.const(0.0), upper0, zero, zero, zero))
+    return upper, steps, padded
 
 
 def _row_fold(F, x):
@@ -768,10 +802,14 @@ class JaxScoringBackend:
         # counted); task x resource score cells computed on the device
         # (``cells_device``) and, by the strategies, on the host
         # (``cells_host``); activations whose order and λ search ran in the
-        # one program that scored them (``fused``)
+        # one program that scored them (``fused``); the loop steps the λ
+        # searches' balance passes ran over the rows their live probes own
+        # (``balance_steps``), and the steps passes over every padded row
+        # would have run (``balance_steps_padded``), both read back with λ
         self.counts = {"device": 0, "outside": 0, "rejected": 0,
                        "uploads": 0, "readbacks": 0,
-                       "cells_device": 0, "cells_host": 0, "fused": 0}
+                       "cells_device": 0, "cells_host": 0, "fused": 0,
+                       "balance_steps": 0, "balance_steps_padded": 0}
         self._matrix_fns: Dict[tuple, object] = {}
         self._search_fns: Dict[tuple, object] = {}
         self._heft_fns: Dict[tuple, object] = {}
@@ -995,6 +1033,7 @@ class JaxScoringBackend:
             out["C"] = out["C_np"].tolist()
         if search is not None:
             self.counts["fused"] += 1
+            self._count_balance(host)
             m = int(host["n_pref"]) if want_s else 0
             none = np.zeros(0, np.int32)
             out.update(order_rows=host.get("ord_row", none)[:m],
@@ -1110,7 +1149,8 @@ class JaxScoringBackend:
                 cols = v["gpu_idx"] if accel_only else jnp.arange(n_res, dtype=jnp.int32)
                 out["ord_row"], out["ord_rid"], out["n_pref"], chains = affinity_order(
                     F, S, C, v["tids"], valid, cols)
-            out["lam"] = lambda_search(F, depth, have_both, area_bound, C, v, chains)
+            out.update(zip(_SEARCH_NAMES, lambda_search(
+                F, depth, have_both, area_bound, C, v, chains)))
             return C, outs.join(out, F)
 
         # the program's name in a device trace (jit_dada_score_and_search)
@@ -1206,10 +1246,15 @@ class JaxScoringBackend:
                 fn = self._build_search_fn(key)
                 self._search_fns[key] = fn
             args = [(self.jax.device_put, _search_layout(key).pack(vals)), (None, C_dev)]
-        F = self.f64
-        _, (upper,) = call_program(
-            "search", fn, args, [(None, lambda x: float(F.decode(x)))], self.counts)
-        return upper
+        _, (out,) = call_program(
+            "search", fn, args, [(None, _SEARCH_OUTS.split)], self.counts)
+        self._count_balance(out)
+        return float(out["lam"])
+
+    def _count_balance(self, out: Dict[str, np.ndarray]) -> None:
+        """Add a λ search's balance counters, read back with its λ."""
+        for k in _SEARCH_NAMES[1:]:
+            self.counts[k] += int(out[k])
 
     def _build_search_fn(self, key):
         have_both, area_bound, depth = key[4:]
@@ -1219,7 +1264,8 @@ class JaxScoringBackend:
         def search(packed, C):
             v = layout.unpack(packed, F)
             chains = (v["chain_cost"], v["chain_valid"], v["task_slot"], v["chain_len"])
-            return lambda_search(F, depth, have_both, area_bound, C, v, chains)
+            out = lambda_search(F, depth, have_both, area_bound, C, v, chains)
+            return _SEARCH_OUTS.join(dict(zip(_SEARCH_NAMES, out)), F)
 
         # the program's name in a device trace (jit_dada_lambda_search); the
         # def is named apart from the method, which the lint would take for it

@@ -3,6 +3,9 @@ decisions, λ trajectories and score values bit-identical to the numpy
 path, a hard failure when the jax backend cannot be built, bounded jit
 retraces via padded shapes, and a Pallas transfer kernel that matches the
 XLA fold."""
+import functools
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
@@ -354,6 +357,232 @@ def test_device_affinity_order_equals_host_rule(arith):
     assert m == len(pref) < n
     assert np.asarray(ord_row)[:m].tolist() == [e[2] for e in pref]
     assert np.asarray(ord_rid)[:m].tolist() == [e[3] for e in pref]
+
+
+# ---------------------------------------------------------------------------
+# the λ search's balance passes: one loop over the rows the grid's live
+# probes own, against DADA's Python search written out
+
+
+_TINY = 1e-12
+
+
+def _host_probe(a, lam):
+    """``try_build(lam)`` as ``dada.place`` runs it, without its early
+    exits: whether λ is feasible, and the rows the balance phase visits if
+    the probe has not failed before it (the dedicated rows and the
+    flexible positions of ``flex_order``; on one class, every row left)."""
+    C, p_cpu, p_gpu = a["C"], a["p_cpu"], a["p_gpu"]
+    cpus, gpus = a["cpu_rids"], a["gpu_rids"]
+    cap = (2.0 + a["alpha"]) * lam + _TINY
+    bad = a["max_off"] > cap
+    if a["area_bound"]:
+        bad |= a["area"] > lam * len(C[0]) - a["off_total"] + _TINY
+    loads = list(a["offsets"])
+    assigned = set()
+    budget = a["alpha"] * lam + _TINY
+    for row, rid, c in a["by_score"]:
+        if loads[rid] <= budget:
+            assigned.add(row)
+            loads[rid] = loads[rid] + c
+            bad |= loads[rid] > cap
+    rem = [i for i in range(len(C)) if i not in assigned]
+    bad |= any((not cpus or p_cpu[i] > lam) and (not gpus or p_gpu[i] > lam) for i in rem)
+
+    def eft(i, pool):
+        best_v, best = float("inf"), pool[0]
+        for rid in pool:
+            v = loads[rid] + C[i][rid]
+            if v < best_v:
+                best_v, best = v, rid
+        loads[best] = best_v
+        return best_v > cap
+
+    if not (cpus and gpus):
+        ded, flex = rem, []
+    else:
+        ded = [i for i in rem if p_cpu[i] > lam or p_gpu[i] > lam]
+        flex = [k for k, i in enumerate(a["flex_order"]) if i in rem and i not in ded]
+    owned = (set(), set()) if bad else (set(ded), set(flex))
+    for i in ded:
+        bad |= eft(i, (gpus if p_cpu[i] > lam else cpus) if cpus and gpus else cpus or gpus)
+    for k in flex:
+        i = a["flex_order"][k]
+        g = min(gpus, key=lambda r: loads[r])  # the first minimum
+        if loads[g] <= lam + _TINY:
+            loads[g] = loads[g] + C[i][g]
+            bad |= loads[g] > cap
+        else:
+            bad |= eft(i, cpus)
+    return not bad, owned
+
+
+def _host_search(a, depth):
+    """DADA's bisection on λ, a λ-loop iteration of the device at a time:
+    the final λ, and for each iteration the union of the rows its grid's
+    live probes own (the dedicated and flexible passes' lengths)."""
+    lower, upper, it = 0.0, a["upper0"], 0
+
+    def searching():
+        return upper - lower > a["eps_rel"] * upper and it < a["max_iters"]
+
+    grids = []
+    while searching():
+        # every probe the next `depth` bisection steps can make
+        probes, spans = [], [(lower, upper)]
+        for _ in range(depth):
+            mids = [(lo + hi) / 2.0 for lo, hi in spans]
+            probes += mids
+            spans = [s for (lo, hi), m in zip(spans, mids) for s in ((lo, m), (m, hi))]
+        ded, flex = set(), set()
+        for lam in probes:
+            d, f = _host_probe(a, lam)[1]
+            ded |= d
+            flex |= f
+        grids.append((len(ded), len(flex)))
+        for _ in range(depth):
+            if not searching():
+                break
+            lam = (upper + lower) / 2.0
+            if _host_probe(a, lam)[0]:
+                upper = lam
+            else:
+                lower = lam
+            it += 1
+    return upper, grids
+
+
+def _activation(case):
+    """A synthetic activation drawn from a seed per case. Durations and
+    transfers are small dyadic numbers, so loads tie and land on the caps;
+    ``all_bad`` holds a task larger than λ everywhere and an upper bound
+    just above it, so every probe of the first grid fails before its
+    balance phase."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    n = {"n9": 9, "n17": 17}.get(case, 13)
+    n_cpu, n_gpu = (6, 0) if case == "one_class" else (4, 2)
+    accel = np.asarray([False] * n_cpu + [True] * n_gpu)
+    p_cpu = rng.choice([1.0, 2.0, 3.0, 4.0, 6.0, 8.0], n)
+    p_gpu = p_cpu / rng.choice([0.25, 0.5, 1.0, 4.0, 16.0], n)
+    X = rng.choice([0.0, 0.25, 0.5], (n, len(accel)))
+    C = np.where(accel[None, :], p_gpu[:, None], p_cpu[:, None]) + X
+    offsets = rng.choice([0.0, 0.5, 1.0], len(accel))
+    upper0 = float(np.maximum(p_cpu, p_gpu).sum() + offsets.max() + X.max(axis=1).sum()) + _TINY
+    if case == "all_bad":
+        p_cpu[0] = p_gpu[0] = 64.0
+        upper0 = 64.0 * 32 / 31.5
+    alpha = 0.0 if case == "alpha0" else 0.5
+    rows = rng.permutation(n)[:0 if alpha == 0.0 else n // 2]
+    by_score = [(int(i), int(rng.integers(len(accel))), 0.0) for i in rows]
+    by_score = [(i, rid, float(C[i, rid])) for i, rid, _ in by_score]
+    return dict(
+        C=C.tolist(), p_cpu=p_cpu.tolist(), p_gpu=p_gpu.tolist(), offsets=offsets.tolist(),
+        cpu_rids=[j for j in range(len(accel)) if not accel[j]],
+        gpu_rids=[j for j in range(len(accel)) if accel[j]],
+        flex_order=rng.permutation(n).tolist(), by_score=by_score, alpha=alpha,
+        area_bound=case == "n17", area=float(np.minimum(p_cpu, p_gpu).sum()),
+        off_total=float(offsets.sum()), max_off=float(offsets.max()),
+        eps_rel=1e-3, max_iters=40, upper0=upper0, accel=accel.tolist())
+
+
+@functools.lru_cache(maxsize=None)
+def _search_backend(depth, arith):
+    from repro.core import f64
+    from repro.core.backend import JaxScoringBackend
+    from repro.sched.config import SchedConfig
+
+    be = JaxScoringBackend(SchedConfig(backend="jax", lambda_depth=depth))
+    be.f64 = f64.NATIVE if arith == "native" else f64.SOFT
+    return be
+
+
+@pytest.mark.parametrize("case", ["mixed", "all_bad", "alpha0", "one_class", "n9", "n17"])
+@pytest.mark.parametrize("arith", ["native", "soft"])
+@pytest.mark.parametrize("depth", [1, 5])
+def test_compacted_balance_search_equals_host_loop(depth, arith, case):
+    """The λ search, its balance passes looping over the rows the grid's
+    live probes own, settles on the Python loop's λ bit for bit, and counts
+    as its loop steps the union of those rows, grid by grid, against
+    ``2 · n_pad`` a grid (``n_pad`` on one class) for passes over every
+    padded row."""
+    from repro.core.backend import _bucket, _pad_rows
+
+    a = _activation(case)
+    want, grids = _host_search(a, depth)
+    n, n_res = len(a["C"]), len(a["accel"])
+    both = bool(a["cpu_rids"] and a["gpu_rids"])
+    if case == "all_bad":
+        assert grids[0] == (0, 0)
+    elif both:
+        assert any(d for d, _ in grids) and any(f for _, f in grids)
+    be = _search_backend(depth, arith)
+    with jax.enable_x64(True):
+        C_dev = jax.numpy.asarray(be.f64.encode(_pad_rows(a["C"], _bucket(n))))
+    before = dict(be.counts)
+    got = be.dada_lambda_search(
+        n=n, n_res=n_res, offsets=a["offsets"], C_dev=C_dev, p_cpu=a["p_cpu"],
+        p_gpu=a["p_gpu"], by_score=[(1.0, i, rid, c) for i, rid, c in a["by_score"]],
+        tid_index={i: i for i in range(n)}, flex_order=a["flex_order"],
+        resources=[SimpleNamespace(is_accelerator=x) for x in a["accel"]],
+        have_both=both, no_cpus=not a["cpu_rids"], no_gpus=not a["gpu_rids"],
+        alpha=a["alpha"], area_bound=a["area_bound"], area=a["area"],
+        off_total=a["off_total"], max_off=a["max_off"], eps_rel=a["eps_rel"],
+        max_iters=a["max_iters"], upper0=a["upper0"])
+    assert got == want
+    assert be.counts["balance_steps"] - before["balance_steps"] == sum(map(sum, grids))
+    assert (be.counts["balance_steps_padded"] - before["balance_steps_padded"]
+            == (2 if both else 1) * _bucket(n) * len(grids))
+
+
+def test_balance_counters_on_one_schedule():
+    """Every 17-to-31-wide activation of a DADA+CP Cholesky schedule on the
+    paper's machine, through the one program at the chip's depth 5: its λ
+    is the Python loop's, ``balance_steps`` is the union of the rows its
+    grids' live probes own, ``balance_steps_padded`` is ``2 · n_pad`` a
+    grid, and both come back in its one read-back."""
+    from repro.core.backend import _bucket
+    from repro.sched.config import SchedConfig
+
+    cfg = SchedConfig(backend="jax", jax_min=17, lambda_depth=5)
+    be = get_backend("jax", cfg)
+    score = be.score_matrices
+    seen = []
+
+    def recording(sim, tids, resources, **kw):
+        before = dict(be.counts)
+        out = score(sim, tids, resources, **kw)
+        seen.append((tids, resources, kw, out,
+                     {k: be.counts[k] - before[k] for k in be.counts}))
+        return out
+
+    be.score_matrices = recording
+    try:
+        run_simulation(cholesky_graph(20, 512, with_fns=False), paper_machine(8),
+                       DADA(alpha=0.5, use_cp=True, backend="jax", config=cfg), seed=4)
+    finally:
+        del be.score_matrices
+    with_prefs = 0
+    for tids, resources, kw, out, n in seen:
+        s, C = kw["search"], out["C_np"]
+        accel = [r.is_accelerator for r in resources]
+        a = dict(
+            C=C.tolist(), p_cpu=list(kw["p_cpu"]), p_gpu=list(kw["p_gpu"]),
+            offsets=list(s["offsets"]), cpu_rids=[j for j, x in enumerate(accel) if not x],
+            gpu_rids=[j for j, x in enumerate(accel) if x], flex_order=list(s["flex_order"]),
+            by_score=[(int(i), int(r), float(C[i, r]))
+                      for i, r in zip(out["order_rows"], out["order_rids"])],
+            alpha=s["alpha"], area_bound=s["area_bound"], area=s["area"],
+            off_total=s["off_total"], max_off=s["max_off"], eps_rel=s["eps_rel"],
+            max_iters=s["max_iters"], upper0=out["upper0"])
+        lam, grids = _host_search(a, 5)
+        steps = sum(map(sum, grids))
+        assert out["lam"] == lam
+        assert n["balance_steps"] == steps
+        assert n["balance_steps_padded"] == 2 * _bucket(len(tids)) * len(grids)
+        assert n["uploads"] == n["readbacks"] == n["fused"] == n["device"] == 1
+        with_prefs += bool(a["by_score"]) and steps > 0
+    assert len(seen) >= 4 and with_prefs
+    assert all(17 <= len(tids) <= 31 for tids, *_ in seen)
 
 
 # ---------------------------------------------------------------------------
